@@ -229,6 +229,7 @@ def bench_circuit_engines(quick=False, ensemble_size=24, lp_iters=200):
     """
     from repro.experiments import solve_ensemble_lp
     from repro.launch.perf import measured_roofline
+    from repro.launch.roofline import PEAKS
     from repro.pipeline.batch_circuit import (
         lower_calendar,
         member_tables,
@@ -293,7 +294,14 @@ def bench_circuit_engines(quick=False, ensemble_size=24, lp_iters=200):
         )
 
     # Roofline distance of the two XLA calendars (the "wide" engine is
-    # host NumPy: no HLO exists for it, by design).
+    # host NumPy: no HLO exists for it, by design).  Only a device with
+    # published peaks has one: anywhere else it is "not measured".
+    kind = stats["device_kind"]
+    if kind not in PEAKS:
+        for engine in ("jax", "kernel"):
+            for key in ("bound_s", "frac", "dominant"):
+                stats[f"circuit_{engine}_roofline_{key}"] = "not measured"
+        return stats
     tabs = [
         tab
         for inst, alloc, order in zip(ens, allocs, orders)
@@ -308,7 +316,7 @@ def bench_circuit_engines(quick=False, ensemble_size=24, lp_iters=200):
             .as_text()
         )
         terms = measured_roofline(
-            hlo, stats[f"circuit_{engine}_warm_ensemble{B}_s"]
+            hlo, stats[f"circuit_{engine}_warm_ensemble{B}_s"], kind
         )
         stats[f"circuit_{engine}_roofline_bound_s"] = terms["bound_s"]
         stats[f"circuit_{engine}_roofline_frac"] = terms["roofline_frac"]
@@ -1124,6 +1132,9 @@ if __name__ == "__main__":
         "keys to gate (CI jobs gate only the keys their benches produce)",
     )
     args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     if args.check_floors:
         import sys
 

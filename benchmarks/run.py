@@ -64,7 +64,9 @@ def main(argv=None):
         "(batch = Pipeline.run_batch, loop = per-instance reference)",
     )
     args = ap.parse_args(argv)
+    from repro.launch.compile_cache import enable_compile_cache
 
+    enable_compile_cache()
     benches = _benches()
     if args.list:
         for name in benches:

@@ -398,4 +398,7 @@ if __name__ == "__main__":
         help="append the stats to the repo-tracked BENCH_micro.json",
     )
     args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     main(quick=args.quick, scenario=args.scenario, trajectory=args.trajectory)

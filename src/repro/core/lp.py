@@ -19,17 +19,16 @@ Three solvers:
                         (padded coflows/ports masked out of the max terms and
                         the objective).  The per-step (B, Mp, Mp) @ (B, Mp, Pp)
                         contractions are the `lp_terms_batch` kernel's shape.
-  * solve_subgradient — pure-JAX projected subgradient on the equivalent
-                        convex piecewise-linear program
+  * solve_subgradient — the batched solver on one instance.  Both run
+                        projected subgradient on the equivalent convex
+                        piecewise-linear program
                             min_Y  F(Y) = sum_m w_m T_m(Y),
                             T_m(Y) = max(a_m, max_p (X~^T P_rho)[m,p] / R,
                                               max_p (delta/K)(X~^T P_tau)[m,p])
                         where X~ has diag 1, X~[a,b] = Y[a,b] (a<b),
                         1 - Y[b,a] (a>b), and Y is box-projected to [0,1].
                         For fixed precedences the optimal T is the pointwise
-                        max of the RHS, so this is the same LP.  The two
-                        (M,M)@(M,2N) matmuls per step are the `lp_terms`
-                        Pallas kernel's job on TPU.
+                        max of the RHS, so this is the same LP.
 """
 
 from __future__ import annotations
@@ -194,101 +193,6 @@ def solve_exact(instance: CoflowInstance) -> LPSolution:
 # ---------------------------------------------------------------------------
 
 
-def _completion_from_Y(
-    Y: jnp.ndarray,
-    p_rho: jnp.ndarray,
-    p_tau: jnp.ndarray,
-    releases: jnp.ndarray,
-    inv_R: float,
-    delta_over_K: float,
-    temp: jnp.ndarray | None = None,
-) -> jnp.ndarray:
-    """T_m(Y) — optimal completion values for fixed precedences.
-
-    With ``temp`` the hard max over constraint rows is replaced by a
-    temperature-scaled logsumexp (a smooth upper bound), which gives the
-    annealed-smoothing solver useful gradients on plateaus.
-    """
-    M = Y.shape[0]
-    iu = jnp.triu(jnp.ones((M, M), dtype=bool), k=1)
-    il = jnp.tril(jnp.ones((M, M), dtype=bool), k=-1)
-    X = jnp.where(iu, Y, 0.0) + jnp.where(il, 1.0 - Y.T, 0.0)
-    X = X + jnp.eye(M, dtype=Y.dtype)  # fold the self term into the matmul
-    load = (X.T @ p_rho) * inv_R  # (M, 2N) — the lp_terms kernel's matmul
-    rec = (X.T @ p_tau) * delta_over_K
-    stacked = jnp.concatenate([load, rec, releases[:, None]], axis=1)
-    if temp is None:
-        return stacked.max(axis=1)
-    return temp * jax.scipy.special.logsumexp(stacked / temp, axis=1)
-
-
-@functools.partial(
-    jax.jit, static_argnames=("iters", "inv_R", "delta_over_K", "lr")
-)
-def _subgradient_run(
-    Y0: jnp.ndarray,
-    p_rho: jnp.ndarray,
-    p_tau: jnp.ndarray,
-    weights: jnp.ndarray,
-    releases: jnp.ndarray,
-    *,
-    iters: int,
-    inv_R: float,
-    delta_over_K: float,
-    lr: float = 0.05,
-):
-    """Projected Adam on the temperature-annealed smoothed objective.
-
-    The smoothing temperature decays geometrically from ~scale of the
-    objective spread to ~0; best-so-far is tracked under the *true*
-    piecewise-linear objective so the returned point is never worse than
-    the warm start.
-    """
-
-    def true_objective(Y):
-        T = _completion_from_Y(Y, p_rho, p_tau, releases, inv_R, delta_over_K)
-        return jnp.dot(weights, T)
-
-    def smooth_objective(Y, temp):
-        T = _completion_from_Y(
-            Y, p_rho, p_tau, releases, inv_R, delta_over_K, temp=temp
-        )
-        return jnp.dot(weights, T)
-
-    grad_fn = jax.grad(smooth_objective)
-    # Temperature scale tied to the warm-start completion spread.
-    T0 = _completion_from_Y(Y0, p_rho, p_tau, releases, inv_R, delta_over_K)
-    temp0 = jnp.maximum(jnp.max(T0) * 0.05, 1e-3)
-
-    def step(carry, t):
-        Y, m, v, best_Y, best_F = carry
-        temp = temp0 * jnp.exp(-4.0 * t / iters) + 1e-3
-        g = grad_fn(Y, temp)
-        m = 0.9 * m + 0.1 * g
-        v = 0.999 * v + 0.001 * g * g
-        mh = m / (1.0 - 0.9 ** (t + 1.0))
-        vh = v / (1.0 - 0.999 ** (t + 1.0))
-        Y = jnp.clip(Y - lr * mh / (jnp.sqrt(vh) + 1e-8), 0.0, 1.0)
-        F = true_objective(Y)
-        better = F < best_F
-        return (
-            Y,
-            m,
-            v,
-            jnp.where(better, Y, best_Y),
-            jnp.where(better, F, best_F),
-        ), F
-
-    init = (Y0, jnp.zeros_like(Y0), jnp.zeros_like(Y0), Y0, true_objective(Y0))
-    (_, _, _, best_Y, best_F), hist = jax.lax.scan(
-        step, init, jnp.arange(iters, dtype=jnp.float32)
-    )
-    T_best = _completion_from_Y(
-        best_Y, p_rho, p_tau, releases, inv_R, delta_over_K
-    )
-    return best_Y, T_best, best_F, hist
-
-
 def warm_start_Y0_dense(
     weights: np.ndarray, glb: np.ndarray, warm_start_order: np.ndarray | None = None
 ) -> np.ndarray:
@@ -343,29 +247,14 @@ def solve_subgradient(
     Returns a *feasible* (Y in box, pair equalities by construction) solution;
     its objective upper-bounds the LP optimum but in practice lands within
     ~1% of HiGHS (see tests/test_lp.py), and the induced order matches the
-    exact order's weighted CCT.
+    exact order's weighted CCT.  This is the batched solver on a one-member
+    ensemble at the instance's exact shape, so it agrees bit for bit with
+    `solve_subgradient_batch` on any exact-shape bucket holding the instance.
     """
-    M = instance.num_coflows
-    rho, tau = port_stats(instance.demands)
-    Y0 = _warm_start_Y0(instance, warm_start_order)
-
-    best_Y, T_best, best_F, _ = _subgradient_run(
-        jnp.asarray(Y0, dtype=jnp.float32),
-        jnp.asarray(rho, dtype=jnp.float32),
-        jnp.asarray(tau, dtype=jnp.float32),
-        jnp.asarray(instance.weights, dtype=jnp.float32),
-        jnp.asarray(instance.releases, dtype=jnp.float32),
-        iters=iters,
-        inv_R=float(1.0 / instance.aggregate_rate),
-        delta_over_K=float(instance.delta / instance.num_cores),
+    (sol,) = solve_subgradient_batch(
+        [instance], iters=iters, warm_start_orders=[warm_start_order]
     )
-    return LPSolution(
-        completion=np.asarray(T_best, dtype=np.float64),
-        precedence=_precedence_from_Y(np.asarray(best_Y, dtype=np.float64)),
-        objective=float(best_F),
-        method="subgradient",
-        iterations=iters,
-    )
+    return dataclasses.replace(sol, method="subgradient")
 
 
 # ---------------------------------------------------------------------------
@@ -386,10 +275,12 @@ def _completion_from_Y_masked(
 ) -> jnp.ndarray:
     """Shape-padded T_m(Y) for one ensemble member (vmapped over B).
 
-    Identical math to `_completion_from_Y` on the real (M, 2N) block:
-    padded coflow rows/columns of X are zeroed (their T comes out exactly 0
-    and their weight is 0), and padded port columns are masked to -inf so
-    they contribute neither to the hard max nor to the smoothed logsumexp.
+    With ``temp`` the hard max over constraint rows is replaced by a
+    temperature-scaled logsumexp (a smooth upper bound), which gives the
+    annealed-smoothing solver useful gradients on plateaus.  Padded coflow
+    rows/columns of X are zeroed (their T comes out exactly 0 and their
+    weight is 0), and padded port columns are masked to -inf so they
+    contribute neither to the hard max nor to the smoothed logsumexp.
     """
     M = Y.shape[0]
     iu = jnp.triu(jnp.ones((M, M), dtype=bool), k=1)
@@ -429,9 +320,11 @@ def _subgradient_run_batch(
 
     Instances are independent, so the gradient of the *summed* smooth
     objective is exactly the stack of per-instance gradients; Adam is
-    elementwise, so each member follows the same trajectory it would in
-    `_subgradient_run`.  Per-instance best-so-far is tracked under the true
-    piecewise-linear objective.
+    elementwise, so each member follows its own trajectory.  The smoothing
+    temperature decays geometrically from ~scale of the objective spread
+    to ~0; per-instance best-so-far is tracked under the true
+    piecewise-linear objective, so the returned point is never worse than
+    the warm start.
     """
 
     comp_hard = jax.vmap(
@@ -619,6 +512,17 @@ def pack_lp_arrays(
     )
 
 
+#: Least number of members a device's block of the batched solve holds
+#: (see `solve_subgradient_batch_arrays`).
+_MIN_MEMBERS_PER_DEVICE = 2
+
+
+def _pad_members(x, Bp: int):
+    """Pad the leading (member) axis of a host or device array with zeros."""
+    pads = [(0, Bp - x.shape[0])] + [(0, 0)] * (x.ndim - 1)
+    return jnp.pad(x, pads) if isinstance(x, jax.Array) else np.pad(x, pads)
+
+
 def solve_subgradient_batch_arrays(
     arrays,
     iters: int = 3000,
@@ -651,16 +555,25 @@ def solve_subgradient_batch_arrays(
             method="subgradient_batch",
             iterations=iters,
         )
-    from repro.launch.mesh import place
+    from repro.launch.mesh import data_axis_size, place
 
+    # XLA drops a batch dimension of one, and the plain matmul it lowers
+    # to accumulates in another order than the batched contraction: a
+    # member's trajectory would then depend on how many members share its
+    # device.  Fully-masked members keep every device's block at two or
+    # more, so results are bit-identical across batch sizes and meshes.
+    n_dev = 1 if sharding is None else data_axis_size(sharding.mesh)
+    Bp = -(-max(B, _MIN_MEMBERS_PER_DEVICE * n_dev) // n_dev) * n_dev
+    if Bp > B:
+        ins = [_pad_members(x, Bp) for x in ins]
     ins = [place(x, sharding) for x in ins]
     best_Y, T_best, best_F, _ = _subgradient_run_batch(*ins, iters=iters)
     # Device-resident (and, under ``sharding``, device-sharded) result;
     # `unpack` / `experiments.results.device_gather` bring it to host.
     return LPSolutionBatch(
-        completion=T_best,
-        y=best_Y,
-        objective=best_F,
+        completion=T_best[:B],
+        y=best_Y[:B],
+        objective=best_F[:B],
         method="subgradient_batch",
         iterations=iters,
     )

@@ -1,30 +1,21 @@
 """Shared helpers for the Pallas TPU kernels.
 
 All kernels target TPU (pl.pallas_call + explicit BlockSpec VMEM tiling) and
-are validated on CPU with ``interpret=True`` — `use_interpret()` flips
-automatically when no TPU is present so the same call sites work in both
-environments.
+are validated on CPU with ``interpret=True``.  The scheduler's own kernel
+(`repro.kernels.event_resolve`) compiles natively unless its caller asks
+for interpret mode; the others still pick it from the default backend
+through `use_interpret()`.
 """
 
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.pallas import tpu as pltpu
 
 # TPU tiling constants: (sublane, lane) min tile for f32 is (8, 128); MXU
 # native matmul tile is 128x128.
 SUBLANE = 8
 LANE = 128
-
-# jax renamed pltpu.TPUCompilerParams -> CompilerParams and moved the
-# scratch-shape constructors under pltpu.MemorySpace; resolve whichever this
-# install provides so the kernels run on both sides of the rename.
-COMPILER_PARAMS = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-if hasattr(pltpu, "MemorySpace"):
-    VMEM_SCRATCH = pltpu.MemorySpace.VMEM
-else:  # pragma: no cover - depends on installed jax
-    VMEM_SCRATCH = pltpu.VMEM
 
 
 def use_interpret() -> bool:

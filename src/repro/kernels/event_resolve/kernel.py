@@ -16,7 +16,8 @@ scatter-free so it maps onto the VPU/MXU:
 
 Grid: one program per member; each member's blocks are read from HBM
 exactly once.  Validated against the jnp oracle (`ref.py`) in interpret
-mode on CPU (`tests/test_kernels.py`).
+mode on CPU (`tests/test_kernels.py`); both kernels compile natively by
+default, and interpret mode is only ever the caller's explicit choice.
 
 Two kernels share this file:
 
@@ -48,11 +49,22 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels.common import LANE, SUBLANE, pad_to, use_interpret
+from repro.kernels.common import LANE, SUBLANE, pad_to
 
 # Pad value for claim matrices: larger than any real flow id or the F
 # sentinel (ids stay < 2**24), exactly representable in f32.
 _CLAIM_PAD = float(1 << 30)
+
+
+def _member_block(ndim: int):
+    """Index map of a one-member block: grid step ``g`` -> ``(g, 0, ...)``.
+
+    The zeros are int32 so the map lowers the same under x64 (the batched
+    calendar calls the pair kernel inside ``jax.enable_x64``): Python int
+    literals would trace as int64 there, and Mosaic refuses an index map
+    returning mixed (i32, i64) block indices.
+    """
+    return lambda g: (g,) + (jnp.int32(0),) * (ndim - 1)
 
 
 def _event_resolve_kernel(
@@ -94,11 +106,13 @@ def event_resolve_pallas(
     free_in: jnp.ndarray,
     free_out: jnp.ndarray,
     t: jnp.ndarray,
-    interpret: bool | None = None,
+    interpret: bool = False,
 ) -> jnp.ndarray:
-    """(G, F) endpoints + (G, N) port state -> (G, F) f32 start mask."""
-    if interpret is None:
-        interpret = use_interpret()
+    """(G, F) endpoints + (G, N) port state -> (G, F) f32 start mask.
+
+    ``interpret`` runs the Pallas interpreter instead of compiling for the
+    TPU (CPU tests).
+    """
     G, F = src.shape
     # Lane-align both the flow axis (contracted through the (Fp, Fp)
     # triangle) and the port axis; padded flows carry mask 0 and padded
@@ -117,15 +131,15 @@ def event_resolve_pallas(
         ),
         grid=(G,),
         in_specs=[
-            pl.BlockSpec((1, f_pad, 1), lambda g: (g, 0, 0)),
-            pl.BlockSpec((1, f_pad, 1), lambda g: (g, 0, 0)),
-            pl.BlockSpec((1, f_pad, 1), lambda g: (g, 0, 0)),
-            pl.BlockSpec((1, f_pad, 1), lambda g: (g, 0, 0)),
-            pl.BlockSpec((1, n_pad), lambda g: (g, 0)),
-            pl.BlockSpec((1, n_pad), lambda g: (g, 0)),
-            pl.BlockSpec((1, 1), lambda g: (g, 0)),
+            pl.BlockSpec((1, f_pad, 1), _member_block(3)),
+            pl.BlockSpec((1, f_pad, 1), _member_block(3)),
+            pl.BlockSpec((1, f_pad, 1), _member_block(3)),
+            pl.BlockSpec((1, f_pad, 1), _member_block(3)),
+            pl.BlockSpec((1, n_pad), _member_block(2)),
+            pl.BlockSpec((1, n_pad), _member_block(2)),
+            pl.BlockSpec((1, 1), _member_block(2)),
         ],
-        out_specs=pl.BlockSpec((1, f_pad, 1), lambda g: (g, 0, 0)),
+        out_specs=pl.BlockSpec((1, f_pad, 1), _member_block(3)),
         out_shape=jax.ShapeDtypeStruct((G, f_pad, 1), jnp.float32),
         interpret=interpret,
         name="event_resolve",
@@ -149,7 +163,7 @@ def _pair_resolve_kernel(claim_ref, idle_ref, start_ref):
 def pair_resolve_pallas(
     claim: jnp.ndarray,
     idle: jnp.ndarray,
-    interpret: bool | None = None,
+    interpret: bool = False,
 ) -> jnp.ndarray:
     """(G, N, N) f32 pair claims + idle mask -> (G, N, N) f32 start mask.
 
@@ -158,10 +172,9 @@ def pair_resolve_pallas(
     ids are unique per member, so a pair starts iff it is idle and its
     claim equals both its row minimum and its column minimum.  Padded
     rows/columns carry ``idle == 0`` and a claim above every real id, so
-    they neither start nor disturb any minimum.
+    they neither start nor disturb any minimum.  ``interpret`` runs the
+    Pallas interpreter instead of compiling for the TPU (CPU tests).
     """
-    if interpret is None:
-        interpret = use_interpret()
     G, N, _ = claim.shape
     claim_p, _ = pad_to(claim.astype(jnp.float32), 1, SUBLANE, value=_CLAIM_PAD)
     claim_p, _ = pad_to(claim_p, 2, LANE, value=_CLAIM_PAD)
@@ -173,10 +186,10 @@ def pair_resolve_pallas(
         _pair_resolve_kernel,
         grid=(G,),
         in_specs=[
-            pl.BlockSpec((1, n_sub, n_lane), lambda g: (g, 0, 0)),
-            pl.BlockSpec((1, n_sub, n_lane), lambda g: (g, 0, 0)),
+            pl.BlockSpec((1, n_sub, n_lane), _member_block(3)),
+            pl.BlockSpec((1, n_sub, n_lane), _member_block(3)),
         ],
-        out_specs=pl.BlockSpec((1, n_sub, n_lane), lambda g: (g, 0, 0)),
+        out_specs=pl.BlockSpec((1, n_sub, n_lane), _member_block(3)),
         out_shape=jax.ShapeDtypeStruct((G, n_sub, n_lane), jnp.float32),
         interpret=interpret,
         name="pair_resolve",

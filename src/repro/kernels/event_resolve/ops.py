@@ -103,12 +103,17 @@ def event_resolve(
     pending: jnp.ndarray,
     t: jnp.ndarray,
     use_kernel: bool = True,
+    interpret: bool = False,
 ) -> jnp.ndarray:
-    """Reserving-round start mask (G, F) bool; Pallas kernel or jnp oracle."""
+    """Reserving-round start mask (G, F) bool; Pallas kernel or jnp oracle.
+
+    ``interpret`` runs the kernel in the Pallas interpreter (CPU tests).
+    """
     _validate_event_resolve(src, dst, rel, free_in, free_out, pending, t)
     if use_kernel:
         out = event_resolve_pallas(
-            src, dst, rel, pending.astype(jnp.float32), free_in, free_out, t
+            src, dst, rel, pending.astype(jnp.float32), free_in, free_out, t,
+            interpret=interpret,
         )
         return out > 0.5
     return event_resolve_ref(src, dst, rel, free_in, free_out, pending, t)
@@ -118,6 +123,7 @@ def pair_resolve(
     claim: jnp.ndarray,
     idle: jnp.ndarray,
     use_kernel: bool = True,
+    interpret: bool = False,
 ) -> jnp.ndarray:
     """Start mask of one pair-space resolution round, (G, N, N) bool.
 
@@ -129,9 +135,11 @@ def pair_resolve(
     `repro.core.circuit.resolve_event`'s first-claimer pass reduced to
     O(N^2) pair space.  All f64 time comparisons stay outside (exact jnp
     selections in the batched calendar), so kernel and oracle agree with
-    the f64 reference bit for bit.
+    the f64 reference bit for bit.  ``use_kernel`` compiles the Pallas
+    kernel for the TPU; ``interpret`` runs it in the Pallas interpreter
+    instead (CPU tests).
     """
     _validate_pair_resolve(claim, idle)
     if use_kernel:
-        return pair_resolve_pallas(claim, idle) > 0.5
+        return pair_resolve_pallas(claim, idle, interpret=interpret) > 0.5
     return pair_resolve_ref(claim, idle)

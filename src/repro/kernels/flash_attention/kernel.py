@@ -23,7 +23,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.common import COMPILER_PARAMS, VMEM_SCRATCH, LANE, round_up, use_interpret
+from repro.kernels.common import LANE, round_up, use_interpret
 
 NEG_INF = -1e30
 
@@ -152,11 +152,11 @@ def flash_attention_pallas(
         out_specs=pl.BlockSpec((1, 1, bq, D), lambda b, h, i, j: (b, h, i, 0)),
         out_shape=jax.ShapeDtypeStruct((B, Hq, Sqp, D), q.dtype),
         scratch_shapes=[
-            VMEM_SCRATCH((bq, D), jnp.float32),
-            VMEM_SCRATCH((bq, LANE), jnp.float32),
-            VMEM_SCRATCH((bq, LANE), jnp.float32),
+            pltpu.VMEM((bq, D), jnp.float32),
+            pltpu.VMEM((bq, LANE), jnp.float32),
+            pltpu.VMEM((bq, LANE), jnp.float32),
         ],
-        compiler_params=COMPILER_PARAMS(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

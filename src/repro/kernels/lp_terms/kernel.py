@@ -25,9 +25,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.common import (
-    COMPILER_PARAMS,
     LANE,
-    VMEM_SCRATCH,
     pad_to,
     round_up,
     use_interpret,
@@ -150,8 +148,8 @@ def lp_terms_batch_pallas(
             pl.BlockSpec((1, block_m, LANE), lambda b, m, k, *_: (b, m, 0)),
         ],
         scratch_shapes=[
-            VMEM_SCRATCH((block_m, Pp), jnp.float32),
-            VMEM_SCRATCH((block_m, Pp), jnp.float32),
+            pltpu.VMEM((block_m, Pp), jnp.float32),
+            pltpu.VMEM((block_m, Pp), jnp.float32),
         ],
     )
     load, rec = pl.pallas_call(
@@ -161,7 +159,7 @@ def lp_terms_batch_pallas(
             jax.ShapeDtypeStruct((B, Mp, LANE), jnp.float32),
             jax.ShapeDtypeStruct((B, Mp, LANE), jnp.float32),
         ],
-        compiler_params=COMPILER_PARAMS(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -228,10 +226,10 @@ def lp_terms_pallas(
             jax.ShapeDtypeStruct((Mp, LANE), jnp.float32),
         ],
         scratch_shapes=[
-            VMEM_SCRATCH((block_m, Pp), jnp.float32),
-            VMEM_SCRATCH((block_m, Pp), jnp.float32),
+            pltpu.VMEM((block_m, Pp), jnp.float32),
+            pltpu.VMEM((block_m, Pp), jnp.float32),
         ],
-        compiler_params=COMPILER_PARAMS(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,

@@ -29,7 +29,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.common import COMPILER_PARAMS, VMEM_SCRATCH, use_interpret
+from repro.kernels.common import use_interpret
 
 
 def _mlstm_kernel(
@@ -137,10 +137,10 @@ def mlstm_chunk_pallas(
             jax.ShapeDtypeStruct((BH, 1, Dh), jnp.float32),
         ],
         scratch_shapes=[
-            VMEM_SCRATCH((Dh, Dh), jnp.float32),
-            VMEM_SCRATCH((1, Dh), jnp.float32),
+            pltpu.VMEM((Dh, Dh), jnp.float32),
+            pltpu.VMEM((1, Dh), jnp.float32),
         ],
-        compiler_params=COMPILER_PARAMS(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,

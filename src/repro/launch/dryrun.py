@@ -51,7 +51,7 @@ def run_cell(
     from repro.configs import SHAPES, get_arch
     from repro.launch.mesh import make_production_mesh
     from repro.launch.roofline import (
-        HW, collective_bytes, model_flops, roofline_terms,
+        V5E, collective_bytes, model_flops, roofline_terms,
     )
     from repro.launch.sharding import ShardingRules, activate
     from repro.launch.specs import (
@@ -139,7 +139,7 @@ def run_cell(
     coll["total"] = cost.collective_total
     flops = cost.flops
     bytes_accessed = cost.bytes
-    terms = roofline_terms(flops, bytes_accessed, coll["total"])
+    terms = roofline_terms(flops, bytes_accessed, coll["total"], V5E)
     mf = model_flops(cfg, shape)
     useful = mf / max(flops * n_chips, 1e-30)
     result = {

@@ -25,6 +25,20 @@ __all__ = [
 ]
 
 
+def _auto_mesh(shape, axes, devices=None):
+    """`jax.make_mesh` with Auto axis types on every axis.
+
+    jax defaults to Explicit axes, which make every array's sharding part
+    of its type; the batched stages place inputs with `NamedSharding`s and
+    leave propagation to the compiler, which needs Auto.
+    """
+    from jax.sharding import AxisType
+
+    return jax.make_mesh(
+        shape, axes, axis_types=(AxisType.Auto,) * len(axes), devices=devices
+    )
+
+
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
@@ -33,9 +47,9 @@ def make_production_mesh(*, multi_pod: bool = False):
         n *= s
     devices = jax.devices()
     if len(devices) == n:
-        return jax.make_mesh(shape, axes)
+        return _auto_mesh(shape, axes)
     if len(devices) > n:  # dry-run forces 512; single-pod uses the first 256
-        return jax.make_mesh(shape, axes, devices=devices[:n])
+        return _auto_mesh(shape, axes, devices=devices[:n])
     raise RuntimeError(
         f"need {n} devices for mesh {shape}, have {len(devices)} — run under "
         "the dry-run entrypoint (XLA_FLAGS=--xla_force_host_platform_device_count=512)"
@@ -49,7 +63,7 @@ def make_local_mesh():
     ``XLA_FLAGS=--xla_force_host_platform_device_count=N`` forces more).
     """
     n = len(jax.devices())
-    return jax.make_mesh((n, 1), ("data", "model"))
+    return _auto_mesh((n, 1), ("data", "model"))
 
 
 def mesh_axis_sizes(mesh) -> dict[str, int]:

@@ -21,21 +21,25 @@ from pathlib import Path
 __all__ = ["measured_roofline", "main"]
 
 
-def measured_roofline(hlo_text: str, measured_s: float, hw=None) -> dict:
+def measured_roofline(
+    hlo_text: str, measured_s: float, device_kind: str
+) -> dict:
     """Roofline terms + achieved fraction for one compiled program.
 
     ``hlo_text`` is the post-compile HLO (``lowered.compile().as_text()``);
-    ``measured_s`` the measured wall time of one execution.  Returns the
-    `roofline_terms` dict extended with the static counts and
-    ``roofline_frac = bound_s / measured_s`` (1.0 == at the hardware
-    roofline; tiny values == latency/overhead bound).
+    ``measured_s`` the measured wall time of one execution on a device of
+    ``device_kind`` (whose peaks `repro.launch.roofline.hardware` looks
+    up; an unknown kind raises).  Returns the `roofline_terms` dict
+    extended with the static counts and ``roofline_frac = bound_s /
+    measured_s`` (1.0 == at the hardware roofline; tiny values ==
+    latency/overhead bound).
     """
     from repro.launch import hlo_cost, roofline
 
     cost = hlo_cost.analyze(hlo_text)
     terms = roofline.roofline_terms(
         cost.flops, cost.bytes, cost.collective_total,
-        hw=hw if hw is not None else roofline.HW,
+        roofline.hardware(device_kind),
     )
     terms["flops"] = cost.flops
     terms["bytes"] = cost.bytes

@@ -11,8 +11,8 @@ cost_analysis does not expose collective traffic, so collective bytes are
 parsed from the post-SPMD HLO text: for every all-reduce / all-gather /
 reduce-scatter / all-to-all / collective-permute op we sum the output
 operand bytes (all-reduce counted twice — ring RS+AG moves ~2x the payload).
-Hardware constants: TPU v5e-class — 197 TFLOP/s bf16, 819 GB/s HBM,
-~50 GB/s/link ICI (per task spec).
+Hardware constants come from `PEAKS`, keyed by the device kind JAX
+reports; a device that is not in the table is an error, never a default.
 """
 
 from __future__ import annotations
@@ -21,8 +21,10 @@ import dataclasses
 import re
 
 __all__ = [
-    "HW",
+    "PEAKS",
+    "V5E",
     "Hardware",
+    "hardware",
     "collective_bytes",
     "roofline_terms",
     "roofline_fraction",
@@ -32,12 +34,33 @@ __all__ = [
 
 @dataclasses.dataclass(frozen=True)
 class Hardware:
-    peak_flops: float = 197e12  # bf16 per chip
-    hbm_bw: float = 819e9  # bytes/s per chip
-    ici_bw: float = 50e9  # bytes/s per link
+    peak_flops: float  # bf16 FLOP/s per chip
+    hbm_bw: float  # bytes/s per chip
+    ici_bw: float  # bytes/s per link
 
 
-HW = Hardware()
+#: Published per-chip peaks, keyed by `jax.Device.device_kind`.
+#: TPU v5e — Google Cloud documentation, "TPU v5e": 197 TFLOP/s bf16,
+#: 16 GB of HBM at 819 GB/s, 1,600 Gbit/s of inter-chip interconnect
+#: (four links of ~50 GB/s).
+PEAKS = {
+    "TPU v5 lite": Hardware(peak_flops=197e12, hbm_bw=819e9, ici_bw=50e9),
+}
+
+
+def hardware(device_kind: str) -> Hardware:
+    """Peaks of one device kind; unknown kinds raise `ValueError`."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no published peaks for device kind {device_kind!r}; add them "
+            "to repro.launch.roofline.PEAKS with their source"
+        ) from None
+
+
+#: The chip the dry-run's described pods are made of.
+V5E = PEAKS["TPU v5 lite"]
 
 _DTYPE_BYTES = {
     "pred": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2, "bf16": 2, "f16": 2,
@@ -91,7 +114,7 @@ def roofline_terms(
     device_flops: float,
     device_bytes: float,
     device_collective_bytes: float,
-    hw: Hardware = HW,
+    hw: Hardware,
 ) -> dict[str, float]:
     compute = device_flops / hw.peak_flops
     memory = device_bytes / hw.hbm_bw

@@ -30,11 +30,13 @@ SPMD across the member axis.  The padding mirrors the masking scheme of
   * padded members (sharding round-up) have no valid flows and no real
     cores — pure no-ops.
 
-The scan runs in float64 (locally enabled x64) and performs the same
-floating-point operations in the same order as the NumPy oracle, so core
-choices, prefix port stats and prefix lower bounds are **bit-identical**
-to `allocate` — asserted per scheme and per flow table by
-`tests/test_pipeline.py`.  `allocate_batch` is the list-in/list-out
+The scan carries every size, load and bound as the int64 bit pattern of
+its double (locally enabled x64) and performs the NumPy oracle's
+floating-point operations, in the same order, with the exact integer
+arithmetic of `repro.pipeline.exact64` (XLA:TPU only emulates f64, not
+to the last bit), so core choices, prefix port stats and prefix lower
+bounds are **bit-identical** to `allocate` on every backend — asserted
+per scheme and per flow table by `tests/test_pipeline.py`.  `allocate_batch` is the list-in/list-out
 wrapper (build one `EnsembleBatch`, run the array form, materialize
 `Allocation`s) kept for oracle tests and loop-path callers.
 """
@@ -47,7 +49,6 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import enable_x64
 
 from repro.core.allocation import Allocation
 from repro.core.coflow import CoflowInstance, flows_of
@@ -57,6 +58,7 @@ from repro.pipeline.ensemble_batch import (
     EnsembleBatch,
     build_ensemble_batch,
 )
+from repro.pipeline.exact64 import NEG_INF, ONE, add, from_bits, mul, to_bits
 
 __all__ = ["allocate_batch", "allocate_batch_arrays", "flow_sequence"]
 
@@ -102,37 +104,38 @@ def flow_sequence(
 
 
 @jax.jit
-def _scan_all(pi, pj, d, valid, inv_rates, delta, one, lb0, core_mask, rho0, tau0):
+def _scan_all(pi, pj, d, valid, inv_rates, delta, lb0, core_mask, rho0, tau0):
     """Run the allocation recurrence for the whole padded ensemble.
 
-    Shapes: pi/pj (B, F) int32 flat-port endpoints, d (B, F) f64 sizes,
-    valid (B, F) bool, inv_rates/lb0/core_mask (B, Kmax), delta/one (B,)
-    f64, rho0/tau0 (B, Kmax, Pmax) f64.  Returns per-step core choices and
-    real-core lb maxima plus the final (rho, tau) port stats.
-
-    ``one`` holds runtime 1.0s: XLA:CPU contracts ``p + q`` with a product
-    operand into a single-rounding FMA, which drifts the lower bounds by
-    1 ulp off the NumPy oracle.  Multiplying each product by a value the
-    compiler cannot prove is 1.0 leaves only ``fma(p, 1.0, q)`` as a legal
-    contraction — bitwise equal to the separately-rounded ``p + q``.
+    Shapes: pi/pj (B, F) int32 flat-port endpoints, d (B, F) sizes,
+    valid (B, F) bool, inv_rates/lb0/core_mask (B, Kmax), delta (B,),
+    rho0/tau0 (B, Kmax, Pmax).  Every real-valued input and output is the
+    int64 bit pattern of a non-negative double (`repro.pipeline.exact64`).
+    Returns per-step core choices and real-core lb maxima plus the final
+    (rho, tau) port stats.
     """
 
-    def member(pi, pj, d, valid, inv_rates, delta, one, lb0, core_mask, rho0, tau0):
+    def member(pi, pj, d, valid, inv_rates, delta, lb0, core_mask, rho0, tau0):
+        def bump(x, k, p, by):  # x[k, p] += by, rounded as NumPy rounds
+            return x.at[k, p].set(add(x[k, p], by))
+
         def step(carry, x):
             rho, tau, lb = carry
             i, j, dd, v = x
             # Candidate LB on every core if this flow lands there — the
-            # exact expressions (and rounding) of the NumPy oracle.
-            li = (rho[:, i] + dd) * inv_rates * one + (tau[:, i] + 1.0) * delta * one
-            lj = (rho[:, j] + dd) * inv_rates * one + (tau[:, j] + 1.0) * delta * one
+            # NumPy oracle's expression and rounding, step for step.
+            li = add(mul(add(rho[:, i], dd), inv_rates),
+                     mul(add(tau[:, i], ONE), delta))
+            lj = add(mul(add(rho[:, j], dd), inv_rates),
+                     mul(add(tau[:, j], ONE), delta))
             cand = jnp.maximum(lb, jnp.maximum(li, lj))
             k = jnp.argmin(cand)
-            dv = jnp.where(v, dd, 0.0)
-            ov = jnp.where(v, 1.0, 0.0)
-            rho = rho.at[k, i].add(dv).at[k, j].add(dv)
-            tau = tau.at[k, i].add(ov).at[k, j].add(ov)
+            dv = jnp.where(v, dd, 0)
+            ov = jnp.where(v, ONE, 0)
+            rho = bump(bump(rho, k, i, dv), k, j, dv)
+            tau = bump(bump(tau, k, i, ov), k, j, ov)
             lb = lb.at[k].set(jnp.where(v, cand[k], lb[k]))
-            lb_real = jnp.max(jnp.where(core_mask, lb, -jnp.inf))
+            lb_real = jnp.max(jnp.where(core_mask, lb, NEG_INF))
             return (rho, tau, lb), (k, lb_real)
 
         (rho, tau, _), (ks, lbs) = jax.lax.scan(
@@ -141,7 +144,7 @@ def _scan_all(pi, pj, d, valid, inv_rates, delta, one, lb0, core_mask, rho0, tau
         return ks, lbs, rho, tau
 
     return jax.vmap(member)(
-        pi, pj, d, valid, inv_rates, delta, one, lb0, core_mask, rho0, tau0
+        pi, pj, d, valid, inv_rates, delta, lb0, core_mask, rho0, tau0
     )
 
 
@@ -182,23 +185,22 @@ def allocate_batch_arrays(
         tau = np.zeros((Bp, Kp, Pp))
         prefix_lb = np.zeros(ends.shape)
     else:
-        zeros_kp = np.zeros((Bp, Kp, Pp))
-        with enable_x64():
+        zeros_kp = np.zeros((Bp, Kp, Pp), dtype=np.int64)
+        with jax.enable_x64():
             from repro.launch.mesh import place
 
             put = lambda x: place(x, ensemble.sharding)  # noqa: E731
             ks, lbs, rho, tau = _scan_all(
                 put(pi.astype(np.int32)), put(pj.astype(np.int32)),
-                put(size), put(valid),
-                put(ensemble.inv_rates), put(delta),
-                put(np.ones(Bp, dtype=np.float64)),
-                put(lb0), put(ensemble.core_mask),
+                put(to_bits(size)), put(valid),
+                put(to_bits(ensemble.inv_rates)), put(to_bits(delta)),
+                put(to_bits(lb0)), put(ensemble.core_mask),
                 put(zeros_kp), put(zeros_kp),
             )
         core = np.asarray(ks).astype(np.int64)
-        lbs = np.asarray(lbs)
-        rho = np.asarray(rho)
-        tau = np.asarray(tau)
+        lbs = from_bits(lbs)
+        rho = from_bits(rho)
+        tau = from_bits(tau)
         # lb starts all-zero, so before any flow lands the prefix LB is 0.
         prefix_lb = np.where(
             ends > 0,

@@ -16,9 +16,9 @@ executors behind `schedule_batch`:
     clock advance, a single dispatch per round with donated calendar
     buffers) reduces the wide engine's per-(ingress, egress)-pair
     head-pointer layout; the per-round reduction is the
-    `repro.kernels.event_resolve.pair_resolve` Pallas kernel on native
-    TPU (the jnp pair oracle elsewhere, warned once).  A round scans
-    O(N^2) active pairs instead of O(F) flows.
+    `repro.kernels.event_resolve.pair_resolve` Pallas kernel, compiled
+    natively on TPU (the jnp pair oracle on other backends, warned once).
+    A round scans O(N^2) active pairs instead of O(F) flows.
   * ``"jax"`` — the vmapped per-member `lax.while_loop` in flow space
     (`_run_calendar`), kept as the segment-min reference program;
   * ``"wide"`` — the lockstep NumPy pair engine (`_run_calendar_wide`),
@@ -68,11 +68,14 @@ Padding semantics mirror `batch_alloc`:
     iteration zero;
   * padded ports are never indexed by real flows.
 
-All times are f64 (locally enabled x64) and the per-round operations are
-pure selections (compares, min/max, ``t + dur`` with ``dur`` precomputed
-exactly as the oracle's ``delta + size / rate``), so establishment and
-completion times are **bit-identical** to `schedule_core` on both
-disciplines — fuzz-asserted by `tests/test_batch_circuit.py`.
+All times are the int64 bit patterns of non-negative doubles (locally
+enabled x64; `repro.pipeline.exact64`) and the per-round operations are
+pure selections (compares, min/max on the patterns) plus ``t + dur``, an
+exact IEEE addition in integer arithmetic (XLA:TPU only emulates f64,
+not to the last bit), with ``dur`` precomputed on the host exactly as
+the oracle's ``delta + size / rate``; so establishment and completion
+times are **bit-identical** to `schedule_core` on both disciplines and
+every backend — fuzz-asserted by `tests/test_batch_circuit.py`.
 
 Shapes are rounded up to small quanta so repeated sweeps, schemes and
 disciplines over similar ensembles reuse one compiled program per padded
@@ -90,13 +93,13 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import enable_x64
 
 from repro.core.allocation import Allocation
 from repro.core.circuit import NOT_SCHEDULED, CoreSchedule
 from repro.core.coflow import CoflowInstance
 from repro.core.validate import ccts_from_schedules
 from repro.pipeline.ensemble_batch import AllocationBatch, EnsembleBatch
+from repro.pipeline.exact64 import INF, NEG_INF, add, from_bits, to_bits
 
 __all__ = [
     "schedule_batch",
@@ -110,11 +113,17 @@ __all__ = [
 #: Calendar executors selectable via ``engine=`` (plus ``"auto"``).
 _ENGINES = ("jax", "wide", "kernel")
 
-#: Test hook: force the Pallas pair kernel on (True, interpret mode off
-#: TPU) or off (False) regardless of backend; None follows the backend.
-_PAIR_KERNEL_OVERRIDE: bool | None = None
+#: Test hook: run the kernel engine's Pallas round in the Pallas
+#: interpreter, so CPU tests execute the program the TPU compiles.
+_PAIR_KERNEL_INTERPRET = False
+
+#: The claim matrix carries flow ids in f32 lanes: exact below 2**24.
+_MAX_KERNEL_FLOWS = 1 << 24
 
 _KERNEL_FALLBACK_WARNED = False
+
+#: `NOT_SCHEDULED` as the device programs carry it (an int64 pattern).
+_UNSCHEDULED = int(np.float64(NOT_SCHEDULED).view(np.int64))
 
 # Bucket quanta: flows, ports and members round up to these so that
 # near-shaped ensembles (e.g. the same sweep under both disciplines, or
@@ -202,6 +211,36 @@ def _port_segments(keys: np.ndarray, n_pad: int):
     return perm, offs, segend, segempty
 
 
+def _pair_segments(keys: np.ndarray, num_pairs: int):
+    """Pair-sorted layout of one bucket for the kernel engine.
+
+    ``keys`` (G, Fmax) holds each flow's pair ``src * Nmax + dst``
+    (``num_pairs`` for padded flows, which sort last).  Returns ``perm``
+    (G, Fmax) i32 — the stable sort by pair, so a pair's flows keep their
+    priority order — and ``first`` / ``last`` (G, num_pairs) i32, each
+    pair's first and last sorted position (``last`` -1 for an empty pair).
+    """
+    G, F = keys.shape
+    perm = np.argsort(keys, axis=1, kind="stable").astype(np.int32)
+    sorted_keys = np.take_along_axis(keys, perm, axis=1)
+    pairs = np.arange(num_pairs)
+    first = np.empty((G, num_pairs), dtype=np.int32)
+    last = np.empty((G, num_pairs), dtype=np.int32)
+    for g in range(G):
+        left = np.searchsorted(sorted_keys[g], pairs, side="left")
+        right = np.searchsorted(sorted_keys[g], pairs, side="right")
+        first[g] = np.minimum(left, F - 1)
+        last[g] = np.where(right > left, right - 1, -1)
+    return perm, first, last
+
+
+def _unsort(a: np.ndarray, perm: np.ndarray) -> np.ndarray:
+    """Pair-sorted (G, Fmax) outputs back to flow order."""
+    out = np.empty_like(a)
+    np.put_along_axis(out, perm, a, axis=1)
+    return out
+
+
 @functools.partial(jax.jit, static_argnames=("reserving", "bound"))
 def _run_calendar(
     src, dst, rel, dur, pending0, free0,
@@ -210,10 +249,11 @@ def _run_calendar(
 ):
     """Execute the padded event calendar for all members.
 
-    Shapes: src/dst/psrc/pdst (G, Fmax) i32, rel/dur (G, Fmax) f64,
-    pending0 (G, Fmax) bool, free0 (G, Nmax) f64 zeros, soff/doff
-    (G, Fmax) i32, send/dend (G, Nmax) i32, sempty/dempty (G, Nmax) bool.
-    Returns (establish, complete) (G, Fmax) f64 plus per-member
+    Shapes: src/dst/psrc/pdst (G, Fmax) i32, rel/dur (G, Fmax) i64
+    double patterns, pending0 (G, Fmax) bool, free0 (G, Nmax) i64 zeros,
+    soff/doff (G, Fmax) i32, send/dend (G, Nmax) i32, sempty/dempty
+    (G, Nmax) bool.
+    Returns (establish, complete) (G, Fmax) i64 patterns plus per-member
     ``unfinished`` / ``stalled`` flags (bound exhausted / no event time
     could advance the clock — both impossible for well-formed inputs,
     checked on host).
@@ -225,7 +265,7 @@ def _run_calendar(
     def member(src, dst, rel, dur, pending0, free0,
                psrc, soff, send, sempty, pdst, doff, dend, dempty):
         ar = jnp.arange(F, dtype=jnp.int32)
-        t0 = jnp.min(jnp.where(pending0, rel, jnp.inf))
+        t0 = jnp.min(jnp.where(pending0, rel, INF))
 
         def first_claimer(claim, perm, offs, segend, segempty):
             # Exclusive segment-min of claiming flow indices per port:
@@ -249,17 +289,17 @@ def _run_calendar(
             fj = first_claimer(claim, pdst, doff, dend, dempty)
             start = idle & (ar == fi[src]) & (ar == fj[dst])
             est = jnp.where(start, t, est)
-            comp = jnp.where(start, t + dur, comp)
+            comp = jnp.where(start, add(t, dur), comp)
             # Only a port's first claimer can have started; if it did, the
             # port frees at that flow's completion — two (Nmax,) gathers
             # instead of a scatter.
             fic = jnp.clip(fi, 0, F - 1)
             fjc = jnp.clip(fj, 0, F - 1)
             free_in = jnp.where(
-                (fi < F) & start[fic], t + dur[fic], free_in
+                (fi < F) & start[fic], add(t, dur[fic]), free_in
             )
             free_out = jnp.where(
-                (fj < F) & start[fjc], t + dur[fjc], free_out
+                (fj < F) & start[fjc], add(t, dur[fjc]), free_out
             )
             pending = pending & ~start
             # Advance fuses into this iteration unless another round at t
@@ -267,7 +307,7 @@ def _run_calendar(
             # waiting flow (reserving), and any idle-but-blocked leftover
             # may start once its blocker is gone (greedy).
             if reserving:
-                advance = ~jnp.any(start & (dur == 0.0))
+                advance = ~jnp.any(start & (dur == 0))
             else:
                 advance = ~jnp.any(idle & ~start)
             times = jnp.where(
@@ -275,10 +315,10 @@ def _run_calendar(
                 jnp.maximum(
                     rel, jnp.maximum(free_in[src], free_out[dst])
                 ),
-                jnp.inf,
+                INF,
             )
-            t_next = jnp.min(jnp.where(times > t, times, jnp.inf))
-            stall = advance & jnp.any(pending) & jnp.isinf(t_next)
+            t_next = jnp.min(jnp.where(times > t, times, INF))
+            stall = advance & jnp.any(pending) & (t_next == INF)
             t = jnp.where(advance, t_next, t)
             return (
                 free_in, free_out, est, comp, pending, t, it + 1,
@@ -288,8 +328,8 @@ def _run_calendar(
         init = (
             free0,
             free0,
-            jnp.full((F,), NOT_SCHEDULED, rel.dtype),
-            jnp.full((F,), NOT_SCHEDULED, rel.dtype),
+            jnp.full((F,), _UNSCHEDULED, rel.dtype),
+            jnp.full((F,), _UNSCHEDULED, rel.dtype),
             pending0,
             t0,
             jnp.int32(0),
@@ -306,8 +346,8 @@ def _run_calendar(
 
 
 def _run_calendar_pairs_impl(
-    src, dst, rel, dur, pending0, free0, pairid, pperm, poffs, psend, psempty,
-    reserving, bound, use_kernel,
+    rel, dur, pending0, free0, ids, pfirst, plast,
+    reserving, bound, use_kernel, interpret=False,
 ):
     """The ``engine="kernel"`` executor: one lockstep pair-space calendar.
 
@@ -316,132 +356,156 @@ def _run_calendar_pairs_impl(
     sequentially, and only each pair's head (first waiting flow) can ever
     claim or start — so the whole batch advances through ONE
     `lax.while_loop` whose round body is a single fused dispatch (claim
-    -> `pair_resolve` -> start/complete writes -> clock advance) over
-    (G, P = Nmax^2) pair state instead of a vmap of per-member loops over
-    (Fmax,) flow state.
+    -> `pair_resolve` -> start writes -> clock advance) over (G, P =
+    Nmax^2) pair state instead of a vmap of per-member loops over (Fmax,)
+    flow state.
 
-    Heads are stateless: each round recomputes every pair's first waiting
-    flow as an exclusive segment-min over the pair-sorted flow axis (the
-    same presorted-`cummin` scheme `_run_calendar` uses per port, with
-    pairs as segments), which eliminates the wide engine's head-rewind
-    bookkeeping at release crossings.  The per-round reduction — idle &
-    row-first & col-first over the (G, N, N) claim matrix — is the
-    `repro.kernels.event_resolve.pair_resolve` Pallas kernel when
-    ``use_kernel`` (native TPU), else its jnp oracle; both reduce exact
-    integer ids, so either way every f64 comparison stays in exact jnp
-    selections and CCTs remain bit-identical to `schedule_core`.
+    Flows arrive in pair-sorted order (grouped by pair, priority order
+    within a pair; ``ids`` holds each position's priority id), so a round
+    touches flow space only through elementwise passes, scans, gathers of
+    P values and one scatter of P values — never a gather of Fmax values,
+    which costs ~8 ns per element on a TPU v5e:
 
-    Shapes: src/dst/pairid/pperm/poffs (G, Fmax) i32 (``pairid`` holds
-    ``src * Nmax + dst``, P for padded flows), rel/dur (G, Fmax) f64,
-    pending0 (G, Fmax) bool, free0 (G, Nmax) f64 zeros, psend/psempty
-    (G, P).  Returns (establish, complete, unfinished, stalled) exactly
-    like `_run_calendar`.
+      * heads are stateless: each pair's first waiting position is a
+        suffix `cummin` of waiting positions read at the pair's first
+        position (no head-rewind bookkeeping at release crossings);
+      * the round reduction — idle & row-first & col-first over the
+        (G, N, N) claim matrix — is the `repro.kernels.event_resolve.
+        pair_resolve` Pallas kernel when ``use_kernel`` (compiled for the
+        TPU, or run by the Pallas interpreter with ``interpret``), else
+        its jnp oracle; both reduce exact integer ids;
+      * started heads are scattered back to flow space; completions are
+        ``establish + dur`` after the loop;
+      * the next event time is the least of (a) the post-round free time
+        of each pair that still has a waiting flow, where later than t,
+        and (b) the least pending release later than t.  That is the
+        flow-space calendar's next event (`_run_calendar`), or a release
+        instant before it at which every pending flow's earliest start is
+        still later — a round that starts nothing, as the wide engine's
+        release stops.
+
+    Every time comparison stays in exact selections on double patterns,
+    so CCTs remain bit-identical to `schedule_core`.
+
+    Shapes: rel/dur (G, Fmax) i64 double patterns and pending0 (G, Fmax)
+    bool, all pair-sorted; free0 (G, Nmax) i64 zeros; ids (G, Fmax) i32;
+    pfirst/plast (G, P) i32 — each pair's first and last sorted position
+    (``plast`` -1 for a pair without flows).  Returns (establish,
+    complete) (G, Fmax) i64 patterns in pair-sorted order and the
+    per-member ``unfinished`` / ``stalled`` flags of `_run_calendar`.
     """
     from repro.kernels.event_resolve import pair_resolve
 
-    G, F = src.shape
+    G, F = rel.shape
     N = free0.shape[1]
     P = N * N
     ar = jnp.arange(F, dtype=jnp.int32)
-    arp = jnp.arange(P, dtype=jnp.int32)
-    pair_off = ((P - arp) * (F + 1)).astype(jnp.int32)
-    PI = arp // N  # static pair -> ingress port
-    PJ = arp % N  # static pair -> egress port
-    pairc = jnp.clip(pairid, 0, P - 1)
+
+    def ports(free_in, free_out):
+        # Each pair's ingress and egress free times, (G, P) each: pair
+        # (i, j) sits at i * N + j, so these are broadcasts, not gathers.
+        return (
+            jnp.broadcast_to(free_in[:, :, None], (G, N, N)).reshape(G, P),
+            jnp.broadcast_to(free_out[:, None, :], (G, N, N)).reshape(G, P),
+        )
+
+    # One gather of P rows reads a head's priority id, its duration (the
+    # int64 pattern as two 32-bit halves) and the next waiting position
+    # after it: on a TPU a gather costs per index, not per byte.
+    head_rows = jnp.stack(
+        [ids, dur.astype(jnp.int32), (dur >> 32).astype(jnp.int32)], axis=-1
+    )
+
+    def mark(pos):
+        # (G, F) mask of the positions in ``pos`` (G, P); F is dropped.
+        return jax.vmap(
+            lambda p: jnp.zeros((F,), bool).at[p].set(True, mode="drop")
+        )(pos)
 
     def cond(carry):
-        _, _, _, _, pending, _, it, stalled = carry
+        _, _, _, pending, _, it, stalled = carry
         return jnp.any(pending & ~stalled[:, None]) & (it < bound)
 
     def body(carry):
-        free_in, free_out, est, comp, pending, t, it, stalled = carry
+        free_in, free_out, est, pending, t, it, stalled = carry
         t_ = t[:, None]
         waiting = pending & (rel <= t_) & ~stalled[:, None]
-        # Pair heads: exclusive segment-min of waiting flow ids over the
-        # pair-sorted flow axis (descending per-segment offsets keep the
-        # running cummin from leaking across pair boundaries).
-        w = jnp.where(jnp.take_along_axis(waiting, pperm, 1), pperm, F) + poffs
-        cm = jax.lax.cummin(w, axis=1)
-        cand = jnp.where(
-            psempty, F, jnp.take_along_axis(cm, psend, 1) - pair_off[None, :]
+        # Pair heads: the first waiting position at or after each pair's
+        # first position, if it is still inside the pair.
+        nxt = jax.lax.cummin(jnp.where(waiting, ar, F), axis=1, reverse=True)
+        head = jnp.take_along_axis(nxt, pfirst, 1)
+        head = jnp.where(head <= plast, head, F)
+        headc = jnp.minimum(head, F - 1)
+        has = head < F
+        # The waiting position after each position rides in the same gather.
+        after = jnp.concatenate([nxt[:, 1:], jnp.full((G, 1), F, nxt.dtype)], 1)
+        rows = jax.vmap(lambda r, h: r[h])(
+            jnp.concatenate([head_rows, after[..., None]], axis=-1), headc
         )
-        candc = jnp.clip(cand, 0, F - 1)
-        has = cand < F
-        idle = (
-            has
-            & (jnp.take(free_in, PI, axis=1) <= t_)
-            & (jnp.take(free_out, PJ, axis=1) <= t_)
+        dur_p = (rows[..., 2].astype(jnp.int64) << 32) | (
+            rows[..., 1].astype(jnp.int64) & 0xFFFFFFFF
         )
+        fi, fo = ports(free_in, free_out)
+        idle = has & (fi <= t_) & (fo <= t_)
         claim = has if reserving else idle
-        claimf = jnp.where(claim, cand, F).astype(jnp.float32)
+        claimf = jnp.where(claim, rows[..., 0], F).astype(jnp.float32)
         startp = pair_resolve(
             claimf.reshape(G, N, N),
             idle.reshape(G, N, N),
             use_kernel=use_kernel,
+            interpret=interpret,
         ).reshape(G, P)
-        # Gather back to flow space: a flow starts iff its pair started
-        # and it is that pair's head this round.
-        sflow = jnp.take_along_axis(startp, pairc, 1) & (
-            jnp.take_along_axis(cand, pairc, 1) == ar[None, :]
-        )
+        sflow = mark(jnp.where(startp, head, F))
         est = jnp.where(sflow, t_, est)
-        comp = jnp.where(sflow, t_ + dur, comp)
         pending = pending & ~sflow
         # Port frees via (G, N, N) row/column max reductions — at most one
         # pair per row/column starts, so the max picks its completion.
-        dur_p = jnp.take_along_axis(dur, candc, 1)
-        ev = jnp.where(startp, t_ + dur_p, -jnp.inf).reshape(G, N, N)
+        ev = jnp.where(startp, add(t_, dur_p), NEG_INF).reshape(G, N, N)
         sm = startp.reshape(G, N, N)
         free_in = jnp.where(sm.any(2), ev.max(2), free_in)
         free_out = jnp.where(sm.any(1), ev.max(1), free_out)
         # Advance unless another round at this t is possible: a
         # zero-duration start chains its pair's next flow, and (greedy) an
         # idle-but-blocked pair may start once its blocker started.
-        chained = jnp.any(startp & (dur_p == 0.0), axis=1)
+        chained = jnp.any(startp & (dur_p == 0), axis=1)
         if reserving:
             more = chained
         else:
             more = chained | jnp.any(idle & ~startp, axis=1)
         advance = ~more
-        times = jnp.where(
-            pending,
-            jnp.maximum(
-                rel,
-                jnp.maximum(
-                    jnp.take_along_axis(free_in, src, 1),
-                    jnp.take_along_axis(free_out, dst, 1),
-                ),
-            ),
-            jnp.inf,
+        # A pair still waits after the round unless it started its only
+        # waiting flow: the next waiting position past a started head.
+        still = has & (~startp | (rows[..., 3] <= plast))
+        pfree = jnp.maximum(*ports(free_in, free_out))
+        t_next = jnp.minimum(
+            jnp.min(jnp.where(still & (pfree > t_), pfree, INF), axis=1),
+            jnp.min(jnp.where(pending & (rel > t_), rel, INF), axis=1),
         )
-        t_next = jnp.min(jnp.where(times > t_, times, jnp.inf), axis=1)
         alive = jnp.any(pending, axis=1)
-        stall = advance & alive & jnp.isinf(t_next) & ~stalled
-        t = jnp.where(advance & jnp.isfinite(t_next) & ~stalled, t_next, t)
-        return (
-            free_in, free_out, est, comp, pending, t, it + 1, stalled | stall,
-        )
+        stall = advance & alive & (t_next == INF) & ~stalled
+        t = jnp.where(advance & (t_next < INF) & ~stalled, t_next, t)
+        return free_in, free_out, est, pending, t, it + 1, stalled | stall
 
     init = (
         free0,
         free0,
-        jnp.full((G, F), NOT_SCHEDULED, rel.dtype),
-        jnp.full((G, F), NOT_SCHEDULED, rel.dtype),
+        jnp.full((G, F), _UNSCHEDULED, rel.dtype),
         pending0,
-        jnp.min(jnp.where(pending0, rel, jnp.inf), axis=1),
+        jnp.min(jnp.where(pending0, rel, INF), axis=1),
         jnp.int32(0),
         jnp.zeros((G,), bool),
     )
     out = jax.lax.while_loop(cond, body, init)
-    _, _, est, comp, pending, _, _, stalled = out
+    _, _, est, pending, _, _, stalled = out
+    comp = jnp.where(est != _UNSCHEDULED, add(est, dur), _UNSCHEDULED)
     return est, comp, jnp.any(pending, axis=1), stalled
 
 
-_PAIR_STATICS = ("reserving", "bound", "use_kernel")
+_PAIR_STATICS = ("reserving", "bound", "use_kernel", "interpret")
 _run_calendar_pairs = jax.jit(
     _run_calendar_pairs_impl, static_argnames=_PAIR_STATICS
 )
-# Donated variant for accelerator backends: the round's big f64 carry
+# Donated variant for accelerator backends: the round's big carry
 # buffers alias their inputs so each fused dispatch updates in place (CPU
 # ignores donation with a UserWarning, so it gets the plain jit).
 _run_calendar_pairs_donated = jax.jit(
@@ -449,6 +513,30 @@ _run_calendar_pairs_donated = jax.jit(
     static_argnames=_PAIR_STATICS,
     donate_argnames=("pending0", "free0"),
 )
+
+
+@functools.lru_cache(maxsize=None)
+def _run_calendar_pairs_sharded(
+    mesh, reserving, bound, use_kernel, interpret=False
+):
+    """The kernel-engine calendar of a bucket sharded over ``data``.
+
+    The compiler cannot partition a Mosaic kernel, and members are
+    independent, so every device steps its own members' lockstep loop:
+    one `jax.shard_map` over the member axis, no cross-device traffic.
+    """
+    spec = jax.sharding.PartitionSpec("data")
+    body = functools.partial(
+        _run_calendar_pairs_impl, reserving=reserving, bound=bound,
+        use_kernel=use_kernel, interpret=interpret,
+    )
+    # check_vma=False: the Pallas call's output shape declares no
+    # per-axis variance, and every value here is per-member anyway.
+    return jax.jit(
+        jax.shard_map(
+            body, mesh=mesh, in_specs=spec, out_specs=spec, check_vma=False
+        )
+    )
 
 
 def _run_calendar_wide(
@@ -667,13 +755,17 @@ def _check_engine(discipline: str, engine: str) -> str:
     variable wins when set (it overrides auto-selection only, never an
     explicit ``engine=`` argument), otherwise accelerator backends
     (TPU/GPU) get the kernelized pair calendar and CPU hosts the lockstep
-    NumPy engine — mirroring the kernels' interpret-mode convention.
+    NumPy engine.  On an accelerator the variable may not name the host
+    ``"wide"`` engine: a run there stays on the device unless the caller
+    asks for the host engine explicitly.
     """
     if discipline not in ("reserving", "greedy"):
         raise ValueError(f"unknown discipline {discipline!r}")
     if engine not in ("auto",) + _ENGINES:
         raise ValueError(f"unknown engine {engine!r}")
     if engine == "auto":
+        backend = jax.default_backend()
+        accelerator = backend in ("tpu", "gpu")
         env = os.environ.get("REPRO_CIRCUIT_ENGINE", "").strip().lower()
         if env:
             if env not in _ENGINES:
@@ -681,8 +773,14 @@ def _check_engine(discipline: str, engine: str) -> str:
                     f"unknown engine {env!r} (from REPRO_CIRCUIT_ENGINE; "
                     f"expected one of {', '.join(_ENGINES)})"
                 )
+            if accelerator and env == "wide":
+                raise ValueError(
+                    "REPRO_CIRCUIT_ENGINE=wide would move the calendar off "
+                    f"the {backend} onto the host; pass engine='wide' to "
+                    "ask for the host engine"
+                )
             return env
-        engine = "kernel" if jax.default_backend() in ("tpu", "gpu") else "wide"
+        engine = "kernel" if accelerator else "wide"
     return engine
 
 
@@ -701,8 +799,29 @@ def _warn_kernel_fallback() -> None:
         "runs through the jnp pair oracle (results identical, timings are "
         "not kernel timings)",
         RuntimeWarning,
-        stacklevel=3,
+        stacklevel=4,
     )
+
+
+def _pair_kernel_mode(num_flows: int) -> tuple[bool, bool]:
+    """``(use_kernel, interpret)`` of the kernel engine's round reduction.
+
+    On TPU the Pallas kernel is compiled natively, and a bucket it cannot
+    take is an error, never a quiet switch to the oracle.  Other backends
+    run the jnp pair oracle (warned once) unless a test asks for the
+    interpreter through `_PAIR_KERNEL_INTERPRET`.
+    """
+    if _PAIR_KERNEL_INTERPRET:
+        return True, True
+    if jax.default_backend() == "tpu":
+        if num_flows >= _MAX_KERNEL_FLOWS:
+            raise ValueError(
+                f"calendar bucket of {num_flows} flows per member exceeds "
+                f"the pair kernel's exact f32 id range ({_MAX_KERNEL_FLOWS})"
+            )
+        return True, False
+    _warn_kernel_fallback()
+    return False, False
 
 
 def _pad_members(
@@ -716,7 +835,14 @@ def _pad_members(
     rows (bucket rounding, plus ``g_multiple`` for shard counts) have no
     pending flows.
     """
-    G = _round_up(_round_up(len(tabs), _G_QUANTUM), g_multiple)
+    # Every member row steps through every lockstep round, so small
+    # buckets round up to a power of two (one or two members — the whole
+    # trace at K <= 2 — stay one or two rows), larger ones to the quantum.
+    n = len(tabs)
+    G = 1 << (n - 1).bit_length() if n <= _G_QUANTUM else _round_up(
+        n, _G_QUANTUM
+    )
+    G = _round_up(G, g_multiple)
     Fmax = _round_up(max(t["src"].shape[0] for t in tabs), _F_QUANTUM)
     Nmax = _round_up(num_ports_max, _N_QUANTUM)
     src = np.zeros((G, Fmax), dtype=np.int32)
@@ -741,55 +867,56 @@ def _pad_members(
     )
 
 
-def _calendar_program(pad: dict, discipline: str, engine: str):
+def _calendar_program(pad: dict, discipline: str, engine: str, sharding=None):
     """Assemble the jitted JAX executor for one padded bucket.
 
-    Returns ``(fn, args, statics)`` with ``args`` host arrays — callers
-    place them (optionally sharded) and invoke ``fn(*args, **statics)``
-    under `enable_x64`, or lower without running via ``fn.lower``.
+    Returns ``(fn, args, statics, perm)`` with ``args`` host arrays —
+    callers place them (with ``sharding`` when given) and invoke
+    ``fn(*args, **statics)`` under `jax.enable_x64`, or lower without
+    running via ``fn.lower``.  ``perm`` (kernel engine; None otherwise) is
+    the pair-sorted order of the outputs: output column ``i`` belongs to
+    flow ``perm[:, i]``.
     """
     reserving = discipline == "reserving"
     src, dst = pad["src"], pad["dst"]
     G, Fmax, Nmax = pad["G"], pad["Fmax"], pad["Nmax"]
-    free0 = np.zeros((G, Nmax), dtype=np.float64)
+    rel, dur = to_bits(pad["rel"]), to_bits(pad["dur"])
+    free0 = np.zeros((G, Nmax), dtype=np.int64)
     if engine == "jax":
         psrc, soff, send, sempty = _port_segments(pad["skey"], Nmax)
         pdst, doff, dend, dempty = _port_segments(pad["dkey"], Nmax)
         args = (
-            src, dst, pad["rel"], pad["dur"], pad["pending"], free0,
+            src, dst, rel, dur, pad["pending"], free0,
             psrc, soff, send, sempty, pdst, doff, dend, dempty,
         )
         return _run_calendar, args, dict(
             reserving=reserving, bound=event_bound(Fmax)
-        )
-    # engine == "kernel": pair-space segments over P = Nmax^2 pair keys.
+        ), None
+    # engine == "kernel": flows in pair-sorted order over P = Nmax^2 pairs;
+    # ``perm`` maps the outputs back.
     P = Nmax * Nmax
     pairkey = np.where(
         pad["pending"], src.astype(np.int64) * Nmax + dst, P
     )
-    pperm, poffs, psend, psempty = _port_segments(pairkey, P)
-    if _PAIR_KERNEL_OVERRIDE is not None:
-        use_kernel = _PAIR_KERNEL_OVERRIDE
-    else:
-        from repro.kernels.common import use_interpret
-
-        # The claim matrix carries flow ids in f32 lanes: exact below
-        # 2**24, which no realistic bucket approaches.
-        use_kernel = not use_interpret() and Fmax < (1 << 24)
-        if not use_kernel:
-            _warn_kernel_fallback()
+    perm, pfirst, plast = _pair_segments(pairkey, P)
+    take = lambda a: np.take_along_axis(a, perm, axis=1)  # noqa: E731
+    use_kernel, interpret = _pair_kernel_mode(Fmax)
+    args = (
+        take(rel), take(dur), take(pad["pending"]), free0, perm, pfirst, plast,
+    )
+    statics = dict(
+        reserving=reserving, bound=event_bound(Fmax), use_kernel=use_kernel,
+        interpret=interpret,
+    )
+    if sharding is not None:
+        fn = _run_calendar_pairs_sharded(sharding.mesh, **statics)
+        return fn, args, {}, perm
     fn = (
         _run_calendar_pairs_donated
         if jax.default_backend() in ("tpu", "gpu")
         else _run_calendar_pairs
     )
-    args = (
-        src, dst, pad["rel"], pad["dur"], pad["pending"], free0,
-        pairkey.astype(np.int32), pperm, poffs, psend, psempty,
-    )
-    return fn, args, dict(
-        reserving=reserving, bound=event_bound(Fmax), use_kernel=use_kernel
-    )
+    return fn, args, statics, perm
 
 
 def _execute_members(
@@ -822,15 +949,19 @@ def _execute_members(
             bound=event_bound(pad["Fmax"]) + pad["Fmax"],
             labels=list(labels),
         )
-    fn, args, statics = _calendar_program(pad, discipline, engine)
-    with enable_x64():
+    fn, args, statics, perm = _calendar_program(
+        pad, discipline, engine, sharding
+    )
+    with jax.enable_x64():
         from repro.launch.mesh import place
 
         est, comp, unfinished, stalled = fn(
             *(place(a, sharding) for a in args), **statics
         )
-    est = np.asarray(est)
-    comp = np.asarray(comp)
+    est = from_bits(est)
+    comp = from_bits(comp)
+    if perm is not None:
+        est, comp = (_unsort(a, perm) for a in (est, comp))
     unfinished = np.asarray(unfinished)
     stalled = np.asarray(stalled)
     for g, label in enumerate(labels):
@@ -865,8 +996,8 @@ def lower_calendar(
     if not tabs:
         raise ValueError("lower_calendar needs at least one member table")
     pad = _pad_members(tabs, num_ports_max)
-    fn, args, statics = _calendar_program(pad, discipline, engine)
-    with enable_x64():
+    fn, args, statics, _ = _calendar_program(pad, discipline, engine)
+    with jax.enable_x64():
         return fn.lower(*args, **statics)
 
 
@@ -893,7 +1024,8 @@ def schedule_batch(
     the accelerator path), ``"jax"`` (the vmapped flow-space
     `lax.while_loop`), ``"wide"`` (the lockstep NumPy pair engine, the
     CPU path), or ``"auto"`` (kernel on TPU/GPU, wide on hosts;
-    overridable via the ``REPRO_CIRCUIT_ENGINE`` environment variable).
+    overridable via the ``REPRO_CIRCUIT_ENGINE`` environment variable,
+    which may not move an accelerator run onto the host).
     All are bit-identical to the oracle and to each other.
     """
     engine = _check_engine(discipline, engine)

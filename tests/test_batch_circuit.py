@@ -66,7 +66,7 @@ def _batch_vs_loop(instances, discipline, engine="auto"):
 FUZZ_CASES = (
     [(s, "wide") for s in range(6)]
     + [(s, "jax") for s in range(2)]
-    + [(s, "kernel") for s in range(2)]
+    + [(s, "kernel") for s in range(4)]
 )
 
 
@@ -267,7 +267,8 @@ def test_not_scheduled_guard_regression():
 def test_check_engine_auto_env_and_explicit(monkeypatch):
     """"auto" resolves per backend (kernel on TPU/GPU, wide on hosts);
     REPRO_CIRCUIT_ENGINE overrides auto-selection only, never an explicit
-    engine= argument; junk in the variable is a loud error."""
+    engine= argument; junk in the variable, or a host engine named on an
+    accelerator, is a loud error."""
     from repro.pipeline import batch_circuit as bc
 
     monkeypatch.delenv("REPRO_CIRCUIT_ENGINE", raising=False)
@@ -282,6 +283,13 @@ def test_check_engine_auto_env_and_explicit(monkeypatch):
     with pytest.raises(ValueError, match="REPRO_CIRCUIT_ENGINE"):
         bc._check_engine("greedy", "auto")
     assert bc._check_engine("greedy", "kernel") == "kernel"
+    # On an accelerator the variable cannot move the run onto the host;
+    # only an explicit engine= argument picks the host engine there.
+    monkeypatch.setenv("REPRO_CIRCUIT_ENGINE", "wide")
+    monkeypatch.setattr(bc.jax, "default_backend", lambda: "tpu")
+    with pytest.raises(ValueError, match="off the tpu"):
+        bc._check_engine("greedy", "auto")
+    assert bc._check_engine("greedy", "wide") == "wide"
 
 
 def test_kernel_fallback_warns_once(monkeypatch):
@@ -311,7 +319,7 @@ def test_kernel_engine_forced_pallas_parity(discipline, monkeypatch):
     program that runs compiled on TPU/GPU."""
     from repro.pipeline import batch_circuit as bc
 
-    monkeypatch.setattr(bc, "_PAIR_KERNEL_OVERRIDE", True)
+    monkeypatch.setattr(bc, "_PAIR_KERNEL_INTERPRET", True)
     inst = random_instance(
         num_coflows=4, num_ports=3, num_cores=2, seed=11, release_span=10.0
     )

@@ -288,7 +288,7 @@ def test_event_resolve_kernel_matches_ref(G, F, N):
     from repro.kernels.event_resolve import event_resolve
 
     s = _random_event_state(G * 1000 + F, G, F, N)
-    got = np.asarray(event_resolve(**s, use_kernel=True))
+    got = np.asarray(event_resolve(**s, use_kernel=True, interpret=True))
     ref = np.asarray(event_resolve(**s, use_kernel=False))
     assert got.dtype == ref.dtype == np.bool_
     assert np.array_equal(got, ref)
@@ -300,7 +300,7 @@ def test_event_resolve_matches_numpy_primitive():
     from repro.kernels.event_resolve import event_resolve
 
     s = _random_event_state(7, 4, 23, 6)
-    got = np.asarray(event_resolve(**s, use_kernel=True))
+    got = np.asarray(event_resolve(**s, use_kernel=True, interpret=True))
     for g in range(4):
         waiting = np.asarray(s["pending"][g]) & (
             np.asarray(s["rel"][g]) <= float(s["t"][g])
@@ -336,7 +336,7 @@ def test_event_resolve_reserving_semantics():
         got = np.asarray(
             event_resolve(
                 src, dst, rel, free_in, free_out, pending, t,
-                use_kernel=use_kernel,
+                use_kernel=use_kernel, interpret=True,
             )
         )
         assert got.tolist() == [[True, False, False]]
@@ -355,7 +355,9 @@ def test_pair_resolve_kernel_matches_ref(G, N):
         np.where(rng.random((G, N, N)) < 0.6, ids, float(F)), jnp.float32
     )
     idle = jnp.asarray(rng.random((G, N, N)) < 0.5)
-    got = np.asarray(pair_resolve(claim, idle, use_kernel=True))
+    got = np.asarray(
+        pair_resolve(claim, idle, use_kernel=True, interpret=True)
+    )
     ref = np.asarray(pair_resolve_ref(claim, idle))
     assert got.dtype == ref.dtype == np.bool_
     assert np.array_equal(got, ref)
@@ -396,7 +398,9 @@ def test_pair_resolve_f64_separation_parity(discipline):
         )
         for use_kernel in (True, False):
             sp = np.asarray(
-                pair_resolve(claim, jnp.asarray(idle[None]), use_kernel)
+                pair_resolve(
+                    claim, jnp.asarray(idle[None]), use_kernel, interpret=True
+                )
             )[0]
             got = sp[src, dst] & (heads[src, dst] == np.arange(F))
             assert np.array_equal(got, ref), (seed, use_kernel)
