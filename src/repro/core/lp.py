@@ -45,6 +45,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.coflow import CoflowInstance, port_stats
+from repro.trace import to_host
 
 __all__ = [
     "LPSolution",
@@ -427,7 +428,7 @@ class LPSolutionBatch:
         to +inf before a stable argsort leaves the relative order of the
         real entries untouched.
         """
-        comp = np.asarray(self.completion, dtype=np.float64)
+        comp = to_host(self.completion).astype(np.float64)
         key = np.where(np.asarray(coflow_mask), comp, np.inf)
         return np.argsort(key, axis=1, kind="stable")
 
@@ -436,9 +437,10 @@ class LPSolutionBatch:
 
         Gathers device (possibly sharded) arrays to host numpy first; the
         f64 conversion matches the legacy list-of-`LPSolution` path."""
-        comp = np.asarray(self.completion, dtype=np.float64)
-        y = np.asarray(self.y, dtype=np.float64)
-        obj = np.asarray(self.objective, dtype=np.float64)
+        comp, y, obj = (
+            a.astype(np.float64)
+            for a in to_host(self.completion, self.y, self.objective)
+        )
         out = []
         for b, M in enumerate(num_coflows):
             out.append(

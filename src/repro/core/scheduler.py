@@ -28,7 +28,6 @@ entries and adds an ensemble-batched execution path.  This module keeps:
 from __future__ import annotations
 
 import dataclasses
-import time
 import warnings
 from typing import Callable
 
@@ -54,7 +53,6 @@ class ScheduleResult:
     ccts: np.ndarray  # (M,) realized completion times (original ids)
     total_weighted_cct: float
     lp: lp_mod.LPSolution | None
-    wall_time_s: float
 
     def normalized_to(self, other: "ScheduleResult") -> float:
         return self.total_weighted_cct / other.total_weighted_cct
@@ -133,7 +131,6 @@ def _run_circuit_scheme(
     discipline: str = "reserving",
     validate: bool = True,
 ) -> ScheduleResult:
-    t0 = time.perf_counter()
     alloc = allocate(instance, order, include_tau=include_tau)
     schedules = _schedule_all_cores(
         instance, alloc, order, sequential=sequential, discipline=discipline
@@ -149,14 +146,12 @@ def _run_circuit_scheme(
         ccts=ccts,
         total_weighted_cct=total_weighted_cct(instance, ccts),
         lp=lp_sol,
-        wall_time_s=time.perf_counter() - t0,
     )
 
 
 def _run_bvn(
     instance: CoflowInstance, order: np.ndarray, lp_sol
 ) -> ScheduleResult:
-    t0 = time.perf_counter()
     alloc = allocate(instance, order, include_tau=True)
     M, N, K = instance.num_coflows, instance.num_ports, instance.num_cores
     per_core = alloc.per_core_demand(M, N)
@@ -176,7 +171,6 @@ def _run_bvn(
         ccts=ccts,
         total_weighted_cct=total_weighted_cct(instance, ccts),
         lp=lp_sol,
-        wall_time_s=time.perf_counter() - t0,
     )
 
 

@@ -37,8 +37,10 @@ def device_gather(tree):
     """
     import jax
 
+    from repro.trace import to_host
+
     return jax.tree.map(
-        lambda x: np.asarray(x) if isinstance(x, jax.Array) else x, tree
+        lambda x: to_host(x) if isinstance(x, jax.Array) else x, tree
     )
 
 

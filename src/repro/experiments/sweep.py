@@ -39,6 +39,7 @@ from typing import Any, Mapping, Sequence
 import numpy as np
 
 from repro import pipeline as pipeline_mod
+from repro import trace
 from repro.core import lp, scheduler, theory
 from repro.core.coflow import CoflowInstance
 from repro.experiments import cache as cache_mod
@@ -85,11 +86,16 @@ class InstanceRecord:
 
 @dataclasses.dataclass
 class SweepResult:
+    """A sweep's records plus its host spans and counters (`repro.trace`:
+    seconds per span name and totals per counter name over the call)."""
+
     records: list[InstanceRecord]
     lp_method: str
-    lp_time_s: float
+    lp_time_s: float  # the ``sweep.lp`` span
     wall_time_s: float
     cache_stats: dict[str, int] | None = None
+    spans: dict[str, float] = dataclasses.field(default_factory=dict)
+    counts: dict[str, int] = dataclasses.field(default_factory=dict)
 
     def __len__(self) -> int:
         return len(self.records)
@@ -261,6 +267,7 @@ def sweep(
 
     t0 = time.perf_counter()
     n = len(instances)
+    totals = trace.Tally()  # the call's spans and counters
 
     # ---- cell keying: which (instance, scheme) cells need computing ----
     # The cache key folds in everything that determines a cell's value;
@@ -309,19 +316,22 @@ def sweep(
     lp_time = 0.0
     if need_idx:
         sub = [instances[i] for i in need_idx]
-        t_lp = time.perf_counter()
-        if lp_method == "batch":
-            sub_sols = solve_ensemble_lp(
-                sub, iters=lp_iters, m_quantum=m_quantum,
-                p_quantum=p_quantum, mesh=mesh,
-            )
-        elif lp_method == "exact":
-            sub_sols = [lp.solve_exact(inst) for inst in sub]
-        elif lp_method == "subgradient":
-            sub_sols = [lp.solve_subgradient(inst, iters=lp_iters) for inst in sub]
-        else:
-            raise ValueError(f"unknown lp_method {lp_method!r}")
-        lp_time = time.perf_counter() - t_lp
+        with trace.collect() as tally, trace.span("sweep.lp") as lp_span:
+            if lp_method == "batch":
+                sub_sols = solve_ensemble_lp(
+                    sub, iters=lp_iters, m_quantum=m_quantum,
+                    p_quantum=p_quantum, mesh=mesh,
+                )
+            elif lp_method == "exact":
+                sub_sols = [lp.solve_exact(inst) for inst in sub]
+            elif lp_method == "subgradient":
+                sub_sols = [
+                    lp.solve_subgradient(inst, iters=lp_iters) for inst in sub
+                ]
+            else:
+                raise ValueError(f"unknown lp_method {lp_method!r}")
+        totals.add(tally)
+        lp_time = lp_span.seconds
         sols_by_idx = dict(zip(need_idx, sub_sols))
     elif lp_method not in ("batch", "exact", "subgradient"):
         raise ValueError(f"unknown lp_method {lp_method!r}")
@@ -340,19 +350,22 @@ def sweep(
         )
         sub = [instances[i] for i in idx]
         subsols = [sols_by_idx[i] for i in idx]
-        if alloc == "batch":
-            sc = stage_caches.setdefault(tuple(idx), {})
-            res = pipe.run_batch(
-                sub, lp_solutions=subsols, validate=validate,
-                stage_cache=sc, mesh=mesh, refine=refine,
-            )
-        else:
-            res = [
-                pipe.run(
-                    inst, lp_solution=sol, validate=validate, refine=refine
+        with trace.collect() as tally:
+            if alloc == "batch":
+                sc = stage_caches.setdefault(tuple(idx), {})
+                res = pipe.run_batch(
+                    sub, lp_solutions=subsols, validate=validate,
+                    stage_cache=sc, mesh=mesh, refine=refine,
                 )
-                for inst, sol in zip(sub, subsols)
-            ]
+            else:
+                res = [
+                    pipe.run(
+                        inst, lp_solution=sol, validate=validate,
+                        refine=refine,
+                    )
+                    for inst, sol in zip(sub, subsols)
+                ]
+        totals.add(tally)
         return dict(zip(idx, res))
 
     scheme_results: dict[str, dict[int, Any]] = {}
@@ -446,4 +459,6 @@ def sweep(
         lp_time_s=lp_time,
         wall_time_s=time.perf_counter() - t0,
         cache_stats=cache_stats,
+        spans=totals.spans,
+        counts=totals.counts,
     )

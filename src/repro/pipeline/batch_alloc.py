@@ -59,6 +59,7 @@ from repro.pipeline.ensemble_batch import (
     build_ensemble_batch,
 )
 from repro.pipeline.exact64 import NEG_INF, ONE, add, from_bits, mul, to_bits
+from repro.trace import span, to_host
 
 __all__ = ["allocate_batch", "allocate_batch_arrays", "flow_sequence"]
 
@@ -162,57 +163,64 @@ def allocate_batch_arrays(
     ``[allocate(inst, order, include_tau) for ...]`` (see module
     docstring).
     """
-    Bp, Fp = ensemble.flow_size.shape
-    perm = ensemble.permute_flows(orders)
-    take = lambda a: np.take_along_axis(a, perm, axis=1)  # noqa: E731
-    coflow = take(ensemble.flow_coflow)
-    src = take(ensemble.flow_src)
-    dst = take(ensemble.flow_dst)
-    size = take(ensemble.flow_size)
-    pi = take(ensemble.flow_pi)
-    pj = take(ensemble.flow_pj)
-    valid = take(ensemble.flow_valid)
-    ends = ensemble.prefix_ends(orders)
+    with span("alloc.prepare"):
+        Bp, Fp = ensemble.flow_size.shape
+        perm = ensemble.permute_flows(orders)
+        take = lambda a: np.take_along_axis(a, perm, axis=1)  # noqa: E731
+        coflow = take(ensemble.flow_coflow)
+        src = take(ensemble.flow_src)
+        dst = take(ensemble.flow_dst)
+        size = take(ensemble.flow_size)
+        pi = take(ensemble.flow_pi)
+        pj = take(ensemble.flow_pj)
+        valid = take(ensemble.flow_valid)
+        ends = ensemble.prefix_ends(orders)
 
-    Kp, Pp = ensemble.pad_cores, ensemble.pad_flat_ports
-    delta = ensemble.delta if include_tau else np.zeros_like(ensemble.delta)
-    lb0 = np.where(ensemble.core_mask, 0.0, PAD_LB)
+        Kp, Pp = ensemble.pad_cores, ensemble.pad_flat_ports
+        delta = (
+            ensemble.delta if include_tau else np.zeros_like(ensemble.delta)
+        )
+        lb0 = np.where(ensemble.core_mask, 0.0, PAD_LB)
 
     if Fp == 0:
         # Nothing to place anywhere in the ensemble: zero prefix stats.
-        core = np.zeros((Bp, 0), dtype=np.int64)
-        rho = np.zeros((Bp, Kp, Pp))
-        tau = np.zeros((Bp, Kp, Pp))
-        prefix_lb = np.zeros(ends.shape)
+        ks = lbs = np.zeros((Bp, 0), dtype=np.int64)
+        rho = tau = np.zeros((Bp, Kp, Pp), dtype=np.int64)
     else:
-        zeros_kp = np.zeros((Bp, Kp, Pp), dtype=np.int64)
         with jax.enable_x64():
             from repro.launch.mesh import place
 
-            put = lambda x: place(x, ensemble.sharding)  # noqa: E731
-            ks, lbs, rho, tau = _scan_all(
-                put(pi.astype(np.int32)), put(pj.astype(np.int32)),
-                put(to_bits(size)), put(valid),
-                put(to_bits(ensemble.inv_rates)), put(to_bits(delta)),
-                put(to_bits(lb0)), put(ensemble.core_mask),
-                put(zeros_kp), put(zeros_kp),
-            )
-        core = np.asarray(ks).astype(np.int64)
-        lbs = from_bits(lbs)
-        rho = from_bits(rho)
-        tau = from_bits(tau)
-        # lb starts all-zero, so before any flow lands the prefix LB is 0.
-        prefix_lb = np.where(
-            ends > 0,
-            np.take_along_axis(lbs, np.maximum(ends - 1, 0), axis=1),
-            0.0,
-        ).astype(np.float64)
+            with span("alloc.prepare"):
+                zeros_kp = np.zeros((Bp, Kp, Pp), dtype=np.int64)
+                put = lambda x: place(x, ensemble.sharding)  # noqa: E731
+                args = (
+                    put(pi.astype(np.int32)), put(pj.astype(np.int32)),
+                    put(to_bits(size)), put(valid),
+                    put(to_bits(ensemble.inv_rates)), put(to_bits(delta)),
+                    put(to_bits(lb0)), put(ensemble.core_mask),
+                    put(zeros_kp), put(zeros_kp),
+                )
+            with span("alloc.wait"):
+                ks, lbs, rho, tau = to_host(*_scan_all(*args))
+                del args  # frees the inputs' device buffers in the span
 
-    return AllocationBatch(
-        order=np.asarray(orders), perm=perm, coflow=coflow, src=src, dst=dst,
-        size=size, valid=valid, core=core, rho_ports=rho, tau_ports=tau,
-        prefix_lb=prefix_lb, ends=ends,
-    )
+    with span("alloc.unpack"):
+        # lb starts all-zero, so before any flow lands the prefix LB is 0.
+        prefix_lb = np.zeros(ends.shape)
+        if Fp:
+            prefix_lb = np.where(
+                ends > 0,
+                np.take_along_axis(
+                    from_bits(lbs), np.maximum(ends - 1, 0), axis=1
+                ),
+                0.0,
+            ).astype(np.float64)
+        return AllocationBatch(
+            order=np.asarray(orders), perm=perm, coflow=coflow, src=src,
+            dst=dst, size=size, valid=valid, core=ks.astype(np.int64),
+            rho_ports=from_bits(rho), tau_ports=from_bits(tau),
+            prefix_lb=prefix_lb, ends=ends,
+        )
 
 
 def allocate_batch(

@@ -100,6 +100,7 @@ from repro.core.coflow import CoflowInstance
 from repro.core.validate import ccts_from_schedules
 from repro.pipeline.ensemble_batch import AllocationBatch, EnsembleBatch
 from repro.pipeline.exact64 import INF, NEG_INF, add, from_bits, to_bits
+from repro.trace import count, span, to_host
 
 __all__ = [
     "schedule_batch",
@@ -256,7 +257,8 @@ def _run_calendar(
     Returns (establish, complete) (G, Fmax) i64 patterns plus per-member
     ``unfinished`` / ``stalled`` flags (bound exhausted / no event time
     could advance the clock — both impossible for well-formed inputs,
-    checked on host).
+    checked on host), the lockstep round count (the largest member's) and
+    each member's (G,) i32 rounds.
     """
     G, F = src.shape
     n_pad = free0.shape[1]
@@ -336,13 +338,14 @@ def _run_calendar(
             jnp.bool_(False),
         )
         out = jax.lax.while_loop(cond, body, init)
-        _, _, est, comp, pending, _, _, stalled = out
-        return est, comp, jnp.any(pending), stalled
+        _, _, est, comp, pending, _, it, stalled = out
+        return est, comp, jnp.any(pending), stalled, it
 
-    return jax.vmap(member)(
+    est, comp, unfinished, stalled, rounds = jax.vmap(member)(
         src, dst, rel, dur, pending0, free0,
         psrc, soff, send, sempty, pdst, doff, dend, dempty,
     )
+    return est, comp, unfinished, stalled, jnp.max(rounds), rounds
 
 
 def _run_calendar_pairs_impl(
@@ -391,8 +394,11 @@ def _run_calendar_pairs_impl(
     bool, all pair-sorted; free0 (G, Nmax) i64 zeros; ids (G, Fmax) i32;
     pfirst/plast (G, P) i32 — each pair's first and last sorted position
     (``plast`` -1 for a pair without flows).  Returns (establish,
-    complete) (G, Fmax) i64 patterns in pair-sorted order and the
-    per-member ``unfinished`` / ``stalled`` flags of `_run_calendar`.
+    complete) (G, Fmax) i64 patterns in pair-sorted order, the per-member
+    ``unfinished`` / ``stalled`` flags of `_run_calendar`, the loop's
+    round count and each member's (G,) i32 rounds: the rounds in which it
+    still had a pending flow (its own calendar's length; the lockstep
+    batch runs the largest member's).
     """
     from repro.kernels.event_resolve import pair_resolve
 
@@ -423,11 +429,11 @@ def _run_calendar_pairs_impl(
         )(pos)
 
     def cond(carry):
-        _, _, _, pending, _, it, stalled = carry
+        _, _, _, pending, _, it, stalled, _ = carry
         return jnp.any(pending & ~stalled[:, None]) & (it < bound)
 
     def body(carry):
-        free_in, free_out, est, pending, t, it, stalled = carry
+        free_in, free_out, est, pending, t, it, stalled, rounds = carry
         t_ = t[:, None]
         waiting = pending & (rel <= t_) & ~stalled[:, None]
         # Pair heads: the first waiting position at or after each pair's
@@ -484,7 +490,10 @@ def _run_calendar_pairs_impl(
         alive = jnp.any(pending, axis=1)
         stall = advance & alive & (t_next == INF) & ~stalled
         t = jnp.where(advance & (t_next < INF) & ~stalled, t_next, t)
-        return free_in, free_out, est, pending, t, it + 1, stalled | stall
+        stalled = stalled | stall
+        # A member left with a pending flow takes part in the next round.
+        rounds = rounds + (alive & ~stalled).astype(jnp.int32)
+        return free_in, free_out, est, pending, t, it + 1, stalled, rounds
 
     init = (
         free0,
@@ -494,11 +503,16 @@ def _run_calendar_pairs_impl(
         jnp.min(jnp.where(pending0, rel, INF), axis=1),
         jnp.int32(0),
         jnp.zeros((G,), bool),
+        jnp.any(pending0, axis=1).astype(jnp.int32),
     )
     out = jax.lax.while_loop(cond, body, init)
-    _, _, est, pending, _, _, stalled = out
+    _, _, est, pending, _, it, stalled, rounds = out
     comp = jnp.where(est != _UNSCHEDULED, add(est, dur), _UNSCHEDULED)
-    return est, comp, jnp.any(pending, axis=1), stalled
+    unfinished = jnp.any(pending, axis=1)
+    # A member still live when the bound cut the loop was counted for a
+    # round that never ran.
+    rounds = rounds - (unfinished & ~stalled).astype(jnp.int32)
+    return est, comp, unfinished, stalled, it, rounds
 
 
 _PAIR_STATICS = ("reserving", "bound", "use_kernel", "interpret")
@@ -526,10 +540,15 @@ def _run_calendar_pairs_sharded(
     one `jax.shard_map` over the member axis, no cross-device traffic.
     """
     spec = jax.sharding.PartitionSpec("data")
-    body = functools.partial(
-        _run_calendar_pairs_impl, reserving=reserving, bound=bound,
-        use_kernel=use_kernel, interpret=interpret,
-    )
+
+    def body(*args):
+        *out, it, rounds = _run_calendar_pairs_impl(
+            *args, reserving=reserving, bound=bound, use_kernel=use_kernel,
+            interpret=interpret,
+        )
+        # Each device's loop count, one per shard (the host takes the max).
+        return (*out, it[None], rounds)
+
     # check_vma=False: the Pallas call's output shape declares no
     # per-axis variance, and every value here is per-member anyway.
     return jax.jit(
@@ -560,6 +579,8 @@ def _run_calendar_wide(
 
     Members drop out of the lockstep batch as they finish.  Identical
     f64 selections as `_run_calendar` and `schedule_core`: bit-exact.
+    Returns (establish, complete) (G, F), the lockstep round count and
+    each member's (G,) rounds (those it started with a pending flow).
     """
     G, F = src.shape
     N = int(num_ports)
@@ -567,8 +588,9 @@ def _run_calendar_wide(
     NOT = NOT_SCHEDULED
     out_est = np.full((G, F), NOT)
     out_comp = np.full((G, F), NOT)
+    member_rounds = np.zeros(G, dtype=np.int64)
     if G == 0 or F == 0:
-        return out_est, out_comp
+        return out_est, out_comp, 0, member_rounds
 
     pairid = np.where(valid, src.astype(np.int64) * N + dst, P)
     psort = np.argsort(pairid, axis=1, kind="stable")
@@ -645,6 +667,7 @@ def _run_calendar_wide(
             raise RuntimeError(
                 f"batched scheduler exceeded the event bound ({who})"
             )
+        member_rounds[orig[remaining > 0]] += 1
         Ga = orig.size
         t_ = t[:, None]
         base = (np.arange(Ga) * F)[:, None]
@@ -745,7 +768,7 @@ def _run_calendar_wide(
             groups = [
                 grp for g, grp in enumerate(groups) if alive[g]
             ]
-    return out_est, out_comp
+    return out_est, out_comp, it, member_rounds
 
 
 def _check_engine(discipline: str, engine: str) -> str:
@@ -933,45 +956,69 @@ def _execute_members(
     len(tabs), padding rows garbage).  ``sharding`` places the JAX
     executors' inputs with a data-axis `NamedSharding` (member rows round
     up to the shard count); the wide engine is host-side NumPy and
-    ignores it.
+    ignores it.  Counts the calendar's ``calendar.rounds`` (lockstep
+    rounds; the longest device's when sharded), ``calendar.members``,
+    ``calendar.member_rounds`` (summed over the real members) and
+    ``calendar.member_slots`` (members times lockstep rounds) in the
+    current `repro.trace` tally.
     """
-    g_multiple = (
-        int(sharding.mesh.shape["data"])
-        if sharding is not None and engine in ("jax", "kernel")
-        else 1
-    )
-    pad = _pad_members(tabs, num_ports_max, g_multiple)
+    with span("calendar.pack"):
+        g_multiple = (
+            int(sharding.mesh.shape["data"])
+            if sharding is not None and engine in ("jax", "kernel")
+            else 1
+        )
+        pad = _pad_members(tabs, num_ports_max, g_multiple)
     if engine == "wide":
-        return _run_calendar_wide(
-            pad["src"], pad["dst"], pad["rel"], pad["dur"], pad["pending"],
-            pad["Nmax"],
-            reserving=discipline == "reserving",
-            bound=event_bound(pad["Fmax"]) + pad["Fmax"],
-            labels=list(labels),
-        )
-    fn, args, statics, perm = _calendar_program(
-        pad, discipline, engine, sharding
-    )
-    with jax.enable_x64():
-        from repro.launch.mesh import place
-
-        est, comp, unfinished, stalled = fn(
-            *(place(a, sharding) for a in args), **statics
-        )
-    est = from_bits(est)
-    comp = from_bits(comp)
-    if perm is not None:
-        est, comp = (_unsort(a, perm) for a in (est, comp))
-    unfinished = np.asarray(unfinished)
-    stalled = np.asarray(stalled)
-    for g, label in enumerate(labels):
-        if stalled[g]:
-            raise RuntimeError(f"batched scheduler stalled ({label})")
-        if unfinished[g]:  # pragma: no cover - bound is large
-            raise RuntimeError(
-                f"batched scheduler exceeded the event bound ({label})"
+        with span("calendar.wide"):
+            est, comp, rounds, member_rounds = _run_calendar_wide(
+                pad["src"], pad["dst"], pad["rel"], pad["dur"],
+                pad["pending"], pad["Nmax"],
+                reserving=discipline == "reserving",
+                bound=event_bound(pad["Fmax"]) + pad["Fmax"],
+                labels=list(labels),
             )
+            _count_rounds(rounds, member_rounds, len(tabs))
+    else:
+        with jax.enable_x64():
+            from repro.launch.mesh import place
+
+            with span("calendar.pack"):
+                fn, args, statics, perm = _calendar_program(
+                    pad, discipline, engine, sharding
+                )
+                args = [place(a, sharding) for a in args]
+            with span("calendar.wait"):
+                est, comp, unfinished, stalled, rounds, member_rounds = (
+                    to_host(*fn(*args, **statics))
+                )
+                del args  # frees the inputs' device buffers in the span
+        with span("calendar.unpack"):
+            est = from_bits(est)
+            comp = from_bits(comp)
+            if perm is not None:
+                est, comp = (_unsort(a, perm) for a in (est, comp))
+            for g, label in enumerate(labels):
+                if stalled[g]:
+                    raise RuntimeError(
+                        f"batched scheduler stalled ({label})"
+                    )
+                if unfinished[g]:  # pragma: no cover - bound is large
+                    raise RuntimeError(
+                        "batched scheduler exceeded the event bound "
+                        f"({label})"
+                    )
+            _count_rounds(rounds, member_rounds, len(tabs))
     return est, comp
+
+
+def _count_rounds(rounds, member_rounds: np.ndarray, members: int) -> None:
+    """The calendar counters of one program (see `_execute_members`)."""
+    rounds = int(np.max(rounds))
+    count("calendar.rounds", rounds)
+    count("calendar.members", members)
+    count("calendar.member_rounds", int(member_rounds[:members].sum()))
+    count("calendar.member_slots", members * rounds)
 
 
 def lower_calendar(
@@ -1127,13 +1174,7 @@ def cct_batch_arrays(
                 members.append((b, k, idx))
     if members:
         tabs = [
-            dict(
-                src=alloc.src[b, idx],
-                dst=alloc.dst[b, idx],
-                rel=ensemble.releases[b, alloc.coflow[b, idx]],
-                dur=ensemble.delta[b]
-                + alloc.size[b, idx] / ensemble.rates[b, k],
-            )
+            _member_table(ensemble, alloc, b, k, idx, None)
             for b, k, idx in members
         ]
         _est, comp = _execute_members(
@@ -1188,51 +1229,29 @@ def schedule_batch_arrays(
     behavior; ``(b, k)`` entries whose member has no real flows are
     ignored (phantoms alone constrain nothing).
     """
-    engine = _check_engine(discipline, engine)
-    B = ensemble.num_instances
-    if B == 0:
-        return []
-
-    # (b, k, flow-row indices into the ordered flow axis, phantom count)
-    members = []
-    for b in range(B):
-        coreb = alloc.core[b]
-        validb = alloc.valid[b]
-        for k in range(ensemble.num_cores[b]):
-            idx = np.nonzero(validb & (coreb == k))[0]
-            if idx.size:
-                nb = 0
-                if busy is not None and (b, k) in busy:
-                    nb = int(np.asarray(busy[b, k]["src"]).shape[0])
-                members.append((b, k, idx, nb))
+    with span("calendar.pack"):
+        engine = _check_engine(discipline, engine)
+        B = ensemble.num_instances
+        if B == 0:
+            return []
+        # (b, k, flow-row indices into the ordered flow axis, phantoms)
+        members = []
+        for b in range(B):
+            coreb = alloc.core[b]
+            validb = alloc.valid[b]
+            for k in range(ensemble.num_cores[b]):
+                idx = np.nonzero(validb & (coreb == k))[0]
+                if idx.size:
+                    nb = 0
+                    if busy is not None and (b, k) in busy:
+                        nb = int(np.asarray(busy[b, k]["src"]).shape[0])
+                    members.append((b, k, idx, nb))
+        tabs = [
+            _member_table(ensemble, alloc, b, k, idx, busy if nb else None)
+            for b, k, idx, nb in members
+        ]
 
     if members:
-        tabs = []
-        for b, k, idx, nb in members:
-            tab = dict(
-                src=alloc.src[b, idx],
-                dst=alloc.dst[b, idx],
-                rel=ensemble.releases[b, alloc.coflow[b, idx]],
-                dur=ensemble.delta[b]
-                + alloc.size[b, idx] / ensemble.rates[b, k],
-            )
-            if nb:
-                bz = busy[b, k]
-                tab = dict(
-                    src=np.concatenate(
-                        [np.asarray(bz["src"], tab["src"].dtype), tab["src"]]
-                    ),
-                    dst=np.concatenate(
-                        [np.asarray(bz["dst"], tab["dst"].dtype), tab["dst"]]
-                    ),
-                    rel=np.concatenate(
-                        [np.asarray(bz["rel"], np.float64), tab["rel"]]
-                    ),
-                    dur=np.concatenate(
-                        [np.asarray(bz["dur"], np.float64), tab["dur"]]
-                    ),
-                )
-            tabs.append(tab)
         est, comp = _execute_members(
             tabs,
             max(ensemble.num_ports[b] for b in range(B)),
@@ -1241,6 +1260,8 @@ def schedule_batch_arrays(
             labels=[f"instance {b}, core {k}" for b, k, _, _ in members],
             sharding=ensemble.sharding,
         )
+
+    with span("calendar.readback"):
         for g, (b, k, _, nb) in enumerate(members):
             if nb and not np.array_equal(est[g, :nb], tabs[g]["rel"][:nb]):
                 raise AssertionError(
@@ -1248,44 +1269,73 @@ def schedule_batch_arrays(
                     "did not establish at their release — busy tables must "
                     "be port-exclusive with rel at the epoch time"
                 )
-
-    schedules_by_member = {
-        (b, k): g for g, (b, k, _, _) in enumerate(members)
-    }
-    out = []
-    for b in range(B):
-        schedules = []
-        for k in range(ensemble.num_cores[b]):
-            g = schedules_by_member.get((b, k))
-            if g is None:
-                z = np.zeros(0)
-                zi = np.zeros(0, dtype=np.int64)
+        schedules_by_member = {
+            (b, k): g for g, (b, k, _, _) in enumerate(members)
+        }
+        out = []
+        for b in range(B):
+            schedules = []
+            for k in range(ensemble.num_cores[b]):
+                g = schedules_by_member.get((b, k))
+                if g is None:
+                    z = np.zeros(0)
+                    zi = np.zeros(0, dtype=np.int64)
+                    schedules.append(
+                        CoreSchedule(
+                            zi, zi, zi, z, z, z,
+                            float(ensemble.rates[b, k]),
+                            float(ensemble.delta[b]),
+                        )
+                    )
+                    continue
+                _, _, idx, nb = members[g]
+                F = idx.shape[0]
                 schedules.append(
                     CoreSchedule(
-                        zi, zi, zi, z, z, z,
-                        float(ensemble.rates[b, k]),
-                        float(ensemble.delta[b]),
+                        coflow=alloc.coflow[b, idx],
+                        src=alloc.src[b, idx],
+                        dst=alloc.dst[b, idx],
+                        size=alloc.size[b, idx],
+                        establish=est[g, nb:nb + F].copy(),
+                        complete=comp[g, nb:nb + F].copy(),
+                        rate=float(ensemble.rates[b, k]),
+                        delta=float(ensemble.delta[b]),
                     )
                 )
-                continue
-            _, _, idx, nb = members[g]
-            F = idx.shape[0]
-            schedules.append(
-                CoreSchedule(
-                    coflow=alloc.coflow[b, idx],
-                    src=alloc.src[b, idx],
-                    dst=alloc.dst[b, idx],
-                    size=alloc.size[b, idx],
-                    establish=est[g, nb:nb + F].copy(),
-                    complete=comp[g, nb:nb + F].copy(),
-                    rate=float(ensemble.rates[b, k]),
-                    delta=float(ensemble.delta[b]),
+            out.append(
+                (
+                    schedules,
+                    ccts_from_schedules(ensemble.num_coflows[b], schedules),
                 )
             )
-        out.append(
-            (
-                schedules,
-                ccts_from_schedules(ensemble.num_coflows[b], schedules),
-            )
-        )
-    return out
+        return out
+
+
+def _member_table(
+    ensemble: EnsembleBatch,
+    alloc: AllocationBatch,
+    b: int,
+    k: int,
+    idx: np.ndarray,
+    busy: dict | None,
+) -> dict:
+    """Flow table of member (instance ``b``, core ``k``): the ordered flow
+    rows ``idx``, with ``busy``'s phantom circuits of that member (if
+    given) prepended."""
+    tab = dict(
+        src=alloc.src[b, idx],
+        dst=alloc.dst[b, idx],
+        rel=ensemble.releases[b, alloc.coflow[b, idx]],
+        dur=ensemble.delta[b] + alloc.size[b, idx] / ensemble.rates[b, k],
+    )
+    if busy is None:
+        return tab
+    bz = busy[b, k]
+    dtypes = dict(
+        src=tab["src"].dtype, dst=tab["dst"].dtype,
+        rel=np.float64, dur=np.float64,
+    )
+    return {
+        key: np.concatenate([np.asarray(bz[key], dt), tab[key]])
+        for key, dt in dtypes.items()
+    }

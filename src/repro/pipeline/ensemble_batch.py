@@ -23,8 +23,9 @@ Downstream, `repro.pipeline.batch_alloc.allocate_batch_arrays` and
 `repro.pipeline.batch_circuit.schedule_batch_arrays` consume these arrays
 directly (producing the `AllocationBatch` pytree and padded calendar
 outputs), and `Pipeline.run_batch` materializes per-instance results only
-at the very end.  `BUILD_COUNT` counts constructions so tests can assert
-the one-build-per-bucket contract at stage boundaries.
+at the very end.  Each construction counts one ``ensemble.build`` in the
+current `repro.trace` tally, so tests can assert the one-build-per-bucket
+contract at stage boundaries.
 
 Sharding: `build_ensemble_batch(..., mesh=...)` pads the member axis to a
 multiple of the mesh's ``"data"`` axis and records a
@@ -46,6 +47,7 @@ import jax
 from repro.core import lp as lp_mod
 from repro.core.allocation import Allocation
 from repro.core.coflow import CoflowInstance, flows_of, port_stats
+from repro.trace import count
 
 __all__ = [
     "EnsembleBatch",
@@ -57,9 +59,6 @@ __all__ = [
     "set_slot_releases",
     "free_slots",
     "expansion_maps",
-    "BUILD_COUNT",
-    "SLOT_SCATTER_COUNT",
-    "SLOT_GROW_COUNT",
     "PAD_LB",
 ]
 
@@ -68,25 +67,22 @@ __all__ = [
 # (`repro.pipeline.batch_alloc` re-exports this as its historical name.)
 PAD_LB = 1e30
 
-#: Stage-boundary counter: number of `EnsembleBatch` constructions in this
-#: process.  `Pipeline.run_batch` must build exactly one per ensemble (and
-#: the bucketed LP phase one per bucket) — tests diff this counter to
-#: assert no stage re-pads behind the pipeline's back.
-BUILD_COUNT = 0
-
-#: The **controlled exemption** from the build-once contract: number of
-#: in-place slot scatters (`update_slots` / `free_slots`) into a resident
-#: `SlotPoolBatch`.  The streaming service mutates one long-lived batch
-#: instead of rebuilding per epoch, so its `BUILD_COUNT` stays at the
-#: pool constructions while this counter tracks the epoch updates —
-#: tests diff both to assert the service never silently re-packs.
-SLOT_SCATTER_COUNT = 0
-
-#: Arena regrowths (flow-axis capacity bumps) of resident slot pools —
-#: each one is a new padded flow shape, i.e. one entry of the epoch
-#: compile-cache bucket ladder.  Geometric growth bounds this to
-#: O(log(total flows) / log 2) distinct shapes per pool size.
-SLOT_GROW_COUNT = 0
+# Counters (`repro.trace.count`, in the current tally):
+#
+#   * ``ensemble.build`` — `EnsembleBatch` constructions.  `Pipeline.
+#     run_batch` must build exactly one per ensemble (and the bucketed LP
+#     phase one per bucket) — tests read it to assert no stage re-pads
+#     behind the pipeline's back.
+#   * ``slot.scatter`` — the **controlled exemption** from the build-once
+#     contract: in-place slot scatters (`update_slots` / `free_slots`)
+#     into a resident `SlotPoolBatch`.  The streaming service mutates one
+#     long-lived batch instead of rebuilding per epoch, so its builds stay
+#     at the pool constructions while this counter tracks the epoch
+#     updates — tests read both to assert the service never re-packs.
+#   * ``slot.grow`` — arena regrowths (flow-axis capacity bumps) of
+#     resident slot pools: each one is a new padded flow shape, i.e. one
+#     entry of the epoch compile-cache bucket ladder.  Geometric growth
+#     bounds it to O(log(total flows) / log 2) distinct shapes per pool.
 
 
 def _round_up(n: int, q: int) -> int:
@@ -247,7 +243,7 @@ class EnsembleBatch:
         (B*reps,) index maps send an expanded row to its source instance
         and candidate slot (see `expansion_maps`).  This is a pure gather
         of an existing build, not a re-pack from instances, so
-        `BUILD_COUNT` is intentionally NOT bumped — the one-build-per-
+        ``ensemble.build`` is intentionally NOT counted — the one-build-per-
         ensemble contract still counts constructions from host data.
         """
         reps = int(reps)
@@ -377,8 +373,7 @@ def build_ensemble_batch(
     and O(B*Mp*Pp) port statistics (keeping the cheap masks) — the mode
     `Pipeline.run_batch` uses when LP solutions are solved upstream.
     """
-    global BUILD_COUNT
-    BUILD_COUNT += 1
+    count("ensemble.build")
 
     instances = list(instances)
     B = len(instances)
@@ -519,7 +514,7 @@ class SlotPoolBatch:
     residual demands, weights, releases and masks **in place** — frozen
     `EnsembleBatch` fields cannot be rebound, but their array *contents*
     are mutable, which is exactly the controlled exemption from the
-    build-once contract that `SLOT_SCATTER_COUNT` tracks.
+    build-once contract that the ``slot.scatter`` counter tracks.
 
     Why shapes stay fixed: every epoch re-solve consumes the same
     (slots, flow_capacity, ports, cores)-shaped pytree, so the jitted
@@ -646,10 +641,9 @@ def _grow_arena(pool: SlotPoolBatch, need: int) -> None:
 
     Doubling (rounded to the quantum) keeps the number of distinct arena
     shapes — and therefore jitted-stage recompiles — logarithmic in the
-    total flow volume; `SLOT_GROW_COUNT` counts the ladder steps.
+    total flow volume; ``slot.grow`` counts the ladder steps.
     """
-    global SLOT_GROW_COUNT
-    SLOT_GROW_COUNT += 1
+    count("slot.grow")
     b = pool.batch
     new_cap = _round_up(max(need, 2 * pool.flow_capacity), pool.flow_quantum)
 
@@ -721,11 +715,10 @@ def update_slots(
     (largest-first — `flows_of`), port statistics and global lower bound
     and writes them into the resident arrays: **no rebuild**, the one
     sanctioned mutation of a frozen `EnsembleBatch` (counted by
-    `SLOT_SCATTER_COUNT`).  Slots whose flow count exceeds their extent
+    ``slot.scatter``).  Slots whose flow count exceeds their extent
     re-reserve (first-fit / compact / geometric grow).
     """
-    global SLOT_SCATTER_COUNT
-    SLOT_SCATTER_COUNT += 1
+    count("slot.scatter")
     slots = np.asarray(slots, dtype=np.int64)
     demands = np.asarray(demands, dtype=np.float64)
     b, r = pool.batch, pool.member
@@ -780,8 +773,7 @@ def free_slots(pool: SlotPoolBatch, slots: np.ndarray) -> None:
     a previous tenant's demands into a later epoch, and the stale-leak
     tests diff the raw arrays to enforce it.
     """
-    global SLOT_SCATTER_COUNT
-    SLOT_SCATTER_COUNT += 1
+    count("slot.scatter")
     slots = np.asarray(slots, dtype=np.int64)
     b, r = pool.batch, pool.member
     for s in slots:

@@ -26,7 +26,6 @@ kinds chosen at construction time.
 from __future__ import annotations
 
 import dataclasses
-import time
 from typing import Any, Sequence
 
 import numpy as np
@@ -45,6 +44,7 @@ from repro.pipeline.refine import (
     refine_sequential,
 )
 from repro.pipeline.spec import SchemeSpec, get_scheme
+from repro.trace import span
 
 __all__ = ["Pipeline", "build_pipeline", "get_pipeline", "order_view"]
 
@@ -154,7 +154,6 @@ class Pipeline:
         to `run_batch`'s batched search.
         """
         order, lp_sol = self.order_stage.order(instance, lp_solution)
-        t0 = time.perf_counter()
         eff_refine = self._resolve_refine(refine)
         if eff_refine is not None:
             order, _, _, _, _ = refine_sequential(
@@ -172,7 +171,6 @@ class Pipeline:
             ccts=ccts,
             total_weighted_cct=total_weighted_cct(instance, ccts),
             lp=lp_sol,
-            wall_time_s=time.perf_counter() - t0,
         )
 
     def _order_key(self) -> tuple:
@@ -242,10 +240,8 @@ class Pipeline:
 
         ``lp_solutions`` plugs the output of `solve_subgradient_batch` /
         `solve_ensemble_lp` straight in (one solution per instance, input
-        order).  Each result's ``wall_time_s`` covers that instance's
-        circuit stage (its own loop time, or its amortized share of the
-        batched calendar) plus its amortized share of the batched
-        allocation.
+        order).  The stages time themselves as `repro.trace` spans
+        (``pipeline.*``, ``alloc.*``, ``calendar.*``).
 
         ``stage_cache`` shares computed stage outputs between pipelines
         run over the *same* ``(instances, lp_solutions)``: pass one dict
@@ -298,9 +294,10 @@ class Pipeline:
             # run_batch never solves the ordering LP itself (solutions are
             # supplied, or LP-needing stages solve per instance), so skip
             # packing the heavy LP solver inputs.
-            ensemble = build_ensemble_batch(
-                instances, mesh=mesh, with_lp_arrays=False
-            )
+            with span("pipeline.build"):
+                ensemble = build_ensemble_batch(
+                    instances, mesh=mesh, with_lp_arrays=False
+                )
         elif mesh is not None:
             # A cached/provided batch carries its own sharding; a
             # *different* explicit mesh request must not be silently
@@ -353,7 +350,6 @@ class Pipeline:
                 stage_cache[self._order_key()] = cached
         orders_arr, lp_list = cached
         lp_list = lp_list if lp_list is not None else [None] * B
-        t0 = time.perf_counter()
 
         # --- refinement: candidate search on the realized objective -------
         eff_refine = self._resolve_refine(refine)
@@ -425,7 +421,8 @@ class Pipeline:
             if arrays_fn is not None:
                 alloc_batch = arrays_fn(ensemble, orders_arr)
             if alloc_batch is not None:
-                allocs = alloc_batch.materialize(ensemble)
+                with span("alloc.materialize"):
+                    allocs = alloc_batch.materialize(ensemble)
             else:
                 batch_fn = getattr(
                     self.allocate_stage, "allocate_batch", None
@@ -452,7 +449,6 @@ class Pipeline:
             if stage_cache is not None:
                 stage_cache[self._alloc_key(refine_t)] = a_cached
         alloc_batch, allocs = a_cached
-        alloc_share = (time.perf_counter() - t0) / max(B, 1)
 
         # --- circuit: padded calendar off the pytrees ---------------------
         # Stages without any batched form (sequential / bvn / fluid —
@@ -460,13 +456,10 @@ class Pipeline:
         # loop.  ``require_batch`` turns a *fallback* of a batch-capable
         # stage (e.g. backend "loop") into an error, but leaves loop-only
         # stages alone.
-        per_instance_s = None
-        circuit_share = 0.0
         pairs = None if stage_cache is None else stage_cache.get(
             self._circuit_key(refine_t)
         )
         if pairs is None:
-            t1 = time.perf_counter()
             arrays_fn = getattr(
                 self.circuit_stage, "schedule_batch_arrays", None
             )
@@ -485,15 +478,10 @@ class Pipeline:
                         f"{type(self.circuit_stage).__name__}, backend "
                         f"{getattr(self.circuit_stage, 'backend', None)!r})"
                     )
-                pairs, per_instance_s = [], []
-                for inst, order, alloc in zip(instances, orders, allocs):
-                    t2 = time.perf_counter()
-                    pairs.append(
-                        self.circuit_stage.schedule(inst, alloc, order)
-                    )
-                    per_instance_s.append(time.perf_counter() - t2)
-            else:
-                circuit_share = (time.perf_counter() - t1) / max(B, 1)
+                pairs = [
+                    self.circuit_stage.schedule(inst, alloc, order)
+                    for inst, order, alloc in zip(instances, orders, allocs)
+                ]
             if stage_cache is not None:
                 stage_cache[self._circuit_key(refine_t)] = pairs
 
@@ -504,11 +492,8 @@ class Pipeline:
         ):
             schedules, ccts = pairs[i]
             if validate and schedules is not None:
-                validate_schedule(inst, schedules)
-            wall = alloc_share + (
-                per_instance_s[i] if per_instance_s is not None
-                else circuit_share
-            )
+                with span("pipeline.validate"):
+                    validate_schedule(inst, schedules)
             results.append(
                 ScheduleResult(
                     scheme=self.spec.name,
@@ -518,7 +503,6 @@ class Pipeline:
                     ccts=ccts,
                     total_weighted_cct=total_weighted_cct(inst, ccts),
                     lp=lp_sol,
-                    wall_time_s=wall,
                 )
             )
         return results

@@ -81,6 +81,7 @@ import numpy as np
 
 import jax.numpy as jnp
 
+from repro import trace
 from repro.core import lp
 from repro.core.allocation import Allocation
 from repro.core.circuit import CoreSchedule
@@ -106,7 +107,17 @@ EPOCH_MODES = ("auto", "rebuild", "resident")
 
 @dataclasses.dataclass
 class EpochRecord:
-    """One re-solve: who was active, what the scheduler decided."""
+    """One re-solve: who was active, what the scheduler decided.
+
+    ``spans`` holds the host seconds of each `repro.trace` span of the
+    epoch, from settling the incumbent calendar to appending this record:
+    the leaf spans (``stream.advance`` / ``admit`` / ``scatter`` / ``lp``
+    / ``order`` / ``validate`` / ``record``, ``alloc.*``, ``calendar.*``)
+    tile it, and ``stream.epoch`` encloses them all.  ``counts`` holds
+    the epoch's counters (``host_reads``, ``calendar.*``, ``slot.*``).
+    ``lp_wall_s`` is the ``stream.lp`` span; ``wall_s`` runs from the
+    decision's start (after admission) to this record.
+    """
 
     index: int
     time: float  # epoch (event) time
@@ -122,6 +133,8 @@ class EpochRecord:
     num_busy: int  # phantom committed circuits carried in
     wall_s: float
     lp_objective: float | None = None  # kept even when `lp` is dropped
+    spans: dict[str, float] = dataclasses.field(default_factory=dict)
+    counts: dict[str, int] = dataclasses.field(default_factory=dict)
 
 
 @dataclasses.dataclass
@@ -145,6 +158,10 @@ class StreamResult:
     wall_time_s: float
     admission_policy: str = "fifo"  # slot-pool policy (see SlotPool)
     epoch_mode: str = "rebuild"  # resolved epoch driver (never "auto")
+    # Call totals of the `repro.trace` spans and counters (every epoch,
+    # the resident pool's build and the final settlement).
+    spans: dict[str, float] = dataclasses.field(default_factory=dict)
+    counts: dict[str, int] = dataclasses.field(default_factory=dict)
 
     @property
     def realized_weighted_cct(self) -> float:
@@ -301,7 +318,7 @@ class _WarmState:
             self.Y, jnp.asarray(self.solved), jnp.asarray(slots_padded),
             default_Y0,
         )
-        return Y0, bool(any_warm)
+        return Y0, bool(trace.to_host(any_warm))
 
     def scatter_device(
         self, slots_padded: np.ndarray, slots: np.ndarray, y_dense
@@ -563,6 +580,7 @@ def stream(
     )
     resident = epoch_mode == "resident"
     warm = _WarmState(S, device=resident)
+    totals = trace.Tally()  # the call's spans and counters
     rpool = None
     slot_to_global = None
     if resident:
@@ -570,12 +588,15 @@ def stream(
         # growth; the geometric ladder covers estimate misses.
         nnz = int(np.count_nonzero(residual))
         expected = -(-nnz * min(S, M) // M) if M else 0
-        rpool = build_slot_pool_batch(
-            S, instance.num_ports, rates_by_core, instance.delta,
-            flow_quantum=_round_up(
-                max(int(flow_quantum), expected, 1), max(int(flow_quantum), 1)
-            ),
-        )
+        with trace.collect() as setup:
+            rpool = build_slot_pool_batch(
+                S, instance.num_ports, rates_by_core, instance.delta,
+                flow_quantum=_round_up(
+                    max(int(flow_quantum), expected, 1),
+                    max(int(flow_quantum), 1),
+                ),
+            )
+        totals.add(setup)
         slot_to_global = np.full(S, -1, dtype=np.int64)
     finished = np.zeros(M, dtype=bool)
     calendar = _Calendar.empty()
@@ -662,124 +683,138 @@ def stream(
     def _busy_count() -> int:
         return int(busy.k.size)
 
-    def _epoch_rebuild(now: float, admitted: list[int]) -> None:
+    def _epoch_rebuild(
+        now: float, admitted: list[int], tally: trace.Tally
+    ) -> None:
         """PR 7 epoch: dense residual instance, fresh `EnsembleBatch`."""
         nonlocal calendar
         t_epoch = time.perf_counter()
-        actives = pool.active_ids()
-        if not actives:
-            return
-        act = np.asarray(actives, dtype=np.int64)
-        Me = act.shape[0]
-        inst_e = CoflowInstance(
-            demands=residual[act].copy(),
-            weights=result.weights[act].copy(),
-            releases=np.maximum(result.arrival[act], now),
-            rates=rates_by_core.copy(),
-            delta=instance.delta,
-        )
+        with trace.span("stream.scatter"):
+            actives = pool.active_ids()
+            if not actives:
+                return
+            act = np.asarray(actives, dtype=np.int64)
+            Me = act.shape[0]
+            inst_e = CoflowInstance(
+                demands=residual[act].copy(),
+                weights=result.weights[act].copy(),
+                releases=np.maximum(result.arrival[act], now),
+                rates=rates_by_core.copy(),
+                delta=instance.delta,
+            )
 
         lp_sol = None
         is_warm = False
         iters_used = 0
         lp_wall = 0.0
         if needs_lp:
-            t_lp = time.perf_counter()
-            if lp_method == "exact":
-                lp_sol = lp.solve_exact(inst_e)
-            else:
-                arrays = lp.pack_lp_arrays(
-                    [inst_e], pad_coflows=S, pad_ports=two_pi_ports
-                )
-                slots = pool.slots_of(actives)
-                if warm_start:
-                    Y0, is_warm = warm.gather(
-                        slots, arrays["Y0"][0, :Me, :Me]
+            with trace.span("stream.lp") as lp_span:
+                if lp_method == "exact":
+                    lp_sol = lp.solve_exact(inst_e)
+                else:
+                    arrays = lp.pack_lp_arrays(
+                        [inst_e], pad_coflows=S, pad_ports=two_pi_ports
                     )
-                    arrays["Y0"][0, :Me, :Me] = Y0
-                iters_used = lp_iters_warm if is_warm else lp_iters
-                batch = lp.solve_subgradient_batch_arrays(
-                    arrays, iters=iters_used
-                )
-                lp_sol = batch.unpack([Me])[0]
-                warm.scatter(slots, lp_sol.precedence)
-            lp_wall = time.perf_counter() - t_lp
+                    slots = pool.slots_of(actives)
+                    if warm_start:
+                        Y0, is_warm = warm.gather(
+                            slots, arrays["Y0"][0, :Me, :Me]
+                        )
+                        arrays["Y0"][0, :Me, :Me] = Y0
+                    iters_used = lp_iters_warm if is_warm else lp_iters
+                    batch = lp.solve_subgradient_batch_arrays(
+                        arrays, iters=iters_used
+                    )
+                    lp_sol = batch.unpack([Me])[0]
+                    warm.scatter(slots, lp_sol.precedence)
+            lp_wall = lp_span.seconds
             result.lp_time_s += lp_wall
 
-        ensemble = build_ensemble_batch([inst_e], with_lp_arrays=False)
-        if needs_lp:
-            comp = np.zeros(ensemble.weights.shape)
-            comp[0, :Me] = lp_sol.completion
-            orders_arr = order_stage.order_batch(ensemble, comp)
-        else:
-            orders_arr = order_stage.order_batch(ensemble)
+        with trace.span("stream.scatter"):
+            ensemble = build_ensemble_batch([inst_e], with_lp_arrays=False)
+        with trace.span("stream.order"):
+            if needs_lp:
+                comp = np.zeros(ensemble.weights.shape)
+                comp[0, :Me] = lp_sol.completion
+                orders_arr = order_stage.order_batch(ensemble, comp)
+            else:
+                orders_arr = order_stage.order_batch(ensemble)
         alloc_batch = pipe.allocate_stage.allocate_batch_arrays(
             ensemble, orders_arr
         )
-        busy_tabs = busy.tables(now, instance.num_cores)
+        with trace.span("calendar.pack"):
+            busy_tabs = busy.tables(now, instance.num_cores)
         pairs = schedule_batch_arrays(
             ensemble, alloc_batch,
             discipline=circuit.discipline, engine=circuit.engine,
             busy=busy_tabs,
         )
-        schedules, ccts_e = pairs[0]
+        with trace.span("calendar.readback"):
+            schedules, ccts_e = pairs[0]
         if validate:
-            validate_schedule(inst_e, schedules)
+            with trace.span("stream.validate"):
+                validate_schedule(inst_e, schedules)
 
-        calendar = _Calendar.from_schedules(schedules, act)
-        last_ccts[act] = np.asarray(ccts_e, dtype=np.float64)
+        with trace.span("stream.record"):
+            calendar = _Calendar.from_schedules(schedules, act)
+            last_ccts[act] = np.asarray(ccts_e, dtype=np.float64)
 
-        alloc = alloc_batch.materialize(ensemble)[0]
-        order_dense = np.asarray(orders_arr[0][:Me])
-        result.epochs.append(
-            EpochRecord(
-                index=len(result.epochs),
-                time=now,
-                actives=act,
-                admitted=np.asarray(admitted, dtype=np.int64),
-                order=act[order_dense],
-                allocation=alloc,
-                ccts=np.asarray(ccts_e, dtype=np.float64).copy(),
-                lp=lp_sol,
-                warm=is_warm,
-                lp_iters_used=iters_used,
-                lp_wall_s=lp_wall,
-                num_busy=0 if busy_tabs is None else _busy_count(),
-                wall_s=time.perf_counter() - t_epoch,
-                lp_objective=(
-                    float(lp_sol.objective) if lp_sol is not None else None
-                ),
+            alloc = alloc_batch.materialize(ensemble)[0]
+            order_dense = np.asarray(orders_arr[0][:Me])
+            result.epochs.append(
+                EpochRecord(
+                    index=len(result.epochs),
+                    time=now,
+                    actives=act,
+                    admitted=np.asarray(admitted, dtype=np.int64),
+                    order=act[order_dense],
+                    allocation=alloc,
+                    ccts=np.asarray(ccts_e, dtype=np.float64).copy(),
+                    lp=lp_sol,
+                    warm=is_warm,
+                    lp_iters_used=iters_used,
+                    lp_wall_s=lp_wall,
+                    num_busy=0 if busy_tabs is None else _busy_count(),
+                    wall_s=time.perf_counter() - t_epoch,
+                    lp_objective=(
+                        float(lp_sol.objective) if lp_sol is not None
+                        else None
+                    ),
+                    spans=tally.spans,
+                    counts=tally.counts,
+                )
             )
-        )
 
     def _epoch_resident(
-        now: float, admitted: list[int], dirty: np.ndarray
+        now: float, admitted: list[int], dirty: np.ndarray,
+        tally: trace.Tally,
     ) -> None:
         """Device-resident epoch: scatter into the slot pool, solve at
         fixed padded shapes, read the calendar back in slot space."""
         nonlocal calendar
         t_epoch = time.perf_counter()
-        actives = pool.active_ids()
-        if not actives:
-            return
-        act = np.asarray(actives, dtype=np.int64)
-        Me = act.shape[0]
-        slots = pool.slots_of(actives)  # aligned with ascending-id order
-        rel_clamped = np.maximum(result.arrival[act], now)
+        with trace.span("stream.scatter"):
+            actives = pool.active_ids()
+            if not actives:
+                return
+            act = np.asarray(actives, dtype=np.int64)
+            Me = act.shape[0]
+            slots = pool.slots_of(actives)  # aligned with ascending-id order
+            rel_clamped = np.maximum(result.arrival[act], now)
 
-        # In-place slot scatter: residuals that changed since the last
-        # epoch (settled/preempted) plus fresh admissions; every active
-        # slot gets the per-epoch release clamp.
-        upd = np.union1d(np.asarray(admitted, dtype=np.int64), dirty)
-        if upd.size:
-            upd_slots = pool.slots_of(upd)
-            update_slots(
-                rpool, upd_slots, residual[upd], result.weights[upd],
-                np.maximum(result.arrival[upd], now),
-            )
-            slot_to_global[upd_slots] = upd
-        set_slot_releases(rpool, slots, rel_clamped)
-        b = rpool.batch
+            # In-place slot scatter: residuals that changed since the last
+            # epoch (settled/preempted) plus fresh admissions; every active
+            # slot gets the per-epoch release clamp.
+            upd = np.union1d(np.asarray(admitted, dtype=np.int64), dirty)
+            if upd.size:
+                upd_slots = pool.slots_of(upd)
+                update_slots(
+                    rpool, upd_slots, residual[upd], result.weights[upd],
+                    np.maximum(result.arrival[upd], now),
+                )
+                slot_to_global[upd_slots] = upd
+            set_slot_releases(rpool, slots, rel_clamped)
+            b = rpool.batch
 
         lp_sol_objective = None
         is_warm = False
@@ -787,178 +822,199 @@ def stream(
         lp_wall = 0.0
         comp_dense = None
         if needs_lp:
-            t_lp = time.perf_counter()
-            # Dense-gathered LP inputs: bit-equal to
-            # `pack_lp_arrays([inst_e], pad_coflows=S, pad_ports=2N)`
-            # (per-slot f32 rows were cast from the same f64 values at
-            # scatter time), so the same compiled solver program runs —
-            # zero LP retraces across epochs.
-            Y0_default = np.zeros((S, S), dtype=np.float32)
-            Y0_default[:Me, :Me] = lp.warm_start_Y0_dense(
-                result.weights[act], b.glb[0, slots]
-            )
-            slots_padded = np.full(S, S, dtype=np.int32)
-            slots_padded[:Me] = slots
-            if warm_start:
-                Y0_dev, is_warm = warm.gather_device(
-                    slots_padded, jnp.asarray(Y0_default)
+            with trace.span("stream.lp") as lp_span:
+                # Dense-gathered LP inputs: bit-equal to
+                # `pack_lp_arrays([inst_e], pad_coflows=S, pad_ports=2N)`
+                # (per-slot f32 rows were cast from the same f64 values at
+                # scatter time), so the same compiled solver program runs —
+                # zero LP retraces across epochs.
+                Y0_default = np.zeros((S, S), dtype=np.float32)
+                Y0_default[:Me, :Me] = lp.warm_start_Y0_dense(
+                    result.weights[act], b.glb[0, slots]
                 )
-            else:
-                Y0_dev = jnp.asarray(Y0_default)
-            rho_d = np.zeros_like(b.lp_rho)
-            tau_d = np.zeros_like(b.lp_tau)
-            w_d = np.zeros_like(b.lp_weights)
-            r_d = np.zeros_like(b.lp_releases)
-            mask_d = np.zeros_like(b.coflow_mask)
-            rho_d[0, :Me] = b.lp_rho[0, slots]
-            tau_d[0, :Me] = b.lp_tau[0, slots]
-            w_d[0, :Me] = b.lp_weights[0, slots]
-            r_d[0, :Me] = b.lp_releases[0, slots]
-            mask_d[0, :Me] = True
-            arrays = dict(
-                Y0=Y0_dev[None], p_rho=rho_d, p_tau=tau_d, weights=w_d,
-                releases=r_d, inv_R=b.inv_R, delta_over_K=b.delta_over_K,
-                coflow_mask=mask_d, port_mask=b.port_mask,
-            )
-            iters_used = lp_iters_warm if is_warm else lp_iters
-            batch_sol = lp.solve_subgradient_batch_arrays(
-                arrays, iters=iters_used
-            )
-            comp_dense = np.asarray(batch_sol.completion)[0]
-            lp_sol_objective = float(np.asarray(batch_sol.objective)[0])
-            warm.scatter_device(slots_padded, slots, batch_sol.y[0])
-            lp_wall = time.perf_counter() - t_lp
+                slots_padded = np.full(S, S, dtype=np.int32)
+                slots_padded[:Me] = slots
+                if warm_start:
+                    Y0_dev, is_warm = warm.gather_device(
+                        slots_padded, jnp.asarray(Y0_default)
+                    )
+                else:
+                    Y0_dev = jnp.asarray(Y0_default)
+                rho_d = np.zeros_like(b.lp_rho)
+                tau_d = np.zeros_like(b.lp_tau)
+                w_d = np.zeros_like(b.lp_weights)
+                r_d = np.zeros_like(b.lp_releases)
+                mask_d = np.zeros_like(b.coflow_mask)
+                rho_d[0, :Me] = b.lp_rho[0, slots]
+                tau_d[0, :Me] = b.lp_tau[0, slots]
+                w_d[0, :Me] = b.lp_weights[0, slots]
+                r_d[0, :Me] = b.lp_releases[0, slots]
+                mask_d[0, :Me] = True
+                arrays = dict(
+                    Y0=Y0_dev[None], p_rho=rho_d, p_tau=tau_d, weights=w_d,
+                    releases=r_d, inv_R=b.inv_R, delta_over_K=b.delta_over_K,
+                    coflow_mask=mask_d, port_mask=b.port_mask,
+                )
+                iters_used = lp_iters_warm if is_warm else lp_iters
+                batch_sol = lp.solve_subgradient_batch_arrays(
+                    arrays, iters=iters_used
+                )
+                comp_all, obj_all = trace.to_host(
+                    batch_sol.completion, batch_sol.objective
+                )
+                comp_dense = comp_all[0]
+                lp_sol_objective = float(obj_all[0])
+                warm.scatter_device(slots_padded, slots, batch_sol.y[0])
+            lp_wall = lp_span.seconds
             result.lp_time_s += lp_wall
 
-        # Dense ordering view over the resident vectors (gathered to the
-        # ascending-global-id dense convention, masked padding at the
-        # tail) — the same keys, masks and stable sort as the rebuild
-        # path, so dense positions 0..Me-1 order identically.
-        w64 = np.zeros((1, S))
-        glb64 = np.zeros((1, S))
-        rel64 = np.zeros((1, S))
-        mask64 = np.zeros((1, S), dtype=bool)
-        w64[0, :Me] = b.weights[0, slots]
-        glb64[0, :Me] = b.glb[0, slots]
-        rel64[0, :Me] = rel_clamped
-        mask64[0, :Me] = True
-        view = order_view(w64, glb64, rel64, mask64)
-        if needs_lp:
-            comp = np.zeros((1, S))
-            comp[0, :Me] = comp_dense[:Me]
-            orders_dense = order_stage.order_batch(view, comp)
-        else:
-            orders_dense = order_stage.order_batch(view)
-        order_dense = np.asarray(orders_dense[0][:Me])
+        with trace.span("stream.order"):
+            # Dense ordering view over the resident vectors (gathered to
+            # the ascending-global-id dense convention, masked padding at
+            # the tail) — the same keys, masks and stable sort as the
+            # rebuild path, so dense positions 0..Me-1 order identically.
+            w64 = np.zeros((1, S))
+            glb64 = np.zeros((1, S))
+            rel64 = np.zeros((1, S))
+            mask64 = np.zeros((1, S), dtype=bool)
+            w64[0, :Me] = b.weights[0, slots]
+            glb64[0, :Me] = b.glb[0, slots]
+            rel64[0, :Me] = rel_clamped
+            mask64[0, :Me] = True
+            view = order_view(w64, glb64, rel64, mask64)
+            if needs_lp:
+                comp = np.zeros((1, S))
+                comp[0, :Me] = comp_dense[:Me]
+                orders_dense = order_stage.order_batch(view, comp)
+            else:
+                orders_dense = order_stage.order_batch(view)
+            order_dense = np.asarray(orders_dense[0][:Me])
 
-        # Slot-space order: active slots by dense priority, free slots at
-        # the tail (their flows are invalid — exact no-op scan steps).
-        order_slots = np.empty(S, dtype=np.int64)
-        order_slots[:Me] = slots[order_dense]
-        order_slots[Me:] = np.setdiff1d(
-            np.arange(S, dtype=np.int64), slots, assume_unique=True
-        )
+            # Slot-space order: active slots by dense priority, free slots
+            # at the tail (their flows are invalid — exact no-op scan
+            # steps).
+            order_slots = np.empty(S, dtype=np.int64)
+            order_slots[:Me] = slots[order_dense]
+            order_slots[Me:] = np.setdiff1d(
+                np.arange(S, dtype=np.int64), slots, assume_unique=True
+            )
         alloc_batch = pipe.allocate_stage.allocate_batch_arrays(
             b, order_slots[None, :]
         )
-        busy_tabs = busy.tables(now, instance.num_cores)
+        with trace.span("calendar.pack"):
+            busy_tabs = busy.tables(now, instance.num_cores)
         pairs = schedule_batch_arrays(
             b, alloc_batch,
             discipline=circuit.discipline, engine=circuit.engine,
             busy=busy_tabs,
         )
-        schedules, ccts_slot = pairs[0]  # slot-indexed (S,) CCTs
-        ccts_dense = np.asarray(ccts_slot, dtype=np.float64)[slots]
+        with trace.span("calendar.readback"):
+            schedules, ccts_slot = pairs[0]  # slot-indexed (S,) CCTs
+            ccts_dense = np.asarray(ccts_slot, dtype=np.float64)[slots]
         if validate:
-            inst_e = CoflowInstance(
-                demands=residual[act].copy(),
-                weights=result.weights[act].copy(),
-                releases=rel_clamped,
-                rates=rates_by_core.copy(),
-                delta=instance.delta,
-            )
-            dense_of_slot = np.full(S, -1, dtype=np.int64)
-            dense_of_slot[slots] = np.arange(Me, dtype=np.int64)
-            remapped = [
-                CoreSchedule(
-                    coflow=dense_of_slot[cs.coflow], src=cs.src, dst=cs.dst,
-                    size=cs.size, establish=cs.establish,
-                    complete=cs.complete, rate=cs.rate, delta=cs.delta,
+            with trace.span("stream.validate"):
+                inst_e = CoflowInstance(
+                    demands=residual[act].copy(),
+                    weights=result.weights[act].copy(),
+                    releases=rel_clamped,
+                    rates=rates_by_core.copy(),
+                    delta=instance.delta,
                 )
-                for cs in schedules
-            ]
-            validate_schedule(inst_e, remapped)
+                dense_of_slot = np.full(S, -1, dtype=np.int64)
+                dense_of_slot[slots] = np.arange(Me, dtype=np.int64)
+                remapped = [
+                    CoreSchedule(
+                        coflow=dense_of_slot[cs.coflow], src=cs.src,
+                        dst=cs.dst, size=cs.size, establish=cs.establish,
+                        complete=cs.complete, rate=cs.rate, delta=cs.delta,
+                    )
+                    for cs in schedules
+                ]
+                validate_schedule(inst_e, remapped)
 
-        calendar = _Calendar.from_schedules(schedules, slot_to_global)
-        last_ccts[act] = ccts_dense
+        with trace.span("stream.record"):
+            calendar = _Calendar.from_schedules(schedules, slot_to_global)
+            last_ccts[act] = ccts_dense
 
-        result.epochs.append(
-            EpochRecord(
-                index=len(result.epochs),
-                time=now,
-                actives=act,
-                admitted=np.asarray(admitted, dtype=np.int64),
-                order=act[order_dense],
-                allocation=None,  # slot-space; see `epochs[...].order`
-                ccts=ccts_dense.copy(),
-                lp=None,
-                warm=is_warm,
-                lp_iters_used=iters_used,
-                lp_wall_s=lp_wall,
-                num_busy=0 if busy_tabs is None else _busy_count(),
-                wall_s=time.perf_counter() - t_epoch,
-                lp_objective=lp_sol_objective,
+            result.epochs.append(
+                EpochRecord(
+                    index=len(result.epochs),
+                    time=now,
+                    actives=act,
+                    admitted=np.asarray(admitted, dtype=np.int64),
+                    order=act[order_dense],
+                    allocation=None,  # slot-space; see `epochs[...].order`
+                    ccts=ccts_dense.copy(),
+                    lp=None,
+                    warm=is_warm,
+                    lp_iters_used=iters_used,
+                    lp_wall_s=lp_wall,
+                    num_busy=0 if busy_tabs is None else _busy_count(),
+                    wall_s=time.perf_counter() - t_epoch,
+                    lp_objective=lp_sol_objective,
+                    spans=tally.spans,
+                    counts=tally.counts,
+                )
             )
-        )
 
-    def _epoch(now: float, admitted: list[int], dirty: np.ndarray) -> None:
-        if resident:
-            _epoch_resident(now, admitted, dirty)
-        else:
-            _epoch_rebuild(now, admitted)
+    def _epoch(now: float, ids: list[int] | None) -> None:
+        """One event: settle at ``now``, queue ``ids`` (arrivals; None
+        for a drain event), admit, re-solve.  The epoch's spans and
+        counters go to its `EpochRecord` and the call's totals."""
+        with trace.collect() as tally, trace.span("stream.epoch"):
+            with trace.span("stream.advance"):
+                dirty = _advance(now)
+            with trace.span("stream.admit"):
+                if ids is not None:
+                    pool.push(ids)
+                admitted = _admit(now)
+                if ids is None and not admitted:
+                    raise RuntimeError(
+                        "drain epoch freed no slot — non-increasing "
+                        "calendar?"
+                    )
+            if resident:
+                _epoch_resident(now, admitted, dirty, tally)
+            else:
+                _epoch_rebuild(now, admitted, tally)
+        totals.add(tally)
 
     # --- event loop -------------------------------------------------------
     for now, ids in _arrival_batches(result.arrival, n_batches, batch_window):
-        dirty = _advance(now)
-        pool.push(ids)
-        admitted = _admit(now)
-        _epoch(now, admitted, dirty)
+        _epoch(now, ids)
 
     while pool.queue:  # pool-bound overflow: admit as slots drain
         act = pool.active_array()
         if act.size == 0:
             raise RuntimeError("admission queue stuck with an empty pool")
-        now = float(last_ccts[act].min())
-        dirty = _advance(now)
-        admitted = _admit(now)
-        if not admitted:
-            raise RuntimeError(
-                "drain epoch freed no slot — non-increasing calendar?"
-            )
-        _epoch(now, admitted, dirty)
+        _epoch(float(last_ccts[act].min()), None)
 
     # Final calendar runs to completion undisturbed.
-    if calendar.m.size:
-        residual[calendar.m, calendar.i, calendar.j] -= calendar.size
-        np.maximum.at(result.finish, calendar.m, calendar.comp)
-        calendar = _Calendar.empty()
-    np.maximum(residual, 0.0, out=residual)
-    act = pool.active_array()
-    for m in act:
-        if residual[m].any():
-            raise RuntimeError(
-                f"coflow {m} left {residual[m].sum():g} undelivered demand"
-            )
-    if act.size:
-        finished[act] = True
-        slots = pool.release_many(act)
-        warm.forget_slots(slots)
-        if resident:
-            free_slots(rpool, slots)
-            slot_to_global[slots] = -1
+    with trace.collect() as tally, trace.span("stream.finish"):
+        if calendar.m.size:
+            residual[calendar.m, calendar.i, calendar.j] -= calendar.size
+            np.maximum.at(result.finish, calendar.m, calendar.comp)
+            calendar = _Calendar.empty()
+        np.maximum(residual, 0.0, out=residual)
+        act = pool.active_array()
+        for m in act:
+            if residual[m].any():
+                raise RuntimeError(
+                    f"coflow {m} left {residual[m].sum():g} undelivered "
+                    "demand"
+                )
+        if act.size:
+            finished[act] = True
+            slots = pool.release_many(act)
+            warm.forget_slots(slots)
+            if resident:
+                free_slots(rpool, slots)
+                slot_to_global[slots] = -1
+    totals.add(tally)
     if not finished.all():
         missing = np.nonzero(~finished)[0]
         raise RuntimeError(f"coflows never completed: {missing.tolist()}")
 
+    result.spans, result.counts = totals.spans, totals.counts
     result.wall_time_s = time.perf_counter() - t_start
     return result

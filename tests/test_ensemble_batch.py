@@ -1,7 +1,7 @@
 """Tests for the unified `EnsembleBatch` pytree and the array pipeline.
 
 Covers the one-build-per-ensemble contract (the stage-boundary
-`BUILD_COUNT`), the canonical-flow-table permutation against the
+``ensemble.build`` counter), the canonical-flow-table permutation against the
 host-side `flow_sequence` oracle, batched ordering parity for all three
 order stages, the direct LP-batch -> ordering feed, the stage_cache
 ensemble-fingerprint guard, and degenerate (M=0 / empty) ensembles
@@ -19,6 +19,7 @@ from repro.core.ordering import fifo_order, wspt_order
 from repro.pipeline import ensemble_batch as eb
 from repro.pipeline.batch_alloc import allocate_batch_arrays, flow_sequence
 from repro.pipeline.batch_circuit import schedule_batch, schedule_batch_arrays
+from repro.trace import collect
 from repro.traffic.instances import random_instance
 
 GRID = [(5, 3, 2, 0), (8, 4, 3, 1), (10, 4, 4, 2), (6, 5, 2, 3)]
@@ -44,26 +45,26 @@ def test_run_batch_builds_exactly_one_ensemble_batch(grid_with_lp):
     (no per-stage re-padding), asserted via the build counter."""
     instances, sols = grid_with_lp
     cache: dict = {}
-    before = eb.BUILD_COUNT
-    for scheme in pipeline.PAPER_SCHEMES:
-        pipeline.get_pipeline(scheme).run_batch(
-            instances, lp_solutions=sols, stage_cache=cache,
-            require_batch=True,
+    with collect() as tally:
+        for scheme in pipeline.PAPER_SCHEMES:
+            pipeline.get_pipeline(scheme).run_batch(
+                instances, lp_solutions=sols, stage_cache=cache,
+                require_batch=True,
+            )
+        assert tally.counts["ensemble.build"] == 1
+        # A rerun over the same cache (e.g. certify's reserving pass)
+        # reuses the cached pytree: still zero additional builds.
+        pipeline.get_pipeline("ours", discipline="reserving").run_batch(
+            instances, lp_solutions=sols, stage_cache=cache
         )
-    assert eb.BUILD_COUNT - before == 1
-    # A rerun over the same cache (e.g. certify's reserving pass) reuses
-    # the cached pytree: still zero additional builds.
-    pipeline.get_pipeline("ours", discipline="reserving").run_batch(
-        instances, lp_solutions=sols, stage_cache=cache
-    )
-    assert eb.BUILD_COUNT - before == 1
+        assert tally.counts["ensemble.build"] == 1
 
 
 def test_run_batch_without_cache_builds_once(grid_with_lp):
     instances, sols = grid_with_lp
-    before = eb.BUILD_COUNT
-    pipeline.get_pipeline("ours").run_batch(instances, lp_solutions=sols)
-    assert eb.BUILD_COUNT - before == 1
+    with collect() as tally:
+        pipeline.get_pipeline("ours").run_batch(instances, lp_solutions=sols)
+    assert tally.counts["ensemble.build"] == 1
 
 
 # ------------------------------------------------------ canonical flow table

@@ -45,6 +45,7 @@ from repro.pipeline.refine import (
     refine_key,
     refine_sequential,
 )
+from repro.trace import collect
 from repro.traffic.instances import random_instance
 
 # Mixed shapes spanning K=1..4, with and without releases.
@@ -111,9 +112,9 @@ class TestExpandMembers:
 
     def test_expand_does_not_rebuild(self):
         batch = eb.build_ensemble_batch(_mixed_instances()[:2])
-        before = eb.BUILD_COUNT
-        batch.expand_members(4)
-        assert eb.BUILD_COUNT == before
+        with collect() as tally:
+            batch.expand_members(4)
+        assert tally.counts.get("ensemble.build", 0) == 0
 
     def test_expanded_pad_tail_masked(self):
         batch = eb.build_ensemble_batch(_mixed_instances()[:3])

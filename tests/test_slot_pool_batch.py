@@ -3,9 +3,9 @@
 The resident pool is the ONE sanctioned exemption from the build-once
 contract: a single `EnsembleBatch` padded to the pool capacity whose
 array *contents* are scatter-updated in place by `update_slots` /
-`free_slots` (counted by `SLOT_SCATTER_COUNT`), with per-slot flow
+`free_slots` (counted as ``slot.scatter``), with per-slot flow
 extents managed inside a fixed-capacity arena that grows geometrically
-(`SLOT_GROW_COUNT` — the epoch compile-cache bucket ladder).
+(``slot.grow`` — the epoch compile-cache bucket ladder).
 
 Contracts under test:
 
@@ -39,6 +39,7 @@ from repro.core.coflow import flows_of, port_stats
 from repro.pipeline import ensemble_batch as eb
 from repro.pipeline.batch_alloc import allocate_batch_arrays
 from repro.pipeline.batch_circuit import schedule_batch_arrays
+from repro.trace import collect
 from repro.traffic.instances import random_instance
 
 RATES = np.array([10.0, 20.0])
@@ -112,18 +113,17 @@ class TestScatterFidelity:
         assert (pool.flow_start[others] == -1).all()
 
     def test_build_counts_once_and_scatters_count(self):
-        before_build = eb.BUILD_COUNT
-        before_scatter = eb.SLOT_SCATTER_COUNT
-        pool = _pool()
-        assert eb.BUILD_COUNT == before_build + 1
-        inst = _inst(seed=2)
-        eb.update_slots(
-            pool, np.array([1, 3, 4]), inst.demands, inst.weights,
-            inst.releases,
-        )
-        eb.free_slots(pool, np.array([3]))
-        assert eb.BUILD_COUNT == before_build + 1  # still ONE build
-        assert eb.SLOT_SCATTER_COUNT == before_scatter + 2
+        with collect() as tally:
+            pool = _pool()
+            assert tally.counts["ensemble.build"] == 1
+            inst = _inst(seed=2)
+            eb.update_slots(
+                pool, np.array([1, 3, 4]), inst.demands, inst.weights,
+                inst.releases,
+            )
+            eb.free_slots(pool, np.array([3]))
+        assert tally.counts["ensemble.build"] == 1  # still ONE build
+        assert tally.counts["slot.scatter"] == 2
 
     def test_pool_validation(self):
         with pytest.raises(ValueError):
@@ -221,18 +221,18 @@ class TestArenaLifecycle:
             pool, np.array([2]), inst.demands, inst.weights, inst.releases
         )
         start, cap = int(pool.flow_start[2]), int(pool.flow_cap[2])
-        grow_before = eb.SLOT_GROW_COUNT
         # Drop half the flows (a preemption residual) and rescatter.
         resid = inst.demands.copy()
         i_idx, j_idx, _ = flows_of(resid[0], largest_first=True)
         resid[0, i_idx[::2], j_idx[::2]] = 0.0
-        eb.update_slots(
-            pool, np.array([2]), resid, inst.weights, inst.releases
-        )
+        with collect() as tally:
+            eb.update_slots(
+                pool, np.array([2]), resid, inst.weights, inst.releases
+            )
         b = pool.batch
         assert int(pool.flow_start[2]) == start  # same extent
         assert int(pool.flow_cap[2]) == cap
-        assert eb.SLOT_GROW_COUNT == grow_before
+        assert tally.counts.get("slot.grow", 0) == 0
         F = int(b.flow_counts[0, 2])
         assert not b.flow_valid[0, start + F:start + cap].any()
         assert not b.flow_size[0, start + F:start + cap].any()
@@ -242,16 +242,16 @@ class TestArenaLifecycle:
         # quantum 4 but instances carry ~N^2 flows each: the arena must
         # grow, and each growth at least doubles capacity.
         pool = _pool(flow_quantum=4)
-        grow_before = eb.SLOT_GROW_COUNT
         caps = [pool.flow_capacity]
         insts = [_inst(M=1, N=5, seed=10 + s) for s in range(4)]
-        for s, inst in enumerate(insts):
-            eb.update_slots(
-                pool, np.array([s]), inst.demands, inst.weights,
-                inst.releases,
-            )
-            caps.append(pool.flow_capacity)
-        assert eb.SLOT_GROW_COUNT > grow_before
+        with collect() as tally:
+            for s, inst in enumerate(insts):
+                eb.update_slots(
+                    pool, np.array([s]), inst.demands, inst.weights,
+                    inst.releases,
+                )
+                caps.append(pool.flow_capacity)
+        assert tally.counts["slot.grow"] > 0
         for a, b in zip(caps, caps[1:]):
             assert b == a or b >= 2 * a  # geometric ladder
             assert b % 4 == 0  # quantized
@@ -272,15 +272,15 @@ class TestArenaLifecycle:
             )
         cap0 = pool.flow_capacity
         eb.free_slots(pool, np.array([0]))
-        grow_before = eb.SLOT_GROW_COUNT
-        eb.update_slots(
-            pool, np.array([2]), c.demands, c.weights, c.releases
-        )
+        with collect() as tally:
+            eb.update_slots(
+                pool, np.array([2]), c.demands, c.weights, c.releases
+            )
         free_total = cap0 - int(
             pool.flow_cap[pool.flow_start >= 0].sum()
         )
         if free_total >= 0 and pool.flow_capacity == cap0:
-            assert eb.SLOT_GROW_COUNT == grow_before
+            assert tally.counts.get("slot.grow", 0) == 0
         # Surviving tenants intact either way.
         assert np.array_equal(_slot_demand(pool, 1, 4), b_.demands[0])
         assert np.array_equal(_slot_demand(pool, 2, 4), c.demands[0])

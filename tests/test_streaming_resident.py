@@ -26,7 +26,6 @@ import pytest
 from repro.core import lp
 from repro.experiments import stream
 from repro.pipeline import batch_alloc, get_pipeline
-from repro.pipeline import ensemble_batch as eb
 from repro.traffic import poisson_arrivals, with_releases
 from repro.traffic.instances import random_instance
 
@@ -126,7 +125,6 @@ def test_resident_stream_does_not_retrace_after_warmup():
 
 def test_resident_stream_builds_exactly_one_batch():
     inst = _trace(9, 4, 3, seed=11)
-    builds, scatters = eb.BUILD_COUNT, eb.SLOT_SCATTER_COUNT
     res = stream(
         inst, lp_method="batch", lp_iters=200, n_batches=3,
         validate=False, epoch_mode="resident",
@@ -134,8 +132,8 @@ def test_resident_stream_builds_exactly_one_batch():
     assert res.num_resolves >= 2
     # Build-once: ONE EnsembleBatch for the whole stream, all epoch
     # state flowing through counted in-place slot scatters.
-    assert eb.BUILD_COUNT == builds + 1
-    assert eb.SLOT_SCATTER_COUNT > scatters
+    assert res.counts["ensemble.build"] == 1
+    assert res.counts["slot.scatter"] > 0
 
 
 def test_epoch_mode_validation():
