@@ -13,15 +13,19 @@ Here the identical recurrence advances a whole ensemble at once:
 (`repro.pipeline.ensemble_batch.EnsembleBatch`) plus a padded (Bp, Mp)
 order array, realizes the ordered flow sequence as one stable gather of
 the batch's canonical flow table (no re-extraction from instances), and
-advances every instance's (rho, tau, lb) state with one `jax.lax.scan`
-over the flow axis, the per-flow core selection vmapped across the
-ensemble axis.  When the batch carries a `NamedSharding` (built with
+advances every instance's (rho, tau, lb) state with one loop over the
+flow axis, the per-flow core selection vmapped across the ensemble axis.
+The loop's trip count is a runtime scalar, the longest member's valid
+flow count, so a padded flow axis (the streaming slot pool's arena is
+about half empty) costs no steps past it and no recompile when the count
+moves.  When the batch carries a `NamedSharding` (built with
 ``mesh=...``), the scan's inputs are placed with it and the program runs
 SPMD across the member axis.  The padding mirrors the masking scheme of
 `lp_terms_batch` / `solve_subgradient_batch`:
 
   * padded flow steps carry ``valid=False`` and update nothing (masked
-    adds of 0.0 keep the carried f64 state bit-identical);
+    adds of 0.0 keep the carried f64 state bit-identical); those past
+    the longest member's valid flows are not stepped at all;
   * padded cores start at a large finite lower bound (`PAD_LB`) and get a
     large inverse rate, so the argmin never selects them (finite, not inf,
     to keep ``0 * inf`` NaNs out of the candidate terms);
@@ -59,7 +63,7 @@ from repro.pipeline.ensemble_batch import (
     build_ensemble_batch,
 )
 from repro.pipeline.exact64 import NEG_INF, ONE, add, from_bits, mul, to_bits
-from repro.trace import span, to_host
+from repro.trace import count, span, to_host
 
 __all__ = ["allocate_batch", "allocate_batch_arrays", "flow_sequence"]
 
@@ -104,49 +108,82 @@ def flow_sequence(
     )
 
 
+#: Core recorded for every flow with ``valid=False``: no core.
+NO_CORE = -1
+
+
 @jax.jit
-def _scan_all(pi, pj, d, valid, inv_rates, delta, lb0, core_mask, rho0, tau0):
+def _scan_all(
+    steps, pi, pj, d, valid, inv_rates, delta, lb0, core_mask, rho0, tau0
+):
     """Run the allocation recurrence for the whole padded ensemble.
 
-    Shapes: pi/pj (B, F) int32 flat-port endpoints, d (B, F) sizes,
-    valid (B, F) bool, inv_rates/lb0/core_mask (B, Kmax), delta (B,),
-    rho0/tau0 (B, Kmax, Pmax).  Every real-valued input and output is the
-    int64 bit pattern of a non-negative double (`repro.pipeline.exact64`).
-    Returns per-step core choices and real-core lb maxima plus the final
-    (rho, tau) port stats.
+    Shapes: steps () int32, pi/pj (B, F) int32 flat-port endpoints,
+    d (B, F) sizes, valid (B, F) bool, inv_rates/lb0/core_mask (B, Kmax),
+    delta (B,), rho0/tau0 (B, Kmax, Pmax).  Every real-valued input and
+    output is the int64 bit pattern of a non-negative double
+    (`repro.pipeline.exact64`).  Returns per-step core choices and
+    real-core lb maxima plus the final (rho, tau) port stats.
+
+    ``valid`` must be a prefix of every row (`EnsembleBatch.permute_flows`
+    sorts invalid flows last), and ``steps`` at least the longest prefix:
+    the loop runs ``steps`` flow steps, not F, so the flow arena's empty
+    tail costs nothing.  ``steps`` is one traced scalar for the whole
+    ensemble, so the loop stays unbatched under the member vmap; members
+    with fewer valid flows step their invalid tail as masked no-ops.
+    Past ``steps`` the lb maxima hold each member's final one (what a
+    no-op step returns), and every invalid flow's core is `NO_CORE`.
     """
 
-    def member(pi, pj, d, valid, inv_rates, delta, lb0, core_mask, rho0, tau0):
+    def member(rho, tau, lb, i, j, dd, v, inv_rates, delta, core_mask):
         def bump(x, k, p, by):  # x[k, p] += by, rounded as NumPy rounds
             return x.at[k, p].set(add(x[k, p], by))
 
-        def step(carry, x):
-            rho, tau, lb = carry
-            i, j, dd, v = x
-            # Candidate LB on every core if this flow lands there — the
-            # NumPy oracle's expression and rounding, step for step.
-            li = add(mul(add(rho[:, i], dd), inv_rates),
-                     mul(add(tau[:, i], ONE), delta))
-            lj = add(mul(add(rho[:, j], dd), inv_rates),
-                     mul(add(tau[:, j], ONE), delta))
-            cand = jnp.maximum(lb, jnp.maximum(li, lj))
-            k = jnp.argmin(cand)
-            dv = jnp.where(v, dd, 0)
-            ov = jnp.where(v, ONE, 0)
-            rho = bump(bump(rho, k, i, dv), k, j, dv)
-            tau = bump(bump(tau, k, i, ov), k, j, ov)
-            lb = lb.at[k].set(jnp.where(v, cand[k], lb[k]))
-            lb_real = jnp.max(jnp.where(core_mask, lb, NEG_INF))
-            return (rho, tau, lb), (k, lb_real)
+        # Candidate LB on every core if this flow lands there — the
+        # NumPy oracle's expression and rounding, step for step.
+        li = add(mul(add(rho[:, i], dd), inv_rates),
+                 mul(add(tau[:, i], ONE), delta))
+        lj = add(mul(add(rho[:, j], dd), inv_rates),
+                 mul(add(tau[:, j], ONE), delta))
+        cand = jnp.maximum(lb, jnp.maximum(li, lj))
+        k = jnp.argmin(cand)
+        dv = jnp.where(v, dd, 0)
+        ov = jnp.where(v, ONE, 0)
+        rho = bump(bump(rho, k, i, dv), k, j, dv)
+        tau = bump(bump(tau, k, i, ov), k, j, ov)
+        lb = lb.at[k].set(jnp.where(v, cand[k], lb[k]))
+        lb_real = jnp.max(jnp.where(core_mask, lb, NEG_INF))
+        return rho, tau, lb, k.astype(jnp.int32), lb_real
 
-        (rho, tau, _), (ks, lbs) = jax.lax.scan(
-            step, (rho0, tau0, lb0), (pi, pj, d, valid)
+    place_all = jax.vmap(member)
+    # Flow-major, as `lax.scan` lays out its per-step inputs and outputs.
+    xs = (pi.T, pj.T, d.T, valid.T)
+    B, F = valid.shape
+
+    def step(t, carry):
+        rho, tau, lb, ks, lbs = carry
+        # An unsigned ``t``, as `lax.scan` counts: no negative-index select.
+        i, j, dd, v = (jax.lax.dynamic_index_in_dim(x, t, 0, False)
+                       for x in xs)
+        rho, tau, lb, k, lb_real = place_all(
+            rho, tau, lb, i, j, dd, v, inv_rates, delta, core_mask
         )
-        return ks, lbs, rho, tau
+        ks = jax.lax.dynamic_update_index_in_dim(ks, k, t, 0)
+        lbs = jax.lax.dynamic_update_index_in_dim(lbs, lb_real, t, 0)
+        return rho, tau, lb, ks, lbs
 
-    return jax.vmap(member)(
-        pi, pj, d, valid, inv_rates, delta, lb0, core_mask, rho0, tau0
+    init = (
+        rho0, tau0, lb0,
+        jnp.full((F, B), NO_CORE, jnp.int32), jnp.zeros((F, B), lb0.dtype),
     )
+    rho, tau, lb, ks, lbs = jax.lax.fori_loop(
+        jnp.uint32(0), steps.astype(jnp.uint32), step, init
+    )
+    lb_last = jnp.max(jnp.where(core_mask, lb, NEG_INF), axis=1)
+    ran = (jnp.arange(F) < steps)[:, None]
+    lbs = jnp.where(ran, lbs, lb_last[None, :])
+    ks = jnp.where(valid.T, ks, NO_CORE)
+    return ks.T, lbs.T, rho, tau
 
 
 def allocate_batch_arrays(
@@ -181,6 +218,11 @@ def allocate_batch_arrays(
             ensemble.delta if include_tau else np.zeros_like(ensemble.delta)
         )
         lb0 = np.where(ensemble.core_mask, 0.0, PAD_LB)
+        # Valid flows lead every row, so the longest row is all the
+        # scan has to step; the padded length is what a full scan steps.
+        steps = int(valid.sum(axis=1).max(initial=0))
+        count("alloc.steps", steps)
+        count("alloc.step_slots", Fp)
 
     if Fp == 0:
         # Nothing to place anywhere in the ensemble: zero prefix stats.
@@ -194,6 +236,7 @@ def allocate_batch_arrays(
                 zeros_kp = np.zeros((Bp, Kp, Pp), dtype=np.int64)
                 put = lambda x: place(x, ensemble.sharding)  # noqa: E731
                 args = (
+                    np.int32(steps),
                     put(pi.astype(np.int32)), put(pj.astype(np.int32)),
                     put(to_bits(size)), put(valid),
                     put(to_bits(ensemble.inv_rates)), put(to_bits(delta)),

@@ -106,6 +106,7 @@ def test_allocation_scan_compiles_at_trace_width(one_chip):
         s = lambda shape, dt: _spec(one_chip, shape, dt)  # noqa: E731
         i64 = jnp.int64  # reals are int64 double patterns
         compiled = _scan_all.lower(
+            s((), jnp.int32),  # trip count: a runtime scalar, not static
             s((B, F), jnp.int32), s((B, F), jnp.int32),  # endpoints
             s((B, F), i64), s((B, F), jnp.bool_),  # sizes, valid
             s((B, Kp), i64), s((B,), i64),  # inv_rates, delta
