@@ -519,6 +519,14 @@ def pack_lp_arrays(
 _MIN_MEMBERS_PER_DEVICE = 2
 
 
+def padded_members(num_members: int, num_devices: int = 1) -> int:
+    """Members the batched solve runs for ``num_members`` real ones over
+    ``num_devices`` devices: at least `_MIN_MEMBERS_PER_DEVICE` a device,
+    rounded up to the device count (see `solve_subgradient_batch_arrays`)."""
+    least = max(num_members, _MIN_MEMBERS_PER_DEVICE * num_devices)
+    return -(-least // num_devices) * num_devices
+
+
 def _pad_members(x, Bp: int):
     """Pad the leading (member) axis of a host or device array with zeros."""
     pads = [(0, Bp - x.shape[0])] + [(0, 0)] * (x.ndim - 1)
@@ -565,7 +573,7 @@ def solve_subgradient_batch_arrays(
     # device.  Fully-masked members keep every device's block at two or
     # more, so results are bit-identical across batch sizes and meshes.
     n_dev = 1 if sharding is None else data_axis_size(sharding.mesh)
-    Bp = -(-max(B, _MIN_MEMBERS_PER_DEVICE * n_dev) // n_dev) * n_dev
+    Bp = padded_members(B, n_dev)
     if Bp > B:
         ins = [_pad_members(x, Bp) for x in ins]
     ins = [place(x, sharding) for x in ins]

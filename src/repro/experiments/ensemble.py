@@ -28,6 +28,8 @@ from typing import Sequence
 
 from repro.core import lp
 from repro.core.coflow import CoflowInstance
+from repro.experiments.results import device_gather
+from repro.trace import count, span
 
 __all__ = [
     "COLLAPSED",
@@ -122,6 +124,12 @@ def solve_ensemble_lp(
     With ``mesh`` the padded member axis of every bucket is sharded over
     the mesh's ``data`` axis (`NamedSharding`); bucket sizes that do not
     divide the device count round up with fully-masked members.
+
+    Each bucket's host packing, device solve (until its batch is host
+    arrays) and unpacking are the `repro.trace` spans ``lp.pack``,
+    ``lp.wait`` and ``lp.unpack``; the counters ``lp.buckets``,
+    ``lp.members`` (real members) and ``lp.member_slots`` (the members
+    the batched solve runs, padding included) count what was solved.
     """
     instances = list(instances)
     solutions: list[lp.LPSolution | None] = [None] * len(instances)
@@ -134,22 +142,25 @@ def solve_ensemble_lp(
         n_shards = data_axis_size(mesh)
     for bucket in build_buckets(instances, m_quantum, p_quantum):
         members = [instances[i] for i in bucket.indices]
-        arrays = lp.pack_lp_arrays(
-            members,
-            pad_coflows=bucket.num_coflows,
-            pad_ports=bucket.num_flat_ports,
-            pad_members=_round_up(len(members), n_shards),
-        )
-        batch = lp.solve_subgradient_batch_arrays(
-            arrays, iters=iters, sharding=sharding
-        )
-        if sharding is not None:
-            # Cross-device aggregation: assemble the sharded batch on
-            # host before unpadding to solutions.
-            from repro.experiments.results import device_gather
-
+        count("lp.buckets")
+        count("lp.members", len(members))
+        count("lp.member_slots", lp.padded_members(len(members), n_shards))
+        with span("lp.pack"):
+            arrays = lp.pack_lp_arrays(
+                members,
+                pad_coflows=bucket.num_coflows,
+                pad_ports=bucket.num_flat_ports,
+                pad_members=_round_up(len(members), n_shards),
+            )
+        with span("lp.wait"):
+            batch = lp.solve_subgradient_batch_arrays(
+                arrays, iters=iters, sharding=sharding
+            )
+            # The batch as host arrays (assembled from every device's
+            # shard under a mesh): the solve has finished here.
             batch = device_gather(batch)
-        sols = batch.unpack([inst.num_coflows for inst in members])
+        with span("lp.unpack"):
+            sols = batch.unpack([inst.num_coflows for inst in members])
         for i, sol in zip(bucket.indices, sols):
             solutions[i] = sol
     return solutions  # type: ignore[return-value]

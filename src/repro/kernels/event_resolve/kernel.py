@@ -49,7 +49,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels.common import LANE, SUBLANE, pad_to
+from repro.kernels.common import LANE, SUBLANE, pad_to, round_up
 
 # Pad value for claim matrices: larger than any real flow id or the F
 # sentinel (ids stay < 2**24), exactly representable in f32.
@@ -145,6 +145,12 @@ def event_resolve_pallas(
         name="event_resolve",
     )(src_p, dst_p, rel_p, mask_p, fin_p, fout_p, t[:, None].astype(jnp.float32))
     return start[:, :F, 0]
+
+
+def pair_block_cells(num_ports: int) -> int:
+    """Cells of the block `pair_resolve_pallas` reduces for one member of
+    ``num_ports`` x ``num_ports`` pairs: sublane- and lane-padded."""
+    return round_up(num_ports, SUBLANE) * round_up(num_ports, LANE)
 
 
 def _pair_resolve_kernel(claim_ref, idle_ref, start_ref):

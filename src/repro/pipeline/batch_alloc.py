@@ -223,6 +223,9 @@ def allocate_batch_arrays(
         steps = int(valid.sum(axis=1).max(initial=0))
         count("alloc.steps", steps)
         count("alloc.step_slots", Fp)
+        # Members step in lockstep: the member-steps that carried a flow.
+        count("alloc.members", Bp)
+        count("alloc.member_steps", int(valid.sum()))
 
     if Fp == 0:
         # Nothing to place anywhere in the ensemble: zero prefix stats.

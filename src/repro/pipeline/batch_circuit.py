@@ -959,8 +959,9 @@ def _execute_members(
     ignores it.  Counts the calendar's ``calendar.rounds`` (lockstep
     rounds; the longest device's when sharded), ``calendar.members``,
     ``calendar.member_rounds`` (summed over the real members) and
-    ``calendar.member_slots`` (members times lockstep rounds) in the
-    current `repro.trace` tally.
+    ``calendar.member_slots`` (members times lockstep rounds), and for the
+    kernel engine ``calendar.pair_cells`` and ``calendar.pair_slots``
+    (`_count_pairs`), in the current `repro.trace` tally.
     """
     with span("calendar.pack"):
         g_multiple = (
@@ -1009,6 +1010,8 @@ def _execute_members(
                         f"({label})"
                     )
             _count_rounds(rounds, member_rounds, len(tabs))
+            if engine == "kernel":
+                _count_pairs(len(tabs), num_ports_max, pad)
     return est, comp
 
 
@@ -1019,6 +1022,21 @@ def _count_rounds(rounds, member_rounds: np.ndarray, members: int) -> None:
     count("calendar.members", members)
     count("calendar.member_rounds", int(member_rounds[:members].sum()))
     count("calendar.member_slots", members * rounds)
+
+
+def _count_pairs(members: int, num_ports: int, pad: dict) -> None:
+    """The pair-space counters of one kernel-engine program:
+    ``calendar.pair_cells``, the members' real (ingress, egress) pairs
+    (``num_ports`` squared each), and ``calendar.pair_slots``, the cells
+    the round's `pair_resolve` reduces for them (the Pallas kernel's
+    sublane- and lane-padded block, or the oracle's (Nmax, Nmax))."""
+    from repro.kernels.event_resolve.kernel import pair_block_cells
+
+    Nmax = pad["Nmax"]
+    use_kernel, _ = _pair_kernel_mode(pad["Fmax"])
+    slots = pair_block_cells(Nmax) if use_kernel else Nmax * Nmax
+    count("calendar.pair_cells", members * num_ports * num_ports)
+    count("calendar.pair_slots", members * slots)
 
 
 def lower_calendar(

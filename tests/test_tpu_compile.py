@@ -10,7 +10,11 @@ refuses fails here, without a chip:
     x64 (the program ``engine="auto"`` runs on TPU);
   * the `pair_resolve` kernel alone;
   * the allocation scan, with its exact integer double arithmetic;
-  * the batched subgradient LP.
+  * the batched subgradient LP;
+
+and each again at the shapes of the paper's Sec. V-A ensemble (64
+instances of 100 coflows on 10 ports, K=3, swept together): the calendar
+over 192 member-cores, the scan over 64 members, the LP's one bucket.
 
 Nothing runs, so these say nothing about results or times.  The topology
 is described inside a module fixture (never at import), since only one
@@ -62,16 +66,29 @@ def _spec(sharding, shape, dtype):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
 
-# (members, flows per member): an ensemble bucket, and the whole trace's
-# K=2 bucket (two members of ~133k flows, not padded to the quantum).
-@pytest.mark.parametrize("G,F", [(8, 4096), (2, 133_200)])
-def test_kernel_calendar_compiles_with_native_pallas_round(one_chip, G, F):
+# Sec. V-A ensemble: 64 members of 100 coflows on 10 ports (rounded to 12
+# by the calendar's port quantum), K=3 cores, 566-922 flows a member (922
+# the scan's padded length), at most 331 on one core (336 under the flow
+# quantum); the LP bucket pads coflows 100 -> 104 and flat ports 20 -> 24.
+_VA_MEMBERS, _VA_CORES, _VA_N = 64, 3, 12
+_VA_CORE_FLOWS, _VA_FLOWS, _VA_MP, _VA_PP = 336, 922, 104, 24
+
+
+# (members, flows per member, ports): an ensemble bucket, the whole
+# trace's K=2 bucket (two members of ~133k flows, not padded to the
+# quantum), and the Sec. V-A ensemble's 192 member-cores.
+@pytest.mark.parametrize("G,F,N", [
+    pytest.param(8, 4096, _N, id="8-4096"),
+    pytest.param(2, 133_200, _N, id="2-133200"),
+    pytest.param(_VA_MEMBERS * _VA_CORES, _VA_CORE_FLOWS, _VA_N,
+                 id="sec_va-192-336"),
+])
+def test_kernel_calendar_compiles_with_native_pallas_round(one_chip, G, F, N):
     from repro.pipeline.batch_circuit import (
         _run_calendar_pairs_donated,
         event_bound,
     )
 
-    N = _N
     P = N * N
     with jax.enable_x64():
         s = lambda shape, dt: _spec(one_chip, shape, dt)  # noqa: E731
@@ -90,18 +107,23 @@ def test_kernel_calendar_compiles_with_native_pallas_round(one_chip, G, F):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-def test_pair_resolve_kernel_compiles(one_chip):
+@pytest.mark.parametrize("G,N", [
+    (8, _N), (_VA_MEMBERS * _VA_CORES, _VA_N),
+])
+def test_pair_resolve_kernel_compiles(one_chip, G, N):
     from repro.kernels.event_resolve.kernel import pair_resolve_pallas
 
-    spec = _spec(one_chip, (8, _N, _N), jnp.float32)
+    spec = _spec(one_chip, (G, N, N), jnp.float32)
     compiled = pair_resolve_pallas.lower(spec, spec).compile()
     assert "tpu_custom_call" in compiled.as_text()
 
 
-def test_allocation_scan_compiles_at_trace_width(one_chip):
+@pytest.mark.parametrize("B,F,Kp,Pp", [
+    (1, _FLOWS, 8, _PP), (_VA_MEMBERS, _VA_FLOWS, _VA_CORES, 20),
+])
+def test_allocation_scan_compiles_at_trace_width(one_chip, B, F, Kp, Pp):
     from repro.pipeline.batch_alloc import _scan_all
 
-    B, F, Kp, Pp = 1, _FLOWS, 8, _PP
     with jax.enable_x64():
         s = lambda shape, dt: _spec(one_chip, shape, dt)  # noqa: E731
         i64 = jnp.int64  # reals are int64 double patterns
@@ -116,15 +138,17 @@ def test_allocation_scan_compiles_at_trace_width(one_chip):
     assert compiled.as_text()
 
 
-def test_subgradient_lp_compiles_at_trace_width(one_chip):
+@pytest.mark.parametrize("B,Mp,Pp,iters", [
+    (2, _MP, _PP, 1200), (_VA_MEMBERS, _VA_MP, _VA_PP, 3000),
+])
+def test_subgradient_lp_compiles_at_trace_width(one_chip, B, Mp, Pp, iters):
     from repro.core.lp import _subgradient_run_batch
 
-    B, Mp, Pp = 2, _MP, _PP
     s = lambda shape, dt=jnp.float32: _spec(one_chip, shape, dt)  # noqa: E731
     compiled = _subgradient_run_batch.lower(
         s((B, Mp, Mp)), s((B, Mp, Pp)), s((B, Mp, Pp)),  # Y0, rho, tau
         s((B, Mp)), s((B, Mp)), s((B,)), s((B,)),  # w, releases, 1/R, delta/K
         s((B, Mp), jnp.bool_), s((B, Pp), jnp.bool_),  # masks
-        iters=1200,
+        iters=iters,
     ).compile()
     assert compiled.memory_analysis() is not None
