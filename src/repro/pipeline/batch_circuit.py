@@ -18,7 +18,9 @@ executors behind `schedule_batch`:
     head-pointer layout; the per-round reduction is the
     `repro.kernels.event_resolve.pair_resolve` Pallas kernel, compiled
     natively on TPU (the jnp pair oracle on other backends, warned once).
-    A round scans O(N^2) active pairs instead of O(F) flows.
+    Its state is a pair-major flow slab (G, Dp, P = Nmax^2), Dp the
+    most flows on one pair, so a round is dense passes over G·Dp·P
+    cells and never a gather or scatter by index.
   * ``"jax"`` — the vmapped per-member `lax.while_loop` in flow space
     (`_run_calendar`), kept as the segment-min reference program;
   * ``"wide"`` — the lockstep NumPy pair engine (`_run_calendar_wide`),
@@ -132,6 +134,9 @@ _UNSCHEDULED = int(np.float64(NOT_SCHEDULED).view(np.int64))
 _F_QUANTUM = 16
 _N_QUANTUM = 4
 _G_QUANTUM = 8
+#: The kernel engine's flow slab is a power of two deep, at least this
+#: (one sublane tile of 32-bit values on a TPU).
+_D_FLOOR = 8
 
 
 def event_bound(num_flows: int) -> int:
@@ -213,26 +218,64 @@ def _port_segments(keys: np.ndarray, n_pad: int):
 
 
 def _pair_segments(keys: np.ndarray, num_pairs: int):
-    """Pair-sorted layout of one bucket for the kernel engine.
+    """Pair-sorted layout of one bucket for the kernel engine's slab.
 
     ``keys`` (G, Fmax) holds each flow's pair ``src * Nmax + dst``
     (``num_pairs`` for padded flows, which sort last).  Returns ``perm``
     (G, Fmax) i32 — the stable sort by pair, so a pair's flows keep their
-    priority order — and ``first`` / ``last`` (G, num_pairs) i32, each
-    pair's first and last sorted position (``last`` -1 for an empty pair).
+    priority order — and, per sorted position, ``pair`` (G, Fmax) its
+    pair and ``depth`` (G, Fmax) its place among its pair's flows: the
+    slab cell ``(g, depth, pair)`` of `_run_calendar_pairs_impl`
+    (``depth`` -1 for a padded flow).
     """
     G, F = keys.shape
     perm = np.argsort(keys, axis=1, kind="stable").astype(np.int32)
-    sorted_keys = np.take_along_axis(keys, perm, axis=1)
-    pairs = np.arange(num_pairs)
-    first = np.empty((G, num_pairs), dtype=np.int32)
-    last = np.empty((G, num_pairs), dtype=np.int32)
-    for g in range(G):
-        left = np.searchsorted(sorted_keys[g], pairs, side="left")
-        right = np.searchsorted(sorted_keys[g], pairs, side="right")
-        first[g] = np.minimum(left, F - 1)
-        last[g] = np.where(right > left, right - 1, -1)
-    return perm, first, last
+    pair = np.take_along_axis(keys, perm, axis=1)
+    pos = np.arange(F)
+    new = np.ones((G, F), dtype=bool)
+    new[:, 1:] = pair[:, 1:] != pair[:, :-1]
+    first = np.maximum.accumulate(np.where(new, pos, 0), axis=1)
+    depth = np.where(pair < num_pairs, pos - first, -1)
+    return perm, pair, depth
+
+
+def _slab_depth(depth: np.ndarray) -> int:
+    """Slab depth Dp of a bucket: its most flows on one pair of one
+    member, rounded up to a power of two, at least `_D_FLOOR`."""
+    need = int(depth.max(initial=0)) + 1
+    return max(_D_FLOOR, 1 << (need - 1).bit_length())
+
+
+def _pair_slab(rel, dur, pending, keys, num_pairs):
+    """The kernel engine's inputs from a padded bucket.
+
+    ``rel`` / ``dur`` (G, Fmax) i64 patterns, ``pending`` and the pair
+    ``keys`` of `_pair_segments`, in flow order.  Returns ``perm`` (the
+    pair-sorted order of the outputs), the (G, Dp, P) slabs of rel, dur,
+    pending and priority ids (flow ``perm[g, i]`` at cell ``(g,
+    depth[g, i], pair[g, i])``; empty cells not pending) and ``slot``
+    (G, Fmax) i32, each sorted position's flat cell ``depth * P + pair``
+    (-1 for a padded flow).
+    """
+    perm, pair, depth = _pair_segments(keys, num_pairs)
+    G, F = perm.shape
+    P = num_pairs
+    D = _slab_depth(depth)
+    valid = depth >= 0
+    slot = np.where(valid, depth * P + pair, -1).astype(np.int32)
+    rows = np.broadcast_to(np.arange(G)[:, None], (G, F))[valid]
+    cells = slot[valid]
+
+    def slab(a, fill):  # ``a`` in pair-sorted order
+        out = np.full((G, D * P), fill, dtype=a.dtype)
+        out[rows, cells] = a[valid]
+        return out.reshape(G, D, P)
+
+    take = lambda a: np.take_along_axis(a, perm, axis=1)  # noqa: E731
+    return (
+        perm, slab(take(rel), 0), slab(take(dur), 0),
+        slab(take(pending), False), slab(perm, F), slot,
+    )
 
 
 def _unsort(a: np.ndarray, perm: np.ndarray) -> np.ndarray:
@@ -349,7 +392,7 @@ def _run_calendar(
 
 
 def _run_calendar_pairs_impl(
-    rel, dur, pending0, free0, ids, pfirst, plast,
+    rel, dur, pending0, free0, ids, slot,
     reserving, bound, use_kernel, interpret=False,
 ):
     """The ``engine="kernel"`` executor: one lockstep pair-space calendar.
@@ -363,22 +406,24 @@ def _run_calendar_pairs_impl(
     Nmax^2) pair state instead of a vmap of per-member loops over (Fmax,)
     flow state.
 
-    Flows arrive in pair-sorted order (grouped by pair, priority order
-    within a pair; ``ids`` holds each position's priority id), so a round
-    touches flow space only through elementwise passes, scans, gathers of
-    P values and one scatter of P values — never a gather of Fmax values,
-    which costs ~8 ns per element on a TPU v5e:
+    The round state is a pair-major flow slab (G, Dp, P): flow ``d`` of
+    pair ``p`` in priority order sits at cell ``(g, d, p)``, pairs on the
+    lanes, and a cell without a flow is never pending.  Every pass of a
+    round is dense — elementwise ops and reductions over the depth axis,
+    no gather or scatter by index, which on a TPU v5e costs 4-10 ns per
+    index against a fraction of a nanosecond per dense cell:
 
-      * heads are stateless: each pair's first waiting position is a
-        suffix `cummin` of waiting positions read at the pair's first
-        position (no head-rewind bookkeeping at release crossings);
+      * heads are stateless: each pair's head depth is the least waiting
+        depth (no head-rewind bookkeeping at release crossings), and its
+        priority id and duration are read through the one-hot of that
+        depth;
       * the round reduction — idle & row-first & col-first over the
         (G, N, N) claim matrix — is the `repro.kernels.event_resolve.
         pair_resolve` Pallas kernel when ``use_kernel`` (compiled for the
         TPU, or run by the Pallas interpreter with ``interpret``), else
         its jnp oracle; both reduce exact integer ids;
-      * started heads are scattered back to flow space; completions are
-        ``establish + dur`` after the loop;
+      * started heads are written through the same one-hot; completions
+        are ``establish + dur`` after the loop;
       * the next event time is the least of (a) the post-round free time
         of each pair that still has a waiting flow, where later than t,
         and (b) the least pending release later than t.  That is the
@@ -390,11 +435,12 @@ def _run_calendar_pairs_impl(
     Every time comparison stays in exact selections on double patterns,
     so CCTs remain bit-identical to `schedule_core`.
 
-    Shapes: rel/dur (G, Fmax) i64 double patterns and pending0 (G, Fmax)
-    bool, all pair-sorted; free0 (G, Nmax) i64 zeros; ids (G, Fmax) i32;
-    pfirst/plast (G, P) i32 — each pair's first and last sorted position
-    (``plast`` -1 for a pair without flows).  Returns (establish,
-    complete) (G, Fmax) i64 patterns in pair-sorted order, the per-member
+    Shapes: rel/dur (G, Dp, P) i64 double patterns, pending0 (G, Dp, P)
+    bool and ids (G, Dp, P) i32 (each flow's priority id), the slab of
+    `_pair_slab`; free0 (G, Nmax) i64 zeros; slot (G, Fmax) i32 — the
+    slab cell ``d * P + p`` of each pair-sorted flow position (-1 for a
+    padded flow).  Returns (establish, complete) (G, Fmax) i64 patterns
+    in pair-sorted order (one gather of ``slot`` a call), the per-member
     ``unfinished`` / ``stalled`` flags of `_run_calendar`, the loop's
     round count and each member's (G,) i32 rounds: the rounds in which it
     still had a pending flow (its own calendar's length; the lockstep
@@ -402,10 +448,10 @@ def _run_calendar_pairs_impl(
     """
     from repro.kernels.event_resolve import pair_resolve
 
-    G, F = rel.shape
+    G, D, P = rel.shape
     N = free0.shape[1]
-    P = N * N
-    ar = jnp.arange(F, dtype=jnp.int32)
+    F = slot.shape[1]
+    depth = jnp.arange(D, dtype=jnp.int32)[None, :, None]
 
     def ports(free_in, free_out):
         # Each pair's ingress and egress free times, (G, P) each: pair
@@ -415,55 +461,34 @@ def _run_calendar_pairs_impl(
             jnp.broadcast_to(free_out[:, None, :], (G, N, N)).reshape(G, P),
         )
 
-    # One gather of P rows reads a head's priority id, its duration (the
-    # int64 pattern as two 32-bit halves) and the next waiting position
-    # after it: on a TPU a gather costs per index, not per byte.
-    head_rows = jnp.stack(
-        [ids, dur.astype(jnp.int32), (dur >> 32).astype(jnp.int32)], axis=-1
-    )
-
-    def mark(pos):
-        # (G, F) mask of the positions in ``pos`` (G, P); F is dropped.
-        return jax.vmap(
-            lambda p: jnp.zeros((F,), bool).at[p].set(True, mode="drop")
-        )(pos)
-
     def cond(carry):
         _, _, _, pending, _, it, stalled, _ = carry
-        return jnp.any(pending & ~stalled[:, None]) & (it < bound)
+        return jnp.any(pending & ~stalled[:, None, None]) & (it < bound)
 
     def body(carry):
         free_in, free_out, est, pending, t, it, stalled, rounds = carry
         t_ = t[:, None]
-        waiting = pending & (rel <= t_) & ~stalled[:, None]
-        # Pair heads: the first waiting position at or after each pair's
-        # first position, if it is still inside the pair.
-        nxt = jax.lax.cummin(jnp.where(waiting, ar, F), axis=1, reverse=True)
-        head = jnp.take_along_axis(nxt, pfirst, 1)
-        head = jnp.where(head <= plast, head, F)
-        headc = jnp.minimum(head, F - 1)
-        has = head < F
-        # The waiting position after each position rides in the same gather.
-        after = jnp.concatenate([nxt[:, 1:], jnp.full((G, 1), F, nxt.dtype)], 1)
-        rows = jax.vmap(lambda r, h: r[h])(
-            jnp.concatenate([head_rows, after[..., None]], axis=-1), headc
-        )
-        dur_p = (rows[..., 2].astype(jnp.int64) << 32) | (
-            rows[..., 1].astype(jnp.int64) & 0xFFFFFFFF
-        )
+        t3 = t[:, None, None]
+        waiting = pending & (rel <= t3) & ~stalled[:, None, None]
+        # Pair heads: the least waiting depth of each pair (D for none).
+        dh = jnp.min(jnp.where(waiting, depth, D), axis=1)
+        has = dh < D
+        head = depth == dh[:, None, :]
+        hid = jnp.min(jnp.where(head, ids, F), axis=1)
+        dur_p = jnp.max(jnp.where(head, dur, 0), axis=1)
         fi, fo = ports(free_in, free_out)
         idle = has & (fi <= t_) & (fo <= t_)
         claim = has if reserving else idle
-        claimf = jnp.where(claim, rows[..., 0], F).astype(jnp.float32)
+        claimf = jnp.where(claim, hid, F).astype(jnp.float32)
         startp = pair_resolve(
             claimf.reshape(G, N, N),
             idle.reshape(G, N, N),
             use_kernel=use_kernel,
             interpret=interpret,
         ).reshape(G, P)
-        sflow = mark(jnp.where(startp, head, F))
-        est = jnp.where(sflow, t_, est)
-        pending = pending & ~sflow
+        started = head & startp[:, None, :]
+        est = jnp.where(started, t3, est)
+        pending = pending & ~started
         # Port frees via (G, N, N) row/column max reductions — at most one
         # pair per row/column starts, so the max picks its completion.
         ev = jnp.where(startp, add(t_, dur_p), NEG_INF).reshape(G, N, N)
@@ -480,14 +505,15 @@ def _run_calendar_pairs_impl(
             more = chained | jnp.any(idle & ~startp, axis=1)
         advance = ~more
         # A pair still waits after the round unless it started its only
-        # waiting flow: the next waiting position past a started head.
-        still = has & (~startp | (rows[..., 3] <= plast))
+        # waiting flow: a waiting flow deeper than a started head.
+        after = jnp.any(waiting & (depth > dh[:, None, :]), axis=1)
+        still = has & (~startp | after)
         pfree = jnp.maximum(*ports(free_in, free_out))
         t_next = jnp.minimum(
             jnp.min(jnp.where(still & (pfree > t_), pfree, INF), axis=1),
-            jnp.min(jnp.where(pending & (rel > t_), rel, INF), axis=1),
+            jnp.min(jnp.where(pending & (rel > t3), rel, INF), axis=(1, 2)),
         )
-        alive = jnp.any(pending, axis=1)
+        alive = jnp.any(pending, axis=(1, 2))
         stall = advance & alive & (t_next == INF) & ~stalled
         t = jnp.where(advance & (t_next < INF) & ~stalled, t_next, t)
         stalled = stalled | stall
@@ -498,21 +524,25 @@ def _run_calendar_pairs_impl(
     init = (
         free0,
         free0,
-        jnp.full((G, F), _UNSCHEDULED, rel.dtype),
+        jnp.full((G, D, P), _UNSCHEDULED, rel.dtype),
         pending0,
-        jnp.min(jnp.where(pending0, rel, INF), axis=1),
+        jnp.min(jnp.where(pending0, rel, INF), axis=(1, 2)),
         jnp.int32(0),
         jnp.zeros((G,), bool),
-        jnp.any(pending0, axis=1).astype(jnp.int32),
+        jnp.any(pending0, axis=(1, 2)).astype(jnp.int32),
     )
     out = jax.lax.while_loop(cond, body, init)
     _, _, est, pending, _, it, stalled, rounds = out
     comp = jnp.where(est != _UNSCHEDULED, add(est, dur), _UNSCHEDULED)
-    unfinished = jnp.any(pending, axis=1)
+    # Back to pair-sorted flow order: one gather of both times per flow.
+    times = jnp.stack([est, comp], axis=-1).reshape(G, D * P, 2)
+    flows = jax.vmap(lambda x, i: x[i])(times, jnp.maximum(slot, 0))
+    flows = jnp.where((slot >= 0)[..., None], flows, _UNSCHEDULED)
+    unfinished = jnp.any(pending, axis=(1, 2))
     # A member still live when the bound cut the loop was counted for a
     # round that never ran.
     rounds = rounds - (unfinished & ~stalled).astype(jnp.int32)
-    return est, comp, unfinished, stalled, it, rounds
+    return flows[..., 0], flows[..., 1], unfinished, stalled, it, rounds
 
 
 _PAIR_STATICS = ("reserving", "bound", "use_kernel", "interpret")
@@ -915,18 +945,18 @@ def _calendar_program(pad: dict, discipline: str, engine: str, sharding=None):
         return _run_calendar, args, dict(
             reserving=reserving, bound=event_bound(Fmax)
         ), None
-    # engine == "kernel": flows in pair-sorted order over P = Nmax^2 pairs;
-    # ``perm`` maps the outputs back.
+    # engine == "kernel": a (G, Dp, P = Nmax^2) slab of flows by pair and
+    # depth; the outputs come back in pair-sorted order, which ``perm``
+    # maps back.
     P = Nmax * Nmax
     pairkey = np.where(
         pad["pending"], src.astype(np.int64) * Nmax + dst, P
     )
-    perm, pfirst, plast = _pair_segments(pairkey, P)
-    take = lambda a: np.take_along_axis(a, perm, axis=1)  # noqa: E731
-    use_kernel, interpret = _pair_kernel_mode(Fmax)
-    args = (
-        take(rel), take(dur), take(pad["pending"]), free0, perm, pfirst, plast,
+    perm, rel, dur, pending, ids, slot = _pair_slab(
+        rel, dur, pad["pending"], pairkey, P
     )
+    use_kernel, interpret = _pair_kernel_mode(Fmax)
+    args = (rel, dur, pending, free0, ids, slot)
     statics = dict(
         reserving=reserving, bound=event_bound(Fmax), use_kernel=use_kernel,
         interpret=interpret,
@@ -960,8 +990,9 @@ def _execute_members(
     rounds; the longest device's when sharded), ``calendar.members``,
     ``calendar.member_rounds`` (summed over the real members) and
     ``calendar.member_slots`` (members times lockstep rounds), and for the
-    kernel engine ``calendar.pair_cells`` and ``calendar.pair_slots``
-    (`_count_pairs`), in the current `repro.trace` tally.
+    kernel engine ``calendar.pair_cells``, ``calendar.pair_slots``,
+    ``calendar.slab_cells`` and ``calendar.slab_depth`` (`_count_pairs`),
+    in the current `repro.trace` tally.
     """
     with span("calendar.pack"):
         g_multiple = (
@@ -989,6 +1020,7 @@ def _execute_members(
                     pad, discipline, engine, sharding
                 )
                 args = [place(a, sharding) for a in args]
+                slab_depth = args[0].shape[1] if engine == "kernel" else 0
             with span("calendar.wait"):
                 est, comp, unfinished, stalled, rounds, member_rounds = (
                     to_host(*fn(*args, **statics))
@@ -1011,7 +1043,7 @@ def _execute_members(
                     )
             _count_rounds(rounds, member_rounds, len(tabs))
             if engine == "kernel":
-                _count_pairs(len(tabs), num_ports_max, pad)
+                _count_pairs(len(tabs), num_ports_max, pad, slab_depth)
     return est, comp
 
 
@@ -1024,12 +1056,18 @@ def _count_rounds(rounds, member_rounds: np.ndarray, members: int) -> None:
     count("calendar.member_slots", members * rounds)
 
 
-def _count_pairs(members: int, num_ports: int, pad: dict) -> None:
+def _count_pairs(
+    members: int, num_ports: int, pad: dict, slab_depth: int
+) -> None:
     """The pair-space counters of one kernel-engine program:
     ``calendar.pair_cells``, the members' real (ingress, egress) pairs
-    (``num_ports`` squared each), and ``calendar.pair_slots``, the cells
-    the round's `pair_resolve` reduces for them (the Pallas kernel's
-    sublane- and lane-padded block, or the oracle's (Nmax, Nmax))."""
+    (``num_ports`` squared each); ``calendar.pair_slots``, the cells the
+    round's `pair_resolve` reduces for them (the Pallas kernel's
+    sublane- and lane-padded block, or the oracle's (Nmax, Nmax));
+    ``calendar.slab_cells``, the flow-slab cells every round touches for
+    them (``slab_depth`` x Nmax^2 each, against the bucket's real flows
+    the slab's padding), and ``calendar.slab_depth``, the slab's depth
+    Dp."""
     from repro.kernels.event_resolve.kernel import pair_block_cells
 
     Nmax = pad["Nmax"]
@@ -1037,6 +1075,8 @@ def _count_pairs(members: int, num_ports: int, pad: dict) -> None:
     slots = pair_block_cells(Nmax) if use_kernel else Nmax * Nmax
     count("calendar.pair_cells", members * num_ports * num_ports)
     count("calendar.pair_slots", members * slots)
+    count("calendar.slab_cells", members * slab_depth * Nmax * Nmax)
+    count("calendar.slab_depth", slab_depth)
 
 
 def lower_calendar(

@@ -91,6 +91,140 @@ def test_fuzz_mixed_shapes_and_releases(discipline, seed, engine):
     _batch_vs_loop(instances, discipline, engine)
 
 
+def _table(pairs, rel, dur):
+    """A member table of flows on ``pairs`` ((src, dst) each), in
+    priority order, with per-flow releases and durations."""
+    src, dst = np.asarray(pairs, dtype=np.int64).T
+    return dict(
+        src=src, dst=dst, rel=np.asarray(rel, np.float64),
+        dur=np.asarray(dur, np.float64),
+    )
+
+
+def _slab_release_inversion():
+    # Pair (0, 1): the first-priority flow is released last, so the head
+    # is deeper than the pair's first flow until t = 9; pair (2, 1) shares
+    # the egress port and contends with both.
+    return [
+        _table([(0, 1), (0, 1), (0, 1), (2, 1), (0, 3)],
+               [9.0, 0.0, 4.0, 1.0, 0.0], [2.0, 5.0, 3.0, 4.0, 6.0]),
+        _table([(1, 0), (1, 0), (2, 2)], [7.0, 0.0, 0.0], [1.0, 1.5, 2.5]),
+    ], 4, 8
+
+
+def _slab_pair_run(n):
+    def make():
+        # ``n`` flows on pair (1, 2), with releases out of priority order,
+        # and a second member with the same pair lighter.
+        rel = np.arange(n, dtype=np.float64)[::-1] % 3
+        return [
+            _table([(1, 2)] * n + [(0, 2), (1, 0)], np.r_[rel, 0.0, 2.0],
+                   np.r_[np.arange(1, n + 1) * 0.5, 3.0, 1.0]),
+            _table([(1, 2)] * 2, [0.0, 0.0], [1.0, 1.0]),
+        ], 3, 8 if n <= 8 else 16
+    return make
+
+
+def _slab_zero_duration_chain():
+    # A chain of zero-duration flows on one pair starts at one instant,
+    # round after round, and the pair's next real flow starts with them.
+    return [
+        _table([(0, 1)] * 5 + [(0, 2), (3, 1)],
+               [0.0, 0.0, 2.0, 2.0, 2.0, 0.0, 2.0],
+               [0.0, 0.0, 0.0, 0.0, 4.0, 0.0, 1.0]),
+    ], 4, 8
+
+
+SLAB_CASES = {
+    "release_inversion": _slab_release_inversion,
+    "fills_depth": _slab_pair_run(8),
+    "depth_plus_one": _slab_pair_run(9),
+    "zero_duration_chain": _slab_zero_duration_chain,
+}
+
+
+def _slab_vs_oracles(tabs, num_ports, discipline):
+    """Run member tables through the kernel engine's slab calendar and the
+    wide engine, check both against `schedule_core` bit for bit, and
+    return the slab depth the kernel engine used."""
+    from repro.core.circuit import schedule_core
+    from repro.pipeline import batch_circuit as bc
+    from repro.trace import collect
+
+    labels = [f"member {g}" for g in range(len(tabs))]
+    with collect() as tally:
+        kernel = bc._execute_members(
+            tabs, num_ports, discipline, "kernel", labels
+        )
+    wide = bc._execute_members(tabs, num_ports, discipline, "wide", labels)
+    for g, tab in enumerate(tabs):
+        F = tab["src"].shape[0]
+        # Each flow its own coflow, released at its own time; rate 1 and
+        # no delta, so the oracle's durations are ``dur`` exactly.
+        ref = schedule_core(
+            np.arange(F), tab["src"], tab["dst"], tab["dur"], np.arange(F),
+            tab["rel"], num_ports, 1.0, 0.0, discipline=discipline,
+        )
+        for name, (est, comp) in (("kernel", kernel), ("wide", wide)):
+            assert np.array_equal(est[g, :F], ref.establish), (name, g)
+            assert np.array_equal(comp[g, :F], ref.complete), (name, g)
+    return tally.counts["calendar.slab_depth"]
+
+
+@pytest.mark.parametrize("discipline", DISCIPLINES)
+@pytest.mark.parametrize("case", sorted(SLAB_CASES))
+def test_kernel_slab_edge_cases(discipline, case):
+    """The kernel engine's pair-major slab: heads deeper than a pair's
+    first flow, a pair that fills the slab's depth and one that takes the
+    next power of two, and zero-duration chains on one pair."""
+    tabs, num_ports, depth = SLAB_CASES[case]()
+    assert _slab_vs_oracles(tabs, num_ports, discipline) == depth
+
+
+@pytest.mark.parametrize("discipline", DISCIPLINES)
+def test_kernel_slab_busy_phantoms(discipline):
+    """In-flight phantom rows (``busy``) at the head of a member's table
+    ride in the slab like any flow: the kernel engine matches the wide
+    engine through `schedule_batch_arrays`, and `schedule_core` on the
+    member tables with the phantoms in."""
+    from repro.pipeline import batch_circuit as bc
+    from repro.pipeline import get_pipeline
+    from repro.pipeline.ensemble_batch import build_ensemble_batch
+
+    inst = random_instance(
+        num_coflows=6, num_ports=4, num_cores=2, density=0.6,
+        release_span=0.0, seed=21,
+    )
+    ensemble = build_ensemble_batch([inst], with_lp_arrays=False)
+    pipe = get_pipeline("wspt_order")
+    orders = pipe.order_stage.order_batch(ensemble)
+    alloc = pipe.allocate_stage.allocate_batch_arrays(ensemble, orders)
+    busy = {
+        (0, k): dict(
+            src=np.array([0, 2]), dst=np.array([1, 3]),
+            rel=np.zeros(2), dur=np.array([3.0 + k, 0.5]),
+        )
+        for k in range(2)
+    }
+    got = bc.schedule_batch_arrays(
+        ensemble, alloc, discipline, engine="kernel", busy=busy
+    )
+    want = bc.schedule_batch_arrays(
+        ensemble, alloc, discipline, engine="wide", busy=busy
+    )
+    (scheds, ccts), = got
+    (ref_scheds, ref_ccts), = want
+    assert np.array_equal(ccts, ref_ccts)
+    _assert_schedules_identical(scheds, ref_scheds, "busy")
+    tabs = []
+    for k in range(2):
+        idx = np.nonzero(alloc.valid[0] & (alloc.core[0] == k))[0]
+        if idx.size:
+            tabs.append(bc._member_table(ensemble, alloc, 0, k, idx, busy))
+    assert tabs
+    _slab_vs_oracles(tabs, inst.num_ports, discipline)
+
+
 @pytest.mark.parametrize("engine", ["wide", "jax", "kernel"])
 @pytest.mark.parametrize("discipline", DISCIPLINES)
 def test_single_flow_and_empty_cores(discipline, engine):

@@ -74,16 +74,19 @@ _VA_MEMBERS, _VA_CORES, _VA_N = 64, 3, 12
 _VA_CORE_FLOWS, _VA_FLOWS, _VA_MP, _VA_PP = 336, 922, 104, 24
 
 
-# (members, flows per member, ports): an ensemble bucket, the whole
-# trace's K=2 bucket (two members of ~133k flows, not padded to the
-# quantum), and the Sec. V-A ensemble's 192 member-cores.
-@pytest.mark.parametrize("G,F,N", [
-    pytest.param(8, 4096, _N, id="8-4096"),
-    pytest.param(2, 133_200, _N, id="2-133200"),
-    pytest.param(_VA_MEMBERS * _VA_CORES, _VA_CORE_FLOWS, _VA_N,
+# (members, flows per member, ports, slab depth): an ensemble bucket, the
+# whole trace's K=2 bucket (two members of ~133k flows, not padded to the
+# quantum; 28 flows on its hottest pair, so a slab 32 deep), and the
+# Sec. V-A ensemble's 192 member-cores (at most 17 flows on one pair).
+@pytest.mark.parametrize("G,F,N,D", [
+    pytest.param(8, 4096, _N, 8, id="8-4096"),
+    pytest.param(2, 133_200, _N, 32, id="2-133200"),
+    pytest.param(_VA_MEMBERS * _VA_CORES, _VA_CORE_FLOWS, _VA_N, 32,
                  id="sec_va-192-336"),
 ])
-def test_kernel_calendar_compiles_with_native_pallas_round(one_chip, G, F, N):
+def test_kernel_calendar_compiles_with_native_pallas_round(
+    one_chip, G, F, N, D
+):
     from repro.pipeline.batch_circuit import (
         _run_calendar_pairs_donated,
         event_bound,
@@ -94,17 +97,39 @@ def test_kernel_calendar_compiles_with_native_pallas_round(one_chip, G, F, N):
         s = lambda shape, dt: _spec(one_chip, shape, dt)  # noqa: E731
         i32, i64 = jnp.int32, jnp.int64  # times are int64 double patterns
         args = (
-            s((G, F), i64), s((G, F), i64),  # rel, dur (pair-sorted)
-            s((G, F), jnp.bool_),  # pending0
+            s((G, D, P), i64), s((G, D, P), i64),  # rel, dur slabs
+            s((G, D, P), jnp.bool_),  # pending0
             s((G, N), i64),  # free0
-            s((G, F), i32),  # priority ids
-            s((G, P), i32), s((G, P), i32),  # pair segment first / last
+            s((G, D, P), i32),  # priority ids
+            s((G, F), i32),  # slab cell of each pair-sorted flow
         )
         compiled = _run_calendar_pairs_donated.lower(
             *args, reserving=False, bound=event_bound(F), use_kernel=True,
             interpret=False,
         ).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_slab_counters_on_a_small_bucket():
+    """``calendar.slab_cells`` is members x Dp x P and
+    ``calendar.slab_depth`` Dp: 9 flows on one pair take Dp 16."""
+    import numpy as np
+
+    from repro.pipeline import batch_circuit as bc
+    from repro.trace import collect
+
+    n = 9
+    tabs = [
+        dict(src=np.zeros(n, np.int64), dst=np.ones(n, np.int64),
+             rel=np.zeros(n), dur=np.ones(n)),
+        dict(src=np.arange(3), dst=np.arange(3), rel=np.zeros(3),
+             dur=np.ones(3)),
+    ]
+    with collect() as tally:
+        bc._execute_members(tabs, 3, "greedy", "kernel", ["a", "b"])
+    Nmax = bc._round_up(3, bc._N_QUANTUM)
+    assert tally.counts["calendar.slab_depth"] == 16
+    assert tally.counts["calendar.slab_cells"] == 2 * 16 * Nmax * Nmax
 
 
 @pytest.mark.parametrize("G,N", [
