@@ -136,10 +136,9 @@ def probe_calendar(
         t for t in bc.member_tables(inst, alloc, order) if t["coflow"].shape[0]
     ]
     pad = bc._pad_members(tabs, inst.num_ports)
-    engine = "kernel"  # what "auto" runs on a TPU
-    fn, args, statics, _ = bc._calendar_program(pad, "greedy", engine)
+    fn, args, statics, _ = bc._calendar_program(pad, "greedy")
     print(
-        f"[calendar] engine {engine}, G={pad['G']} Fmax={pad['Fmax']} "
+        f"[calendar] device calendar, G={pad['G']} Fmax={pad['Fmax']} "
         f"Nmax={pad['Nmax']}, flows per member "
         f"{[t['coflow'].shape[0] for t in tabs]}",
         flush=True,

@@ -15,9 +15,11 @@ single-device run, asserts bit-identical rows, and merges
 ``sharded_sweep_speedup_x`` into the same artifact (CI forces
 ``XLA_FLAGS=--xla_force_host_platform_device_count=8`` for it).
 
-``--engines`` times the three circuit-calendar executors (wide / jax /
-kernel) on one shared ensemble with bit-parity asserted, reports each
-XLA engine's roofline distance (`repro.launch.perf.measured_roofline`),
+``--engines`` times the two circuit-calendar executors (the host
+calendar ``wide`` and the device calendar ``kernel``, each forced
+whatever the platform) on one shared ensemble with bit-parity asserted,
+reports the device calendar's roofline distance
+(`repro.launch.perf.measured_roofline`),
 and with ``--trajectory`` appends a timestamped snapshot to the
 repo-tracked ``BENCH_micro.json``.  ``--streaming-smoke`` drives the
 online streaming service on a small Poisson-arrival trace: single-batch
@@ -209,17 +211,17 @@ def bench_pipeline_batch(
     }
 
 
-def bench_circuit_engines(quick=False, ensemble_size=24, lp_iters=200):
+def bench_calendar_executors(quick=False, ensemble_size=24, lp_iters=200):
     """Per-engine circuit-calendar timings on one shared ensemble.
 
     Runs the same (instances, allocs, orders) through `schedule_batch`
-    under every engine — ``"wide"`` (lockstep NumPy pair calendar),
-    ``"jax"`` (vmapped flow-space while_loop) and ``"kernel"`` (lockstep
-    pair-space calendar with the Pallas round reduction) — asserting all
-    three produce bit-identical establishment times and CCTs, and times
-    each cold (first call in this function) and warm.
+    under both executors, whatever the platform would pick — ``"wide"``
+    (the host calendar, lockstep NumPy) and ``"kernel"`` (the device
+    calendar, lockstep pair space with the Pallas round reduction) —
+    asserting both produce bit-identical establishment times and CCTs,
+    and times each cold (first call in this function) and warm.
 
-    For the two XLA engines the compiled calendar is also pushed through
+    For the device calendar the compiled program is also pushed through
     `lower_calendar` -> `repro.launch.hlo_cost` -> roofline to report how
     far the measured warm time sits from the cost model's hardware bound
     (``*_roofline_frac``; the measured time includes host packing, so
@@ -230,6 +232,7 @@ def bench_circuit_engines(quick=False, ensemble_size=24, lp_iters=200):
     from repro.experiments import solve_ensemble_lp
     from repro.launch.perf import measured_roofline
     from repro.launch.roofline import PEAKS
+    from repro.pipeline import batch_circuit as bc
     from repro.pipeline.batch_circuit import (
         lower_calendar,
         member_tables,
@@ -263,44 +266,47 @@ def bench_circuit_engines(quick=False, ensemble_size=24, lp_iters=200):
         "jax_version": jax.__version__,
     }
     results = {}
-    for engine in ("wide", "jax", "kernel"):
-        t0 = time.perf_counter()
-        pairs = schedule_batch(ens, allocs, orders, discipline, engine=engine)
-        t_cold = time.perf_counter() - t0
-        t0 = time.perf_counter()
-        pairs = schedule_batch(ens, allocs, orders, discipline, engine=engine)
-        t_warm = time.perf_counter() - t0
+    platform_engine = bc._engine
+    for engine in ("wide", "kernel"):
+        bc._engine = lambda e=engine: e
+        try:
+            t0 = time.perf_counter()
+            pairs = schedule_batch(ens, allocs, orders, discipline)
+            t_cold = time.perf_counter() - t0
+            t0 = time.perf_counter()
+            pairs = schedule_batch(ens, allocs, orders, discipline)
+            t_warm = time.perf_counter() - t0
+        finally:
+            bc._engine = platform_engine
         results[engine] = pairs
         stats[f"circuit_{engine}_cold_ensemble{B}_s"] = t_cold
         stats[f"circuit_{engine}_warm_ensemble{B}_s"] = t_warm
 
-    ref = results["wide"]
-    for engine in ("jax", "kernel"):
-        for (scheds, ccts), (rscheds, rccts) in zip(results[engine], ref):
-            if not np.array_equal(ccts, rccts):
-                raise AssertionError(f"engine {engine!r} CCTs != wide oracle")
-            for s, r in zip(scheds, rscheds):
-                if not (
-                    np.array_equal(s.establish, r.establish)
-                    and np.array_equal(s.complete, r.complete)
-                ):
-                    raise AssertionError(
-                        f"engine {engine!r} schedules != wide oracle"
-                    )
-    base = stats[f"circuit_wide_warm_ensemble{B}_s"]
-    for engine in ("jax", "kernel"):
-        stats[f"circuit_{engine}_vs_wide_warm_x"] = (
-            base / stats[f"circuit_{engine}_warm_ensemble{B}_s"]
-        )
+    for (scheds, ccts), (rscheds, rccts) in zip(
+        results["kernel"], results["wide"]
+    ):
+        if not np.array_equal(ccts, rccts):
+            raise AssertionError("engine 'kernel' CCTs != wide oracle")
+        for s, r in zip(scheds, rscheds):
+            if not (
+                np.array_equal(s.establish, r.establish)
+                and np.array_equal(s.complete, r.complete)
+            ):
+                raise AssertionError(
+                    "engine 'kernel' schedules != wide oracle"
+                )
+    stats["circuit_kernel_vs_wide_warm_x"] = (
+        stats[f"circuit_wide_warm_ensemble{B}_s"]
+        / stats[f"circuit_kernel_warm_ensemble{B}_s"]
+    )
 
-    # Roofline distance of the two XLA calendars (the "wide" engine is
-    # host NumPy: no HLO exists for it, by design).  Only a device with
+    # Roofline distance of the device calendar (the host calendar is
+    # NumPy: no HLO exists for it, by design).  Only a device with
     # published peaks has one: anywhere else it is "not measured".
     kind = stats["device_kind"]
     if kind not in PEAKS:
-        for engine in ("jax", "kernel"):
-            for key in ("bound_s", "frac", "dominant"):
-                stats[f"circuit_{engine}_roofline_{key}"] = "not measured"
+        for key in ("bound_s", "frac", "dominant"):
+            stats[f"circuit_kernel_roofline_{key}"] = "not measured"
         return stats
     tabs = [
         tab
@@ -309,18 +315,13 @@ def bench_circuit_engines(quick=False, ensemble_size=24, lp_iters=200):
         if tab["coflow"].shape[0]
     ]
     nmax = max(inst.num_ports for inst in ens)
-    for engine in ("jax", "kernel"):
-        hlo = (
-            lower_calendar(tabs, nmax, discipline, engine=engine)
-            .compile()
-            .as_text()
-        )
-        terms = measured_roofline(
-            hlo, stats[f"circuit_{engine}_warm_ensemble{B}_s"], kind
-        )
-        stats[f"circuit_{engine}_roofline_bound_s"] = terms["bound_s"]
-        stats[f"circuit_{engine}_roofline_frac"] = terms["roofline_frac"]
-        stats[f"circuit_{engine}_roofline_dominant"] = terms["dominant"]
+    hlo = lower_calendar(tabs, nmax, discipline).compile().as_text()
+    terms = measured_roofline(
+        hlo, stats[f"circuit_kernel_warm_ensemble{B}_s"], kind
+    )
+    stats["circuit_kernel_roofline_bound_s"] = terms["bound_s"]
+    stats["circuit_kernel_roofline_frac"] = terms["roofline_frac"]
+    stats["circuit_kernel_roofline_dominant"] = terms["dominant"]
     return stats
 
 
@@ -501,9 +502,9 @@ def run(quick=False):
     stats.pop("B")
     rows.extend(stats.items())
 
-    # Per-engine circuit calendars (wide / jax / kernel) with roofline
-    # distance for the XLA engines.
-    estats = bench_circuit_engines(quick=quick)
+    # Both circuit calendars (wide / kernel) with roofline distance for
+    # the device calendar.
+    estats = bench_calendar_executors(quick=quick)
     rows.extend(
         (k, v) for k, v in estats.items() if isinstance(v, (int, float))
     )
@@ -576,14 +577,14 @@ def batch_smoke(quick=False):
 
 
 def engines_smoke(quick=False, trajectory=False):
-    """CI smoke: all three circuit engines, bit-parity asserted.
+    """CI smoke: both circuit calendars, bit-parity asserted.
 
     Prints each engine's cold/warm timings plus the roofline fractions,
     merges them into ``results/benchmarks/micro.json`` (the per-run CI
     artifact) and — with ``trajectory=True`` — appends a timestamped
     entry to the repo-tracked ``BENCH_micro.json``.
     """
-    stats = {"bench": "engines", **bench_circuit_engines(quick=quick)}
+    stats = {"bench": "engines", **bench_calendar_executors(quick=quick)}
     for name, val in stats.items():
         if isinstance(val, float):
             print(f"micro,{name},{val:.6g}")
@@ -1084,7 +1085,7 @@ if __name__ == "__main__":
     ap.add_argument(
         "--engines",
         action="store_true",
-        help="run only the per-engine circuit-calendar case (wide/jax/"
+        help="run only the per-engine circuit-calendar case (wide/"
         "kernel timed on one ensemble, bit-parity asserted, roofline "
         "fractions merged into micro.json)",
     )

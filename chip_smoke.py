@@ -31,6 +31,8 @@ The script needs a TPU and fails without one; ``--rehearse`` runs the
 same phases on whatever backend JAX has, at small sizes, to check the
 control flow (``JAX_PLATFORMS=cpu``; add
 ``XLA_FLAGS=--xla_force_host_platform_device_count=4`` for ``--chips 4``).
+On a CPU the calendar is the host one, so a rehearsal checks no calendar
+layout over devices.
 The last line of the output is one JSON object naming the device.
 """
 
@@ -133,13 +135,12 @@ def _offline(size, on_tpu):
         flush=True,
     )
     discipline = "greedy"
-    engine = bc._check_engine(discipline, "auto")
+    engine = bc._engine()
     if on_tpu and engine != "kernel":
-        raise RuntimeError(f'circuit_engine="auto" resolved to {engine!r}')
+        raise RuntimeError(f"the calendar on a TPU resolved to {engine!r}")
     res = sweep(
         [inst], schemes=("ours",), lp_method="batch",
-        lp_iters=cfg["lp_iters"], discipline=discipline,
-        circuit_engine="auto", validate=True,
+        lp_iters=cfg["lp_iters"], discipline=discipline, validate=True,
     )
     rec = res.records[0]
     ours = rec.results["ours"]
@@ -170,7 +171,7 @@ def _offline(size, on_tpu):
     ]
     if engine == "kernel":
         text = bc.lower_calendar(
-            tabs, inst.num_ports, discipline, engine
+            tabs, inst.num_ports, discipline
         ).compile().as_text()
         native = "tpu_custom_call" in text and "pair_resolve" in text
         if on_tpu and not native:
@@ -237,8 +238,7 @@ def _jit_entries():
     fns = {
         "lp": lp._subgradient_run_batch,
         "scan": batch_alloc._scan_all,
-        "calendar": (bc._run_calendar_pairs_donated, bc._run_calendar_pairs,
-                     bc._run_calendar),
+        "calendar": (bc._run_calendar_pairs_donated, bc._run_calendar_pairs),
     }
     return {
         k: sum(f._cache_size() for f in (v if isinstance(v, tuple) else (v,)))
@@ -355,6 +355,7 @@ def _sharded(size):
 
     from repro.experiments import sweep
     from repro.launch.mesh import make_local_mesh
+    from repro.pipeline import batch_circuit as bc
 
     ens = [
         _cell(m, n, k, seed=s) for s, (m, n, k) in enumerate(size["sharded"])
@@ -362,12 +363,15 @@ def _sharded(size):
     metas = [{"cell": i} for i in range(len(ens))]
     mesh = make_local_mesh()
     n_dev = len(jax.devices())
-    # The kernel calendar is what "auto" runs on a TPU (checked by the
-    # one-chip run); naming it keeps the sharded calendar in a CPU
-    # rehearsal too.
+    # The platform picks the calendar: the sharded device calendar on a
+    # TPU (checked by the one-chip run), the host `wide` calendar in a
+    # `--rehearse` run on the CPU, which then has no calendar layout.
+    stages = ["LP", "scan"]
+    if bc._engine() == "kernel":
+        stages.append("calendar")
     kw = dict(
         schemes=("ours", "wspt_order"), lp_iters=size["sharded_lp_iters"],
-        metas=metas, circuit_engine="kernel",
+        metas=metas,
     )
     single = sweep(ens, **kw)
     log = []
@@ -375,7 +379,7 @@ def _sharded(size):
         sharded = sweep(ens, mesh=mesh, **kw)
 
     # Each stage's members must be split over every device, equally.
-    for stage in ("LP", "scan", "calendar"):
+    for stage in stages:
         layouts = [rows for name, rows in log if name == stage]
         if not layouts:
             raise RuntimeError(f"the sharded sweep ran no {stage}")

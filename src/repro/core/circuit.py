@@ -110,8 +110,9 @@ def pair_heads(
     waiting flow in priority order) can ever claim or start.  Returns the
     (N, N) matrix of head flow indices, with ``F`` as the empty-pair
     sentinel — the claim input of `resolve_event_pairs`, and the state the
-    accelerated calendars (`repro.pipeline.batch_circuit`'s "wide" and
-    "kernel" engines) maintain instead of per-flow claims.
+    batched calendars (`repro.pipeline.batch_circuit`'s device calendar
+    and, on a CPU, its host calendar) maintain instead of per-flow
+    claims.
     """
     F = src.shape[0]
     heads = np.full((num_ports, num_ports), F, dtype=np.int64)
@@ -138,8 +139,9 @@ def resolve_event_pairs(
     per-port minimum over flows equals the minimum over that port's pair
     heads — reduced from O(F) flows to O(N^2) pairs per round.  It is the
     NumPy twin of `repro.kernels.event_resolve.pair_resolve` (the Pallas
-    round reduction of the ``engine="kernel"`` batched calendar); parity
-    of all three is asserted in `tests/test_kernels.py`.
+    round reduction of the device calendar in
+    `repro.pipeline.batch_circuit`); parity of all three is asserted in
+    `tests/test_kernels.py`.
     """
     rowmin = claim.min(axis=1, keepdims=True)
     colmin = claim.min(axis=0, keepdims=True)

@@ -1,7 +1,7 @@
 """Content-addressed result cache for instance sweeps.
 
 The experiment fabric's memory: every sweep *cell* — one (instance,
-scheme) pair under a fixed pipeline/engine configuration — is keyed by a
+scheme) pair under a fixed pipeline configuration — is keyed by a
 canonical SHA-256 over
 
   * the **instance digest** (demand/weight/release/rate array bytes plus
@@ -9,7 +9,7 @@ canonical SHA-256 over
   * the **scheme digest** (the registered `SchemeSpec`, as data — a
     re-registered scheme invalidates its cells),
   * the **config digest** (lp_method, lp_iters, bucket quanta,
-    discipline, alloc/circuit paths, circuit engine, certify), and
+    discipline, alloc/circuit paths, certify), and
   * the **code fingerprint** (repro package version + SHA-256 of every
     result-determining source file), so editing a stage implementation
     invalidates every cached cell without any manual versioning.

@@ -173,7 +173,6 @@ def sweep(
     discipline: str = "greedy",
     alloc: str = "batch",
     circuit: str = "batch",
-    circuit_engine: str = "auto",
     certify: bool = False,
     metas: Sequence[Mapping[str, Any]] | None = None,
     validate: bool = True,
@@ -193,15 +192,14 @@ def sweep(
     the batched path — ``"batch"`` (the `batch_circuit` padded event
     calendar) or ``"loop"`` (the per-instance oracle inside `run_batch`);
     with ``alloc="loop"`` the whole pipeline is already per-instance, so
-    ``circuit`` has no effect there.  ``circuit_engine`` picks the
-    batched calendar's executor (``"kernel"``/``"jax"``/``"wide"``;
-    default ``"auto"``, overridable via ``REPRO_CIRCUIT_ENGINE`` — see
-    `repro.pipeline.batch_circuit`).
+    ``circuit`` has no effect there.  The batched calendar is the device
+    calendar on TPU and GPU and the host calendar on a CPU, chosen by
+    platform (`repro.pipeline.batch_circuit`).
 
     ``mesh`` shards the ensemble axis of every batched stage over the
     mesh's ``data`` axis (`jax.sharding.NamedSharding` via
     `repro.launch.mesh.data_sharding`): the bucketed LP solves, the
-    allocation scan and the JAX circuit calendar all run SPMD, with
+    allocation scan and the device circuit calendar all run SPMD, with
     member counts padded up to the device count (fully-masked no-op
     members) and results gathered back on host
     (`repro.experiments.results.device_gather`).  Members are
@@ -284,7 +282,6 @@ def sweep(
                 discipline=discipline,
                 alloc=alloc,
                 circuit=circuit,
-                circuit_engine=circuit_engine,
                 certify=certify,
                 # The sweep-level refine override joins every cell key
                 # (None when schemes run their spec-pinned refine, which
@@ -345,8 +342,7 @@ def sweep(
 
     def _run(scheme_key: str, disc: str, idx: list[int]):
         pipe = pipeline_mod.get_pipeline(
-            scheme_key, discipline=disc, circuit_backend=circuit,
-            circuit_engine=circuit_engine,
+            scheme_key, discipline=disc, circuit_backend=circuit
         )
         sub = [instances[i] for i in idx]
         subsols = [sols_by_idx[i] for i in idx]

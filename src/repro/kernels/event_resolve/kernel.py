@@ -25,10 +25,10 @@ Two kernels share this file:
     an oracle-validated building block (each round scans O(F) flows and
     the (F, F) triangle matmul grows quadratically in flows);
   * `pair_resolve_pallas` — the production round reduction of the
-    ``engine="kernel"`` batched calendar
-    (`repro.pipeline.batch_circuit._run_calendar_pairs`): the wide CPU
-    engine's per-(ingress, egress)-pair head-pointer layout, so one round
-    reduces an (N, N) pair matrix instead of F flows.
+    device calendar (`repro.pipeline.batch_circuit._run_calendar_pairs`),
+    chosen on TPU and GPU: the host calendar's per-(ingress, egress)-pair
+    layout, so one round reduces an (N, N) pair matrix instead of F
+    flows.
 
 The pair kernel's f64 story is *separation*, not emulation: CCT
 bit-parity is the repo's correctness contract and every f64 time

@@ -17,8 +17,9 @@ Two formulations, both oracle-checked against `resolve_event`:
     (G, N, N) matrix of claiming head ids: a pair starts iff it is idle
     and its claim is minimal along both its row (first claimer on the
     ingress) and its column (first claimer on the egress).  This is the
-    `engine="kernel"` calendar's per-round reduction
-    (`repro.core.circuit.resolve_event_pairs` is the NumPy twin).
+    per-round reduction of the device calendar
+    (`repro.pipeline.batch_circuit`; `repro.core.circuit.
+    resolve_event_pairs` is the NumPy twin).
 """
 
 from __future__ import annotations

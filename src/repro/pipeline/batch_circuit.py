@@ -8,53 +8,36 @@ port-free time.  After PR 3 batched allocation, this per-(instance, core)
 loop became the dominant post-LP cost of every figure sweep.
 
 Here the identical event calendar executes for the whole flattened
-(ensemble x core) axis at once, through one of three bit-identical
-executors behind `schedule_batch`:
+(ensemble x core) axis at once.  Each member g is one (instance, core)
+pair with its flows padded to a shared length Fmax and its ports to
+Nmax, and every round performs one resolution of `resolve_event` — the
+same first-occurrence start set for both disciplines (reserving claims =
+waiting flows, greedy claims = idle flows) — fused, when no other round
+at this instant is possible, with one clock advance to the next event.
+Flows of one (ingress, egress) pair share both ports and execute
+sequentially, so only each pair's head (first waiting flow) can ever
+claim or start, and a round is a reduction over the (G, Nmax, Nmax)
+pair matrix instead of the flow axis.
 
-  * ``"kernel"`` — the accelerator path: ONE lockstep `lax.while_loop`
-    over the whole (G, ...) batch whose fused round (claim -> start ->
-    clock advance, a single dispatch per round with donated calendar
-    buffers) reduces the wide engine's per-(ingress, egress)-pair
-    head-pointer layout; the per-round reduction is the
+The platform picks one of two executors (`_engine`), and nothing
+overrides that choice:
+
+  * ``"kernel"`` — the device calendar, on TPU and GPU
+    (`_run_calendar_pairs_impl`): ONE lockstep `lax.while_loop` over the
+    whole (G, ...) batch whose fused round (claim -> start -> clock
+    advance) is a single dispatch with donated calendar buffers.  Its
+    state is a pair-major flow slab (G, Dp, P = Nmax^2), Dp the most
+    flows on one pair, so a round is dense passes over G·Dp·P cells and
+    never a gather or scatter by index; the per-round reduction is the
     `repro.kernels.event_resolve.pair_resolve` Pallas kernel, compiled
     natively on TPU (the jnp pair oracle on other backends, warned once).
-    Its state is a pair-major flow slab (G, Dp, P = Nmax^2), Dp the
-    most flows on one pair, so a round is dense passes over G·Dp·P
-    cells and never a gather or scatter by index.
-  * ``"jax"`` — the vmapped per-member `lax.while_loop` in flow space
-    (`_run_calendar`), kept as the segment-min reference program;
-  * ``"wide"`` — the lockstep NumPy pair engine (`_run_calendar_wide`),
-    the CPU path.
+  * ``"wide"`` — the host calendar, on a CPU (`_run_calendar_wide`): the
+    same rounds lockstep in NumPy, with per-pair head pointers.
 
-In the JAX executor,
-each member g is one (instance, core) pair with its flows padded to a
-shared length Fmax and its ports to Nmax; one bounded
-`jax.lax.while_loop` (vmapped across members) carries
-
-  * port free-time vectors ``free_in`` / ``free_out``  (G, Nmax),
-  * per-flow ``establish`` / ``complete`` / ``pending``  (G, Fmax),
-  * the member clock ``t``,
-
-and every iteration performs one resolution round of `resolve_event` —
-the same first-occurrence start set for both disciplines (reserving
-claims = waiting flows, greedy claims = idle flows) — fused, when the
-round is provably complete, with one clock advance to the next event.
-
-Lockstep iterations are the scarce resource (the whole batch steps while
-the largest member finishes its calendar), so the round is engineered
-scatter-free around a few (G, Fmax) passes:
-
-  * the per-port first-claimer reduction is an exclusive segment-min over
-    the flow axis, computed as one integer `cummin` over flows presorted
-    by port (host-side, static per call) with per-segment offsets — no
-    scatter, exact in int32;
-  * port free times update through (G, Nmax) gathers of each port's
-    first claimer (only the first claimer on a port can have started);
-  * the clock advance fuses into the same iteration unless another round
-    at this instant is possible: for reserving that is only a
-    zero-duration start (a started port stays free and its next waiting
-    flow chains at the same t); for greedy any idle-but-blocked leftover
-    (its blocker may have started and freed nothing it needs).
+`repro.core.circuit.schedule_core` is the oracle both are fuzz-checked
+against, bit for bit (`tests/test_batch_circuit.py`).  Tests that must
+run the device calendar on a CPU monkeypatch `_engine` (and
+`_PAIR_KERNEL_INTERPRET` for the Pallas interpreter).
 
 The calendar is bounded: every flow contributes at most a handful of
 rounds and every advance lands on a distinct release or port-free value
@@ -63,9 +46,9 @@ rounds and every advance lands on a distinct release or port-free value
 
 Padding semantics mirror `batch_alloc`:
 
-  * padded flows start with ``pending=False``, sort into a sentinel port
-    segment past every real port, and can never claim, start, or
-    contribute event times;
+  * padded flows start with ``pending=False``, sort into a sentinel pair
+    past every real pair, and can never claim, start, or contribute
+    event times;
   * padded members (bucket rounding) have no pending flows and finish on
     iteration zero;
   * padded ports are never indexed by real flows.
@@ -77,7 +60,7 @@ exact IEEE addition in integer arithmetic (XLA:TPU only emulates f64,
 not to the last bit), with ``dur`` precomputed on the host exactly as
 the oracle's ``delta + size / rate``; so establishment and completion
 times are **bit-identical** to `schedule_core` on both disciplines and
-every backend — fuzz-asserted by `tests/test_batch_circuit.py`.
+every backend.
 
 Shapes are rounded up to small quanta so repeated sweeps, schemes and
 disciplines over similar ensembles reuse one compiled program per padded
@@ -87,7 +70,6 @@ bucket instead of recompiling per call.
 from __future__ import annotations
 
 import functools
-import os
 import warnings
 from typing import Sequence
 
@@ -112,9 +94,6 @@ __all__ = [
     "event_bound",
     "lower_calendar",
 ]
-
-#: Calendar executors selectable via ``engine=`` (plus ``"auto"``).
-_ENGINES = ("jax", "wide", "kernel")
 
 #: Test hook: run the kernel engine's Pallas round in the Pallas
 #: interpreter, so CPU tests execute the program the TPU compiles.
@@ -190,33 +169,6 @@ def member_tables(
     return out
 
 
-def _port_segments(keys: np.ndarray, n_pad: int):
-    """Sort metadata for the exclusive segment-min over one port axis.
-
-    ``keys`` (G, Fmax) holds each flow's port (``n_pad`` for padded flows,
-    a sentinel segment past every real port).  Returns per-member arrays:
-    ``perm`` (G, Fmax) — stable sort of flows by port; ``offs`` (G, Fmax)
-    — per-sorted-position segment offsets ``(n_pad - port) * (Fmax + 1)``,
-    strictly decreasing across segments so a running `cummin` never leaks
-    a value across a boundary; ``segend`` / ``segempty`` (G, n_pad) — the
-    last sorted position of each real port's segment (clamped) and whether
-    the segment is empty.
-    """
-    G, F = keys.shape
-    perm = np.argsort(keys, axis=1, kind="stable").astype(np.int32)
-    sorted_keys = np.take_along_axis(keys, perm, axis=1)
-    offs = ((n_pad - sorted_keys) * (F + 1)).astype(np.int32)
-    ports = np.arange(n_pad)
-    segend = np.empty((G, n_pad), dtype=np.int32)
-    segempty = np.empty((G, n_pad), dtype=bool)
-    for g in range(G):
-        right = np.searchsorted(sorted_keys[g], ports, side="right")
-        left = np.searchsorted(sorted_keys[g], ports, side="left")
-        segempty[g] = left == right
-        segend[g] = np.clip(right - 1, 0, F - 1)
-    return perm, offs, segend, segempty
-
-
 def _pair_segments(keys: np.ndarray, num_pairs: int):
     """Pair-sorted layout of one bucket for the kernel engine's slab.
 
@@ -285,126 +237,16 @@ def _unsort(a: np.ndarray, perm: np.ndarray) -> np.ndarray:
     return out
 
 
-@functools.partial(jax.jit, static_argnames=("reserving", "bound"))
-def _run_calendar(
-    src, dst, rel, dur, pending0, free0,
-    psrc, soff, send, sempty, pdst, doff, dend, dempty,
-    reserving, bound,
-):
-    """Execute the padded event calendar for all members.
-
-    Shapes: src/dst/psrc/pdst (G, Fmax) i32, rel/dur (G, Fmax) i64
-    double patterns, pending0 (G, Fmax) bool, free0 (G, Nmax) i64 zeros,
-    soff/doff (G, Fmax) i32, send/dend (G, Nmax) i32, sempty/dempty
-    (G, Nmax) bool.
-    Returns (establish, complete) (G, Fmax) i64 patterns plus per-member
-    ``unfinished`` / ``stalled`` flags (bound exhausted / no event time
-    could advance the clock — both impossible for well-formed inputs,
-    checked on host), the lockstep round count (the largest member's) and
-    each member's (G,) i32 rounds.
-    """
-    G, F = src.shape
-    n_pad = free0.shape[1]
-    port_off = ((n_pad - jnp.arange(n_pad)) * (F + 1)).astype(jnp.int32)
-
-    def member(src, dst, rel, dur, pending0, free0,
-               psrc, soff, send, sempty, pdst, doff, dend, dempty):
-        ar = jnp.arange(F, dtype=jnp.int32)
-        t0 = jnp.min(jnp.where(pending0, rel, INF))
-
-        def first_claimer(claim, perm, offs, segend, segempty):
-            # Exclusive segment-min of claiming flow indices per port:
-            # int32 cummin over the port-sorted flow axis; descending
-            # per-segment offsets keep segments independent.
-            w = jnp.where(claim[perm], perm, F) + offs
-            cm = jax.lax.cummin(w)
-            first = cm[segend] - port_off
-            return jnp.where(segempty, F, first)
-
-        def cond(carry):
-            _, _, _, _, pending, _, it, stalled = carry
-            return jnp.any(pending) & ~stalled & (it < bound)
-
-        def body(carry):
-            free_in, free_out, est, comp, pending, t, it, stalled = carry
-            waiting = pending & (rel <= t)
-            idle = waiting & (free_in[src] <= t) & (free_out[dst] <= t)
-            claim = waiting if reserving else idle
-            fi = first_claimer(claim, psrc, soff, send, sempty)
-            fj = first_claimer(claim, pdst, doff, dend, dempty)
-            start = idle & (ar == fi[src]) & (ar == fj[dst])
-            est = jnp.where(start, t, est)
-            comp = jnp.where(start, add(t, dur), comp)
-            # Only a port's first claimer can have started; if it did, the
-            # port frees at that flow's completion — two (Nmax,) gathers
-            # instead of a scatter.
-            fic = jnp.clip(fi, 0, F - 1)
-            fjc = jnp.clip(fj, 0, F - 1)
-            free_in = jnp.where(
-                (fi < F) & start[fic], add(t, dur[fic]), free_in
-            )
-            free_out = jnp.where(
-                (fj < F) & start[fjc], add(t, dur[fjc]), free_out
-            )
-            pending = pending & ~start
-            # Advance fuses into this iteration unless another round at t
-            # is possible: a zero-duration start chains its port's next
-            # waiting flow (reserving), and any idle-but-blocked leftover
-            # may start once its blocker is gone (greedy).
-            if reserving:
-                advance = ~jnp.any(start & (dur == 0))
-            else:
-                advance = ~jnp.any(idle & ~start)
-            times = jnp.where(
-                pending,
-                jnp.maximum(
-                    rel, jnp.maximum(free_in[src], free_out[dst])
-                ),
-                INF,
-            )
-            t_next = jnp.min(jnp.where(times > t, times, INF))
-            stall = advance & jnp.any(pending) & (t_next == INF)
-            t = jnp.where(advance, t_next, t)
-            return (
-                free_in, free_out, est, comp, pending, t, it + 1,
-                stalled | stall,
-            )
-
-        init = (
-            free0,
-            free0,
-            jnp.full((F,), _UNSCHEDULED, rel.dtype),
-            jnp.full((F,), _UNSCHEDULED, rel.dtype),
-            pending0,
-            t0,
-            jnp.int32(0),
-            jnp.bool_(False),
-        )
-        out = jax.lax.while_loop(cond, body, init)
-        _, _, est, comp, pending, _, it, stalled = out
-        return est, comp, jnp.any(pending), stalled, it
-
-    est, comp, unfinished, stalled, rounds = jax.vmap(member)(
-        src, dst, rel, dur, pending0, free0,
-        psrc, soff, send, sempty, pdst, doff, dend, dempty,
-    )
-    return est, comp, unfinished, stalled, jnp.max(rounds), rounds
-
-
 def _run_calendar_pairs_impl(
     rel, dur, pending0, free0, ids, slot,
     reserving, bound, use_kernel, interpret=False,
 ):
-    """The ``engine="kernel"`` executor: one lockstep pair-space calendar.
+    """The device calendar: one lockstep pair-space calendar.
 
-    The wide CPU engine's per-(ingress, egress)-pair head-pointer trick,
-    ported to the JAX path: flows of one pair share both ports, execute
-    sequentially, and only each pair's head (first waiting flow) can ever
-    claim or start — so the whole batch advances through ONE
-    `lax.while_loop` whose round body is a single fused dispatch (claim
-    -> `pair_resolve` -> start writes -> clock advance) over (G, P =
-    Nmax^2) pair state instead of a vmap of per-member loops over (Fmax,)
-    flow state.
+    Only each pair's head (first waiting flow) can ever claim or start,
+    so the whole batch advances through ONE `lax.while_loop` whose round
+    body is a single fused dispatch (claim -> `pair_resolve` -> start
+    writes -> clock advance) over (G, P = Nmax^2) pair state.
 
     The round state is a pair-major flow slab (G, Dp, P): flow ``d`` of
     pair ``p`` in priority order sits at cell ``(g, d, p)``, pairs on the
@@ -426,11 +268,10 @@ def _run_calendar_pairs_impl(
         are ``establish + dur`` after the loop;
       * the next event time is the least of (a) the post-round free time
         of each pair that still has a waiting flow, where later than t,
-        and (b) the least pending release later than t.  That is the
-        flow-space calendar's next event (`_run_calendar`), or a release
-        instant before it at which every pending flow's earliest start is
-        still later — a round that starts nothing, as the wide engine's
-        release stops.
+        and (b) the least pending release later than t.  That is
+        `schedule_core`'s next event, or a release instant before it at
+        which every pending flow's earliest start is still later — a
+        round that starts nothing, as the wide engine's release stops.
 
     Every time comparison stays in exact selections on double patterns,
     so CCTs remain bit-identical to `schedule_core`.
@@ -440,11 +281,12 @@ def _run_calendar_pairs_impl(
     `_pair_slab`; free0 (G, Nmax) i64 zeros; slot (G, Fmax) i32 — the
     slab cell ``d * P + p`` of each pair-sorted flow position (-1 for a
     padded flow).  Returns (establish, complete) (G, Fmax) i64 patterns
-    in pair-sorted order (one gather of ``slot`` a call), the per-member
-    ``unfinished`` / ``stalled`` flags of `_run_calendar`, the loop's
-    round count and each member's (G,) i32 rounds: the rounds in which it
-    still had a pending flow (its own calendar's length; the lockstep
-    batch runs the largest member's).
+    in pair-sorted order (one gather of ``slot`` a call), per-member
+    ``unfinished`` / ``stalled`` flags (bound exhausted / no event time
+    could advance the clock — both impossible for well-formed inputs,
+    checked on host), the loop's round count and each member's (G,) i32
+    rounds: the rounds in which it still had a pending flow (its own
+    calendar's length; the lockstep batch runs the largest member's).
     """
     from repro.kernels.event_resolve import pair_resolve
 
@@ -563,7 +405,7 @@ _run_calendar_pairs_donated = jax.jit(
 def _run_calendar_pairs_sharded(
     mesh, reserving, bound, use_kernel, interpret=False
 ):
-    """The kernel-engine calendar of a bucket sharded over ``data``.
+    """The device calendar of a bucket sharded over ``data``.
 
     The compiler cannot partition a Mosaic kernel, and members are
     independent, so every device steps its own members' lockstep loop:
@@ -608,7 +450,7 @@ def _run_calendar_wide(
     so ``bound`` carries one extra F of slack over `event_bound`.
 
     Members drop out of the lockstep batch as they finish.  Identical
-    f64 selections as `_run_calendar` and `schedule_core`: bit-exact.
+    f64 selections as `schedule_core`: bit-exact.
     Returns (establish, complete) (G, F), the lockstep round count and
     each member's (G,) rounds (those it started with a pending flow).
     """
@@ -801,47 +643,23 @@ def _run_calendar_wide(
     return out_est, out_comp, it, member_rounds
 
 
-def _check_engine(discipline: str, engine: str) -> str:
-    """Validate and resolve the calendar executor.
+def _engine() -> str:
+    """The calendar executor of this platform: the device calendar
+    (``"kernel"``) on TPU and GPU, the host calendar (``"wide"``) on a
+    CPU."""
+    return "kernel" if jax.default_backend() in ("tpu", "gpu") else "wide"
 
-    ``"auto"`` resolves from the environment: a ``REPRO_CIRCUIT_ENGINE``
-    variable wins when set (it overrides auto-selection only, never an
-    explicit ``engine=`` argument), otherwise accelerator backends
-    (TPU/GPU) get the kernelized pair calendar and CPU hosts the lockstep
-    NumPy engine.  On an accelerator the variable may not name the host
-    ``"wide"`` engine: a run there stays on the device unless the caller
-    asks for the host engine explicitly.
-    """
+
+def _check_discipline(discipline: str) -> None:
     if discipline not in ("reserving", "greedy"):
         raise ValueError(f"unknown discipline {discipline!r}")
-    if engine not in ("auto",) + _ENGINES:
-        raise ValueError(f"unknown engine {engine!r}")
-    if engine == "auto":
-        backend = jax.default_backend()
-        accelerator = backend in ("tpu", "gpu")
-        env = os.environ.get("REPRO_CIRCUIT_ENGINE", "").strip().lower()
-        if env:
-            if env not in _ENGINES:
-                raise ValueError(
-                    f"unknown engine {env!r} (from REPRO_CIRCUIT_ENGINE; "
-                    f"expected one of {', '.join(_ENGINES)})"
-                )
-            if accelerator and env == "wide":
-                raise ValueError(
-                    "REPRO_CIRCUIT_ENGINE=wide would move the calendar off "
-                    f"the {backend} onto the host; pass engine='wide' to "
-                    "ask for the host engine"
-                )
-            return env
-        engine = "kernel" if accelerator else "wide"
-    return engine
 
 
 def _warn_kernel_fallback() -> None:
-    """Warn (once per process) that engine="kernel" runs its round through
-    the jnp pair oracle because the Pallas kernel has no native backend
-    here — silent oracle fallbacks would invalidate any perf claim made
-    off this engine's timings."""
+    """Warn (once per process) that the device calendar runs its round
+    through the jnp pair oracle because the Pallas kernel has no native
+    backend here — silent oracle fallbacks would invalidate any perf
+    claim made off this executor's timings."""
     global _KERNEL_FALLBACK_WARNED
     if _KERNEL_FALLBACK_WARNED:
         return
@@ -884,9 +702,8 @@ def _pad_members(
 
     ``tabs`` holds one dict per (instance, core) member with F_k > 0
     (keys: src/dst/rel/dur as in `member_tables`).  Padded flows carry
-    ``pending=False`` and the ``Nmax`` sentinel port keys; padding member
-    rows (bucket rounding, plus ``g_multiple`` for shard counts) have no
-    pending flows.
+    ``pending=False``; padding member rows (bucket rounding, plus
+    ``g_multiple`` for shard counts) have no pending flows.
     """
     # Every member row steps through every lockstep round, so small
     # buckets round up to a power of two (one or two members — the whole
@@ -900,8 +717,6 @@ def _pad_members(
     Nmax = _round_up(num_ports_max, _N_QUANTUM)
     src = np.zeros((G, Fmax), dtype=np.int32)
     dst = np.zeros((G, Fmax), dtype=np.int32)
-    skey = np.full((G, Fmax), Nmax, dtype=np.int64)
-    dkey = np.full((G, Fmax), Nmax, dtype=np.int64)
     rel = np.zeros((G, Fmax), dtype=np.float64)
     dur = np.zeros((G, Fmax), dtype=np.float64)
     pending = np.zeros((G, Fmax), dtype=bool)
@@ -909,45 +724,30 @@ def _pad_members(
         F = tab["src"].shape[0]
         src[g, :F] = tab["src"]
         dst[g, :F] = tab["dst"]
-        skey[g, :F] = tab["src"]
-        dkey[g, :F] = tab["dst"]
         rel[g, :F] = tab["rel"]
         dur[g, :F] = tab["dur"]
         pending[g, :F] = True
     return dict(
-        src=src, dst=dst, skey=skey, dkey=dkey, rel=rel, dur=dur,
-        pending=pending, G=G, Fmax=Fmax, Nmax=Nmax,
+        src=src, dst=dst, rel=rel, dur=dur, pending=pending, G=G,
+        Fmax=Fmax, Nmax=Nmax,
     )
 
 
-def _calendar_program(pad: dict, discipline: str, engine: str, sharding=None):
-    """Assemble the jitted JAX executor for one padded bucket.
+def _calendar_program(pad: dict, discipline: str, sharding=None):
+    """Assemble the jitted device calendar for one padded bucket.
 
     Returns ``(fn, args, statics, perm)`` with ``args`` host arrays —
     callers place them (with ``sharding`` when given) and invoke
     ``fn(*args, **statics)`` under `jax.enable_x64`, or lower without
-    running via ``fn.lower``.  ``perm`` (kernel engine; None otherwise) is
-    the pair-sorted order of the outputs: output column ``i`` belongs to
-    flow ``perm[:, i]``.
+    running via ``fn.lower``.  The inputs are a (G, Dp, P = Nmax^2) slab
+    of flows by pair and depth, and the outputs come back in pair-sorted
+    order: output column ``i`` belongs to flow ``perm[:, i]``.
     """
     reserving = discipline == "reserving"
     src, dst = pad["src"], pad["dst"]
-    G, Fmax, Nmax = pad["G"], pad["Fmax"], pad["Nmax"]
+    Fmax, Nmax = pad["Fmax"], pad["Nmax"]
     rel, dur = to_bits(pad["rel"]), to_bits(pad["dur"])
-    free0 = np.zeros((G, Nmax), dtype=np.int64)
-    if engine == "jax":
-        psrc, soff, send, sempty = _port_segments(pad["skey"], Nmax)
-        pdst, doff, dend, dempty = _port_segments(pad["dkey"], Nmax)
-        args = (
-            src, dst, rel, dur, pad["pending"], free0,
-            psrc, soff, send, sempty, pdst, doff, dend, dempty,
-        )
-        return _run_calendar, args, dict(
-            reserving=reserving, bound=event_bound(Fmax)
-        ), None
-    # engine == "kernel": a (G, Dp, P = Nmax^2) slab of flows by pair and
-    # depth; the outputs come back in pair-sorted order, which ``perm``
-    # maps back.
+    free0 = np.zeros((pad["G"], Nmax), dtype=np.int64)
     P = Nmax * Nmax
     pairkey = np.where(
         pad["pending"], src.astype(np.int64) * Nmax + dst, P
@@ -980,11 +780,12 @@ def _execute_members(
     labels: Sequence[str],
     sharding=None,
 ) -> tuple[np.ndarray, np.ndarray]:
-    """Pad per-member flow tables and run the selected calendar executor.
+    """Pad per-member flow tables and run the calendar executor
+    ``engine`` (``"kernel"`` or ``"wide"``; callers pass `_engine()`).
 
     Returns the (G, Fmax) establishment/completion arrays (G rows >=
-    len(tabs), padding rows garbage).  ``sharding`` places the JAX
-    executors' inputs with a data-axis `NamedSharding` (member rows round
+    len(tabs), padding rows garbage).  ``sharding`` places the device
+    calendar's inputs with a data-axis `NamedSharding` (member rows round
     up to the shard count); the wide engine is host-side NumPy and
     ignores it.  Counts the calendar's ``calendar.rounds`` (lockstep
     rounds; the longest device's when sharded), ``calendar.members``,
@@ -997,7 +798,7 @@ def _execute_members(
     with span("calendar.pack"):
         g_multiple = (
             int(sharding.mesh.shape["data"])
-            if sharding is not None and engine in ("jax", "kernel")
+            if sharding is not None and engine == "kernel"
             else 1
         )
         pad = _pad_members(tabs, num_ports_max, g_multiple)
@@ -1017,20 +818,19 @@ def _execute_members(
 
             with span("calendar.pack"):
                 fn, args, statics, perm = _calendar_program(
-                    pad, discipline, engine, sharding
+                    pad, discipline, sharding
                 )
                 args = [place(a, sharding) for a in args]
-                slab_depth = args[0].shape[1] if engine == "kernel" else 0
+                slab_depth = args[0].shape[1]
             with span("calendar.wait"):
                 est, comp, unfinished, stalled, rounds, member_rounds = (
                     to_host(*fn(*args, **statics))
                 )
                 del args  # frees the inputs' device buffers in the span
         with span("calendar.unpack"):
-            est = from_bits(est)
-            comp = from_bits(comp)
-            if perm is not None:
-                est, comp = (_unsort(a, perm) for a in (est, comp))
+            est, comp = (
+                _unsort(from_bits(a), perm) for a in (est, comp)
+            )
             for g, label in enumerate(labels):
                 if stalled[g]:
                     raise RuntimeError(
@@ -1042,8 +842,7 @@ def _execute_members(
                         f"({label})"
                     )
             _count_rounds(rounds, member_rounds, len(tabs))
-            if engine == "kernel":
-                _count_pairs(len(tabs), num_ports_max, pad, slab_depth)
+            _count_pairs(len(tabs), num_ports_max, pad, slab_depth)
     return est, comp
 
 
@@ -1083,25 +882,19 @@ def lower_calendar(
     tabs: Sequence[dict],
     num_ports_max: int,
     discipline: str = "reserving",
-    engine: str = "auto",
 ):
-    """Lower (don't run) the calendar program for these member tables.
+    """Lower (don't run) the device calendar for these member tables.
 
-    Returns the `jax.stages.Lowered` of the selected JAX executor on the
-    padded bucket — `benchmarks/micro.py` compiles it and feeds the
-    optimized HLO text to `repro.launch.hlo_cost` for the roofline
-    report.  The ``"wide"`` engine is host NumPy with no XLA program, so
-    requesting it raises `ValueError`.
+    Returns the `jax.stages.Lowered` of the device calendar on the padded
+    bucket, on every platform (the host calendar is NumPy, with no XLA
+    program) — `benchmarks/micro.py` compiles it and feeds the optimized
+    HLO text to `repro.launch.hlo_cost` for the roofline report.
     """
-    engine = _check_engine(discipline, engine)
-    if engine == "wide":
-        raise ValueError(
-            'engine "wide" is host NumPy: no XLA program to lower'
-        )
+    _check_discipline(discipline)
     if not tabs:
         raise ValueError("lower_calendar needs at least one member table")
     pad = _pad_members(tabs, num_ports_max)
-    fn, args, statics, _ = _calendar_program(pad, discipline, engine)
+    fn, args, statics, _ = _calendar_program(pad, discipline)
     with jax.enable_x64():
         return fn.lower(*args, **statics)
 
@@ -1111,7 +904,6 @@ def schedule_batch(
     allocs: Sequence[Allocation],
     orders: Sequence[np.ndarray],
     discipline: str = "reserving",
-    engine: str = "auto",
 ) -> list[tuple[list[CoreSchedule], np.ndarray]]:
     """Circuit-schedule a whole ensemble in one vectorized program.
 
@@ -1124,16 +916,11 @@ def schedule_batch(
     `AllocationBatch` pytrees instead of re-extracting member tables from
     instances.
 
-    ``engine`` selects the calendar executor: ``"kernel"`` (the lockstep
-    pair-space calendar with the Pallas `pair_resolve` round reduction —
-    the accelerator path), ``"jax"`` (the vmapped flow-space
-    `lax.while_loop`), ``"wide"`` (the lockstep NumPy pair engine, the
-    CPU path), or ``"auto"`` (kernel on TPU/GPU, wide on hosts;
-    overridable via the ``REPRO_CIRCUIT_ENGINE`` environment variable,
-    which may not move an accelerator run onto the host).
-    All are bit-identical to the oracle and to each other.
+    The platform picks the executor (`_engine`): the device calendar on
+    TPU and GPU, the host calendar on a CPU.  Both are bit-identical to
+    the oracle and to each other.
     """
-    engine = _check_engine(discipline, engine)
+    _check_discipline(discipline)
     instances = list(instances)
     if not (len(instances) == len(allocs) == len(orders)):
         raise ValueError("instances/allocs/orders length mismatch")
@@ -1158,7 +945,7 @@ def schedule_batch(
             [tab for _, _, tab in members],
             max(inst.num_ports for inst in instances),
             discipline,
-            engine,
+            _engine(),
             labels=[f"instance {b}, core {k}" for b, k, _ in members],
         )
 
@@ -1202,7 +989,6 @@ def cct_batch_arrays(
     ensemble: EnsembleBatch,
     alloc: AllocationBatch,
     discipline: str = "reserving",
-    engine: str = "auto",
 ) -> np.ndarray:
     """Realized per-coflow CCTs straight off the padded pytrees — lean.
 
@@ -1216,7 +1002,7 @@ def cct_batch_arrays(
     stage bit for bit (the max over an identical completion multiset is
     order-independent); padded entries are 0.
     """
-    engine = _check_engine(discipline, engine)
+    _check_discipline(discipline)
     B = ensemble.num_instances
     cct = np.zeros((B, ensemble.pad_coflows))
     if B == 0:
@@ -1239,7 +1025,7 @@ def cct_batch_arrays(
             tabs,
             max(ensemble.num_ports[b] for b in range(B)),
             discipline,
-            engine,
+            _engine(),
             labels=[f"instance {b}, core {k}" for b, k, _ in members],
             sharding=ensemble.sharding,
         )
@@ -1254,7 +1040,6 @@ def schedule_batch_arrays(
     ensemble: EnsembleBatch,
     alloc: AllocationBatch,
     discipline: str = "reserving",
-    engine: str = "auto",
     busy: dict[tuple[int, int], dict[str, np.ndarray]] | None = None,
 ) -> list[tuple[list[CoreSchedule], np.ndarray]]:
     """Circuit-schedule straight off the unified padded pytrees.
@@ -1270,8 +1055,8 @@ def schedule_batch_arrays(
 
     Per-instance `CoreSchedule`s / CCT vectors are materialized here —
     the circuit is the pipeline's last array stage.  When the batch
-    carries a `NamedSharding`, the JAX executor's member axis is padded
-    to the shard count and placed with it.
+    carries a `NamedSharding`, the device calendar's member axis is
+    padded to the shard count and placed with it.
 
     ``busy`` (streaming re-solve support) maps ``(b, k)`` to phantom
     flow tables — ``dict(src=, dst=, rel=, dur=)`` 1-D arrays describing
@@ -1288,7 +1073,7 @@ def schedule_batch_arrays(
     ignored (phantoms alone constrain nothing).
     """
     with span("calendar.pack"):
-        engine = _check_engine(discipline, engine)
+        _check_discipline(discipline)
         B = ensemble.num_instances
         if B == 0:
             return []
@@ -1314,7 +1099,7 @@ def schedule_batch_arrays(
             tabs,
             max(ensemble.num_ports[b] for b in range(B)),
             discipline,
-            engine,
+            _engine(),
             labels=[f"instance {b}, core {k}" for b, k, _, _ in members],
             sharding=ensemble.sharding,
         )

@@ -185,16 +185,15 @@ class Pipeline:
     def _refine_key(self, refine_t: tuple) -> tuple:
         """Stage-identity key of a refinement pass.  Refined orders depend
         on everything the search evaluates through — the refine config AND
-        the allocation/circuit configuration — so all of it joins the key
-        (engines are bit-identical, but stay in the key like
-        `_circuit_key` keeps them: conservative beats stale)."""
+        the allocation/circuit configuration — so all of it joins the
+        key."""
         ast = self.allocate_stage
         cst = self.circuit_stage
         return (
             "refine", refine_t,
             ast.kind, getattr(ast, "include_tau", None),
             cst.kind, getattr(cst, "discipline", None),
-            getattr(cst, "backend", None), getattr(cst, "engine", None),
+            getattr(cst, "backend", None),
         ) + self._order_key()
 
     def _alloc_key(self, refine_t: tuple | None = None) -> tuple:
@@ -211,7 +210,6 @@ class Pipeline:
         return (
             "circuit", st.kind,
             getattr(st, "discipline", None), getattr(st, "backend", None),
-            getattr(st, "engine", None),
         ) + self._alloc_key(refine_t)
 
     def run_batch(
@@ -519,12 +517,10 @@ _ORDER_STAGES = {
 }
 
 _CIRCUIT_STAGES = {
-    "list": lambda discipline, backend, engine: st.ListCircuit(
-        discipline, backend, engine
-    ),
-    "sequential": lambda discipline, backend, engine: st.SequentialCircuit(),
-    "bvn": lambda discipline, backend, engine: st.BvnCircuit(),
-    "fluid": lambda discipline, backend, engine: st.FluidCircuit(),
+    "list": lambda discipline, backend: st.ListCircuit(discipline, backend),
+    "sequential": lambda discipline, backend: st.SequentialCircuit(),
+    "bvn": lambda discipline, backend: st.BvnCircuit(),
+    "fluid": lambda discipline, backend: st.FluidCircuit(),
 }
 
 
@@ -535,19 +531,18 @@ def build_pipeline(
     lp_method: str = "exact",
     lp_iters: int = 3000,
     circuit_backend: str = "batch",
-    circuit_engine: str = "auto",
 ) -> Pipeline:
     """Materialize a `SchemeSpec` into an executable `Pipeline`.
 
     ``discipline`` applies to list-scheduler circuits whose spec leaves it
     open (the spec's own pin wins); ``lp_method``/``lp_iters`` configure
     LP-ordering stages that have to solve for themselves.
-    ``circuit_backend`` selects the list scheduler's `run_batch` engine:
+    ``circuit_backend`` selects the list scheduler's `run_batch` path:
     ``"batch"`` (default — the whole-ensemble padded event calendar) or
-    ``"loop"`` (per-instance NumPy oracle); ``circuit_engine`` picks the
-    batch backend's calendar executor (``"kernel"``/``"jax"``/``"wide"``,
-    default ``"auto"`` — see `repro.pipeline.batch_circuit`).  Stages
-    without a batched form ignore both.
+    ``"loop"`` (per-instance NumPy oracle).  The batch calendar is the
+    device calendar on TPU and GPU and the host calendar on a CPU, chosen
+    by platform (`repro.pipeline.batch_circuit`).  Stages without a
+    batched form ignore the backend.
     """
     try:
         order_stage = _ORDER_STAGES[spec.order](lp_method, lp_iters)
@@ -555,7 +550,7 @@ def build_pipeline(
         raise ValueError(f"unknown order stage kind {spec.order!r}") from None
     try:
         circuit_stage = _CIRCUIT_STAGES[spec.circuit](
-            spec.discipline or discipline, circuit_backend, circuit_engine
+            spec.discipline or discipline, circuit_backend
         )
     except KeyError:
         raise ValueError(

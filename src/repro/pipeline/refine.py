@@ -227,7 +227,6 @@ def refine_batch_arrays(
     *,
     include_tau: bool = True,
     discipline: str = "greedy",
-    engine: str = "auto",
     alloc_fn: Callable | None = None,
     cct_fn: Callable | None = None,
 ) -> RefineOutcome:
@@ -252,7 +251,7 @@ def refine_batch_arrays(
         )
     if cct_fn is None:
         cct_fn = lambda ens, a: cct_batch_arrays(  # noqa: E731
-            ens, a, discipline=discipline, engine=engine
+            ens, a, discipline=discipline
         )
     orders = np.array(orders)
     if B == 0:

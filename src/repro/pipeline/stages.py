@@ -236,29 +236,18 @@ class ListCircuit:
     NumPy event loop — the parity oracle and the explicit fallback,
     mirroring the ``alloc="batch"|"loop"`` convention.  ``schedule_batch``
     returns None under the loop backend so `Pipeline.run_batch` can fall
-    back (or error under ``require_batch``).
-
-    ``engine`` selects the batch backend's calendar executor
-    (``"kernel"`` / ``"jax"`` / ``"wide"``; the default ``"auto"``
-    resolves per backend, overridable via ``REPRO_CIRCUIT_ENGINE`` — see
-    `repro.pipeline.batch_circuit`); the loop backend ignores it.
+    back (or error under ``require_batch``).  The batch backend runs the
+    device calendar on TPU and GPU and the host calendar on a CPU, chosen
+    by platform inside `repro.pipeline.batch_circuit`.
     """
 
     kind = "list"
 
-    def __init__(
-        self,
-        discipline: str = "greedy",
-        backend: str = "batch",
-        engine: str = "auto",
-    ):
+    def __init__(self, discipline: str = "greedy", backend: str = "batch"):
         if backend not in ("batch", "loop"):
             raise ValueError(f"unknown circuit backend {backend!r}")
-        if engine not in ("auto", "jax", "wide", "kernel"):
-            raise ValueError(f"unknown engine {engine!r}")
         self.discipline = discipline
         self.backend = backend
-        self.engine = engine
 
     def schedule(self, instance, alloc, order):
         schedules = _schedule_all_cores(
@@ -272,8 +261,7 @@ class ListCircuit:
         from repro.pipeline.batch_circuit import schedule_batch
 
         return schedule_batch(
-            instances, allocs, orders,
-            discipline=self.discipline, engine=self.engine,
+            instances, allocs, orders, discipline=self.discipline
         )
 
     def schedule_batch_arrays(self, ensemble, alloc_batch):
@@ -285,8 +273,7 @@ class ListCircuit:
         from repro.pipeline.batch_circuit import schedule_batch_arrays
 
         return schedule_batch_arrays(
-            ensemble, alloc_batch,
-            discipline=self.discipline, engine=self.engine,
+            ensemble, alloc_batch, discipline=self.discipline
         )
 
     def cct_batch_arrays(self, ensemble, alloc_batch):
@@ -299,8 +286,7 @@ class ListCircuit:
         from repro.pipeline.batch_circuit import cct_batch_arrays
 
         return cct_batch_arrays(
-            ensemble, alloc_batch,
-            discipline=self.discipline, engine=self.engine,
+            ensemble, alloc_batch, discipline=self.discipline
         )
 
 

@@ -481,7 +481,6 @@ def stream(
     lp_iters: int = 3000,
     lp_iters_warm: int | None = None,
     discipline: str = "greedy",
-    engine: str = "auto",
     n_batches: int | None = None,
     batch_window: float | None = None,
     pool_size: int | None = None,
@@ -510,10 +509,12 @@ def stream(
     quantizes the resident flow arena: capacity starts at one quantum
     (or the stream's expected concurrent flow count, whichever is
     larger) and grows geometrically, so arena shapes — the epoch compile
-    -cache buckets — stay logarithmic in the trace's flow volume.  See
-    the module docstring for the event-loop semantics; with
-    ``n_batches=1`` and ``preempt=False`` the run replays the offline
-    `Pipeline.run_batch` bit for bit.
+    -cache buckets — stay logarithmic in the trace's flow volume.  Each
+    epoch's calendar is the device calendar on TPU and GPU and the host
+    calendar on a CPU, chosen by platform
+    (`repro.pipeline.batch_circuit`).  See the module docstring for the
+    event-loop semantics; with ``n_batches=1`` and ``preempt=False`` the
+    run replays the offline `Pipeline.run_batch` bit for bit.
     """
     t_start = time.perf_counter()
     M = instance.num_coflows
@@ -543,7 +544,6 @@ def stream(
         lp_method="exact",
         lp_iters=lp_iters,
         circuit_backend="batch",
-        circuit_engine=engine,
     )
     circuit = pipe.circuit_stage
     if not isinstance(circuit, ListCircuit) or circuit.backend != "batch":
@@ -746,8 +746,7 @@ def stream(
             busy_tabs = busy.tables(now, instance.num_cores)
         pairs = schedule_batch_arrays(
             ensemble, alloc_batch,
-            discipline=circuit.discipline, engine=circuit.engine,
-            busy=busy_tabs,
+            discipline=circuit.discipline, busy=busy_tabs,
         )
         with trace.span("calendar.readback"):
             schedules, ccts_e = pairs[0]
@@ -905,8 +904,7 @@ def stream(
             busy_tabs = busy.tables(now, instance.num_cores)
         pairs = schedule_batch_arrays(
             b, alloc_batch,
-            discipline=circuit.discipline, engine=circuit.engine,
-            busy=busy_tabs,
+            discipline=circuit.discipline, busy=busy_tabs,
         )
         with trace.span("calendar.readback"):
             schedules, ccts_slot = pairs[0]  # slot-indexed (S,) CCTs

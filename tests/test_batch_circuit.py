@@ -21,6 +21,7 @@ from repro.core.circuit import NOT_SCHEDULED
 from repro.core.ordering import wspt_order
 from repro.core.scheduler import _schedule_all_cores
 from repro.core.validate import ccts_from_schedules, validate_schedule
+from repro.pipeline import batch_circuit as bc
 from repro.pipeline.batch_circuit import event_bound, schedule_batch
 from repro.traffic.instances import random_instance
 
@@ -39,12 +40,15 @@ def _assert_schedules_identical(got, ref, ctx):
         assert a.rate == b.rate and a.delta == b.delta, (ctx, k)
 
 
-def _batch_vs_loop(instances, discipline, engine="auto"):
+def _use_engine(monkeypatch, engine):
+    """Run the calendar executor ``engine`` whatever the platform."""
+    monkeypatch.setattr(bc, "_engine", lambda: engine)
+
+
+def _batch_vs_loop(instances, discipline):
     orders = [wspt_order(inst) for inst in instances]
     allocs = [allocate(inst, o) for inst, o in zip(instances, orders)]
-    got = schedule_batch(
-        instances, allocs, orders, discipline=discipline, engine=engine
-    )
+    got = schedule_batch(instances, allocs, orders, discipline=discipline)
     assert len(got) == len(instances)
     for inst, alloc, order, (schedules, ccts) in zip(
         instances, allocs, orders, got
@@ -59,22 +63,20 @@ def _batch_vs_loop(instances, discipline, engine="auto"):
         validate_schedule(inst, schedules)
 
 
-# All three calendar executors are oracle-checked: the lockstep NumPy
-# pair engine ("wide", the CPU path) on the full seed grid, and the two
-# XLA engines — the vmapped flow-space `lax.while_loop` ("jax") and the
-# lockstep pair-space calendar ("kernel") — on compile-friendly subsets.
-FUZZ_CASES = (
-    [(s, "wide") for s in range(6)]
-    + [(s, "jax") for s in range(2)]
-    + [(s, "kernel") for s in range(4)]
-)
+# Both calendar executors are oracle-checked on the same seed grid: the
+# host calendar ("wide", the CPU path) and the device calendar
+# ("kernel", the TPU/GPU path; its round through the jnp pair oracle).
+FUZZ_CASES = [(s, e) for e in ("wide", "kernel") for s in range(6)]
 
 
 @pytest.mark.parametrize("discipline", DISCIPLINES)
 @pytest.mark.parametrize("seed,engine", FUZZ_CASES)
-def test_fuzz_mixed_shapes_and_releases(discipline, seed, engine):
+def test_fuzz_mixed_shapes_and_releases(
+    discipline, seed, engine, monkeypatch
+):
     """Random mixed-shape ensembles: every member pads flows, ports and
     cores differently; half the seeds use arbitrary release times."""
+    _use_engine(monkeypatch, engine)
     rng = np.random.default_rng(seed)
     instances = [
         random_instance(
@@ -88,7 +90,7 @@ def test_fuzz_mixed_shapes_and_releases(discipline, seed, engine):
         )
         for i in range(4)
     ]
-    _batch_vs_loop(instances, discipline, engine)
+    _batch_vs_loop(instances, discipline)
 
 
 def _table(pairs, rel, dur):
@@ -148,7 +150,6 @@ def _slab_vs_oracles(tabs, num_ports, discipline):
     wide engine, check both against `schedule_core` bit for bit, and
     return the slab depth the kernel engine used."""
     from repro.core.circuit import schedule_core
-    from repro.pipeline import batch_circuit as bc
     from repro.trace import collect
 
     labels = [f"member {g}" for g in range(len(tabs))]
@@ -182,12 +183,11 @@ def test_kernel_slab_edge_cases(discipline, case):
 
 
 @pytest.mark.parametrize("discipline", DISCIPLINES)
-def test_kernel_slab_busy_phantoms(discipline):
+def test_kernel_slab_busy_phantoms(discipline, monkeypatch):
     """In-flight phantom rows (``busy``) at the head of a member's table
     ride in the slab like any flow: the kernel engine matches the wide
     engine through `schedule_batch_arrays`, and `schedule_core` on the
     member tables with the phantoms in."""
-    from repro.pipeline import batch_circuit as bc
     from repro.pipeline import get_pipeline
     from repro.pipeline.ensemble_batch import build_ensemble_batch
 
@@ -206,12 +206,10 @@ def test_kernel_slab_busy_phantoms(discipline):
         )
         for k in range(2)
     }
-    got = bc.schedule_batch_arrays(
-        ensemble, alloc, discipline, engine="kernel", busy=busy
-    )
-    want = bc.schedule_batch_arrays(
-        ensemble, alloc, discipline, engine="wide", busy=busy
-    )
+    _use_engine(monkeypatch, "kernel")
+    got = bc.schedule_batch_arrays(ensemble, alloc, discipline, busy=busy)
+    _use_engine(monkeypatch, "wide")
+    want = bc.schedule_batch_arrays(ensemble, alloc, discipline, busy=busy)
     (scheds, ccts), = got
     (ref_scheds, ref_ccts), = want
     assert np.array_equal(ccts, ref_ccts)
@@ -225,11 +223,12 @@ def test_kernel_slab_busy_phantoms(discipline):
     _slab_vs_oracles(tabs, inst.num_ports, discipline)
 
 
-@pytest.mark.parametrize("engine", ["wide", "jax", "kernel"])
+@pytest.mark.parametrize("engine", ["wide", "kernel"])
 @pytest.mark.parametrize("discipline", DISCIPLINES)
-def test_single_flow_and_empty_cores(discipline, engine):
+def test_single_flow_and_empty_cores(discipline, engine, monkeypatch):
     """F=1 instances on K=3 cores: two cores stay empty, and the empty
     CoreSchedules must match the oracle's F=0 fast path field for field."""
+    _use_engine(monkeypatch, engine)
     demands = np.zeros((1, 3, 3))
     demands[0, 1, 2] = 7.0
     inst = dataclasses.replace(
@@ -239,7 +238,7 @@ def test_single_flow_and_empty_cores(discipline, engine):
     order = np.array([0])
     alloc = allocate(inst, order)
     (schedules, ccts), = schedule_batch(
-        [inst], [alloc], [order], discipline=discipline, engine=engine
+        [inst], [alloc], [order], discipline=discipline
     )
     ref = _schedule_all_cores(inst, alloc, order, discipline=discipline)
     _assert_schedules_identical(schedules, ref, "F=1")
@@ -261,12 +260,13 @@ def _raw_alloc(coflow, src, dst, size, core, K, N):
     )
 
 
-@pytest.mark.parametrize("engine", ["wide", "jax", "kernel"])
+@pytest.mark.parametrize("engine", ["wide", "kernel"])
 @pytest.mark.parametrize("discipline", DISCIPLINES)
-def test_zero_duration_flows(discipline, engine):
+def test_zero_duration_flows(discipline, engine, monkeypatch):
     """size=0 + delta=0 subflows (dur == 0) chain same-port starts at one
     instant in the NumPy loop; the padded calendar must do exactly the
     same instead of stalling or spreading them across events."""
+    _use_engine(monkeypatch, engine)
     N, K = 4, 2
     inst = dataclasses.replace(
         random_instance(num_coflows=3, num_ports=N, num_cores=K, seed=1),
@@ -282,7 +282,7 @@ def test_zero_duration_flows(discipline, engine):
     )
     order = np.arange(3)
     (schedules, ccts), = schedule_batch(
-        [inst], [alloc], [order], discipline=discipline, engine=engine
+        [inst], [alloc], [order], discipline=discipline
     )
     ref = _schedule_all_cores(inst, alloc, order, discipline=discipline)
     _assert_schedules_identical(schedules, ref, "dur=0")
@@ -297,8 +297,6 @@ def test_empty_ensemble_and_mismatch():
         schedule_batch([inst], [], [])
     with pytest.raises(ValueError, match="unknown discipline"):
         schedule_batch([], [], [], discipline="nope")
-    with pytest.raises(ValueError, match="unknown engine"):
-        schedule_batch([], [], [], engine="nope")
 
 
 def test_event_bound_is_static_and_generous():
@@ -399,51 +397,30 @@ def test_not_scheduled_guard_regression():
 
 # ------------------------------------------------- engine selection
 def test_check_engine_auto_env_and_explicit(monkeypatch):
-    """"auto" resolves per backend (kernel on TPU/GPU, wide on hosts);
-    REPRO_CIRCUIT_ENGINE overrides auto-selection only, never an explicit
-    engine= argument; junk in the variable, or a host engine named on an
-    accelerator, is a loud error."""
-    from repro.pipeline import batch_circuit as bc
-
-    monkeypatch.delenv("REPRO_CIRCUIT_ENGINE", raising=False)
+    """The platform picks the calendar: the device calendar on TPU and
+    GPU, the host calendar on a CPU."""
     for backend, want in (("cpu", "wide"), ("tpu", "kernel"), ("gpu", "kernel")):
         monkeypatch.setattr(bc.jax, "default_backend", lambda b=backend: b)
-        assert bc._check_engine("greedy", "auto") == want
-    monkeypatch.setenv("REPRO_CIRCUIT_ENGINE", " JAX ")
-    assert bc._check_engine("greedy", "auto") == "jax"
-    # explicit engine= wins over the environment
-    assert bc._check_engine("greedy", "wide") == "wide"
-    monkeypatch.setenv("REPRO_CIRCUIT_ENGINE", "turbo")
-    with pytest.raises(ValueError, match="REPRO_CIRCUIT_ENGINE"):
-        bc._check_engine("greedy", "auto")
-    assert bc._check_engine("greedy", "kernel") == "kernel"
-    # On an accelerator the variable cannot move the run onto the host;
-    # only an explicit engine= argument picks the host engine there.
-    monkeypatch.setenv("REPRO_CIRCUIT_ENGINE", "wide")
-    monkeypatch.setattr(bc.jax, "default_backend", lambda: "tpu")
-    with pytest.raises(ValueError, match="off the tpu"):
-        bc._check_engine("greedy", "auto")
-    assert bc._check_engine("greedy", "wide") == "wide"
+        assert bc._engine() == want
 
 
 def test_kernel_fallback_warns_once(monkeypatch):
-    """On backends without a native Pallas lowering the kernel engine
+    """On backends without a native Pallas lowering the device calendar
     must say (once) that its round runs through the jnp oracle."""
     import warnings
-
-    from repro.pipeline import batch_circuit as bc
 
     if bc.jax.default_backend() != "cpu":
         pytest.skip("fallback only happens on interpret-mode backends")
     monkeypatch.setattr(bc, "_KERNEL_FALLBACK_WARNED", False)
+    _use_engine(monkeypatch, "kernel")
     inst = random_instance(num_coflows=3, num_ports=3, num_cores=2, seed=7)
     order = wspt_order(inst)
     alloc = allocate(inst, order)
     with pytest.warns(RuntimeWarning, match="jnp pair oracle"):
-        schedule_batch([inst], [alloc], [order], engine="kernel")
+        schedule_batch([inst], [alloc], [order])
     with warnings.catch_warnings():
         warnings.simplefilter("error")
-        schedule_batch([inst], [alloc], [order], engine="kernel")
+        schedule_batch([inst], [alloc], [order])
 
 
 @pytest.mark.parametrize("discipline", DISCIPLINES)
@@ -451,26 +428,24 @@ def test_kernel_engine_forced_pallas_parity(discipline, monkeypatch):
     """The full calendar with the Pallas pair_resolve round forced on
     (interpret mode on CPU) stays bit-identical to the oracle — the same
     program that runs compiled on TPU/GPU."""
-    from repro.pipeline import batch_circuit as bc
-
+    _use_engine(monkeypatch, "kernel")
     monkeypatch.setattr(bc, "_PAIR_KERNEL_INTERPRET", True)
     inst = random_instance(
         num_coflows=4, num_ports=3, num_cores=2, seed=11, release_span=10.0
     )
-    _batch_vs_loop([inst], discipline, engine="kernel")
+    _batch_vs_loop([inst], discipline)
 
 
 @pytest.mark.parametrize("discipline", DISCIPLINES)
-def test_run_batch_kernel_engine_parity(discipline, grid_with_lp):
-    """Pipeline.run_batch with circuit_engine="kernel" reproduces the
-    default engine's CCTs and schedules bit for bit."""
+def test_run_batch_kernel_engine_parity(discipline, grid_with_lp, monkeypatch):
+    """Pipeline.run_batch on the device calendar reproduces the host
+    calendar's CCTs and schedules bit for bit."""
     instances, sols, _, _ = grid_with_lp
-    pipe = pipeline.get_pipeline(
-        "ours", discipline=discipline, circuit_engine="kernel"
-    )
-    ref_pipe = pipeline.get_pipeline("ours", discipline=discipline)
+    pipe = pipeline.get_pipeline("ours", discipline=discipline)
+    _use_engine(monkeypatch, "kernel")
     batch = pipe.run_batch(instances, lp_solutions=sols, require_batch=True)
-    ref = ref_pipe.run_batch(instances, lp_solutions=sols, require_batch=True)
+    _use_engine(monkeypatch, "wide")
+    ref = pipe.run_batch(instances, lp_solutions=sols, require_batch=True)
     for a, b in zip(batch, ref):
         assert np.array_equal(a.ccts, b.ccts)
         _assert_schedules_identical(
@@ -479,8 +454,9 @@ def test_run_batch_kernel_engine_parity(discipline, grid_with_lp):
 
 
 def test_lower_calendar_engines():
-    """lower_calendar lowers an XLA program for both JAX engines (the
-    HLO feeds the roofline report) and refuses the host-NumPy engine."""
+    """lower_calendar lowers the device calendar's XLA program on every
+    platform (the HLO feeds the roofline report) and refuses an empty
+    bucket."""
     from repro.pipeline.batch_circuit import lower_calendar, member_tables
 
     inst = random_instance(num_coflows=4, num_ports=3, num_cores=2, seed=3)
@@ -489,12 +465,7 @@ def test_lower_calendar_engines():
     tabs = [
         t for t in member_tables(inst, alloc, order) if t["coflow"].shape[0]
     ]
-    for engine in ("jax", "kernel"):
-        text = lower_calendar(
-            tabs, inst.num_ports, "greedy", engine=engine
-        ).as_text()
-        assert "while" in text
-    with pytest.raises(ValueError, match="no XLA program"):
-        lower_calendar(tabs, inst.num_ports, "greedy", engine="wide")
+    text = lower_calendar(tabs, inst.num_ports, "greedy").as_text()
+    assert "while" in text
     with pytest.raises(ValueError, match="at least one member"):
-        lower_calendar([], inst.num_ports, "greedy", engine="jax")
+        lower_calendar([], inst.num_ports, "greedy")

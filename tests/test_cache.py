@@ -74,12 +74,12 @@ class TestDigests:
         base = dict(
             lp_method="exact", lp_iters=100, m_quantum=8, p_quantum=8,
             discipline="greedy", alloc="batch", circuit="batch",
-            circuit_engine="auto", certify=False,
+            certify=False,
         )
         d0 = canonical_digest(base)
         assert d0 == canonical_digest(dict(base))
         for k, v in [("lp_iters", 200), ("discipline", "reserving"),
-                     ("circuit_engine", "kernel"), ("certify", True)]:
+                     ("circuit", "loop"), ("certify", True)]:
             assert canonical_digest({**base, k: v}) != d0
 
     def test_cell_key_mixes_all_parts(self):

@@ -34,7 +34,7 @@ from repro.traffic.instances import sample_instance
 MEMBERS, N, M, ITERS = 6, 4, 12, 300
 KW = dict(
     schemes=("ours",), lp_method="batch", lp_iters=ITERS, m_quantum=1,
-    p_quantum=1, discipline="greedy", circuit_engine="kernel", cache=None,
+    p_quantum=1, discipline="greedy", cache=None,
 )
 LP_SPANS = ("lp.pack", "lp.wait", "lp.unpack")
 
@@ -51,6 +51,7 @@ def ensemble():
 @pytest.fixture(scope="module")
 def swept(ensemble):
     mp = pytest.MonkeyPatch()
+    mp.setattr(bc, "_engine", lambda: "kernel")
     mp.setattr(bc, "_PAIR_KERNEL_INTERPRET", True)
     try:
         yield sweep(ensemble, **KW)
@@ -109,14 +110,15 @@ def test_ensemble_counters_hold_exactly(ensemble, swept):
     assert c["calendar.pair_slots"] == members * 8 * 128
 
 
-def test_lp_spans_sit_inside_sweep_lp(ensemble, swept, tmp_path):
+def test_lp_spans_sit_inside_sweep_lp(ensemble, swept, tmp_path, monkeypatch):
     from jax.profiler import ProfileData
 
     s = swept.spans
     assert all(s[k] > 0 for k in LP_SPANS)
     assert sum(s[k] for k in LP_SPANS) <= s["sweep.lp"]
+    monkeypatch.setattr(bc, "_engine", lambda: "wide")
     with jax.profiler.trace(str(tmp_path)):
-        sweep(ensemble, **{**KW, "circuit_engine": "wide"})
+        sweep(ensemble, **KW)
     path = sorted(glob.glob(f"{tmp_path}/**/*.xplane.pb", recursive=True))
     found = []
     for plane in ProfileData.from_file(path[-1]).planes:

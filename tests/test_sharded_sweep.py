@@ -6,7 +6,9 @@ smoke's pattern).  The subprocess runs the same mixed-shape ensemble —
 with bucket sizes that do NOT divide the device count — through
 `sweep()` and `sweep(mesh=make_local_mesh())` and asserts bit-identical
 per-coflow CCTs, LP objectives, and byte-identical JSON/CSV row
-artifacts.
+artifacts: once on the calendar the CPU picks (the host one), and once
+with the device calendar forced, so its member-sharded program
+(`batch_circuit._run_calendar_pairs_sharded`) runs over the 8 devices.
 """
 
 import os
@@ -14,11 +16,22 @@ import subprocess
 import sys
 
 _SCRIPT = r"""
-import json, os
+import json, os, sys
 import numpy as np
 import jax
 
 assert len(jax.devices()) == 8, jax.devices()
+
+device_calendar = sys.argv[1:] == ["device-calendar"]
+if device_calendar:
+    from repro.pipeline import batch_circuit as bc
+
+    bc._engine = lambda: "kernel"
+    sharded_calendar = bc._run_calendar_pairs_sharded
+    calls = []
+    bc._run_calendar_pairs_sharded = (
+        lambda *a, **k: calls.append(a) or sharded_calendar(*a, **k)
+    )
 
 from repro.experiments import sweep
 from repro.launch.mesh import data_axis_size, make_local_mesh
@@ -64,13 +77,15 @@ with open(c0, "rb") as f:
 with open(c1, "rb") as f:
     sharded_csv = f.read()
 assert single_csv == sharded_csv, "CSV rows diverged"
+if device_calendar:
+    assert calls, "the sharded sweep ran no sharded device calendar"
 print("SHARDED-PARITY-OK")
 """
 
 
-def test_sharded_sweep_bit_identical_subprocess(tmp_path):
+def _run(tmp_path, *args):
     proc = subprocess.run(
-        [sys.executable, "-c", _SCRIPT],
+        [sys.executable, "-c", _SCRIPT, *args],
         capture_output=True,
         text=True,
         timeout=900,
@@ -85,3 +100,11 @@ def test_sharded_sweep_bit_identical_subprocess(tmp_path):
     )
     assert proc.returncode == 0, proc.stdout + proc.stderr
     assert "SHARDED-PARITY-OK" in proc.stdout
+
+
+def test_sharded_sweep_bit_identical_subprocess(tmp_path):
+    _run(tmp_path)
+
+
+def test_sharded_device_calendar_bit_identical_subprocess(tmp_path):
+    _run(tmp_path, "device-calendar")

@@ -7,7 +7,7 @@ device programs of Algorithm 1 at the widths of the full Facebook trace
 refuses fails here, without a chip:
 
   * the kernel calendar with the native Pallas `pair_resolve` round under
-    x64 (the program ``engine="auto"`` runs on TPU);
+    x64 (the calendar the platform picks on a TPU);
   * the `pair_resolve` kernel alone;
   * the allocation scan, with its exact integer double arithmetic;
   * the batched subgradient LP;

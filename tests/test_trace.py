@@ -4,12 +4,13 @@ with them.
   * the helper: nested spans count inclusively, `collect` hands its
     totals to the enclosing tally, and with no tally installed spans and
     counts record nothing;
-  * streams (resident and rebuild, host and kernel calendar engines):
+  * streams (resident and rebuild, host and device calendars):
     every epoch carries its leaf spans, which tile the epoch
     (``stream.epoch``), ``lp_wall_s`` is the ``stream.lp`` span, and
     collecting or tracing changes no output;
-  * the kernel calendar (Pallas interpret mode) counts its lockstep
-    rounds and each member's rounds exactly, on hand-made tables;
+  * both calendars (the device one with its Pallas round in interpret
+    mode) count their lockstep rounds and each member's rounds exactly,
+    under both disciplines, on hand-made tables;
   * the spans sit on the profiler's clock, inside a caller's annotation;
   * ``host_reads`` sees every device -> host read of a stream.
 """
@@ -30,8 +31,8 @@ from repro.traffic import poisson_arrivals, with_releases
 from repro.traffic.instances import random_instance
 
 #: Leaf spans of every epoch that solves an LP; the calendar's execution
-#: adds ``calendar.wide`` (host engine) or ``calendar.wait`` and
-#: ``calendar.unpack`` (device engines).
+#: adds ``calendar.wide`` (host calendar) or ``calendar.wait`` and
+#: ``calendar.unpack`` (device calendar).
 EPOCH_LEAVES = {
     "stream.advance", "stream.admit", "stream.scatter", "stream.lp",
     "stream.order", "alloc.prepare", "alloc.wait", "alloc.unpack",
@@ -50,10 +51,12 @@ def _trace_instance(seed=3, M=10, N=6, K=3):
     )
 
 
-def _stream_kw(mode, engine):
+def _stream_kw(mode, engine, monkeypatch):
+    """A small stream's options, with ``engine`` the calendar it runs."""
+    monkeypatch.setattr(bc, "_engine", lambda: engine)
     return dict(
         lp_method="batch", lp_iters=200, n_batches=4, pool_size=4,
-        epoch_mode=mode, engine=engine,
+        epoch_mode=mode,
     )
 
 
@@ -104,9 +107,9 @@ def test_to_host_counts_device_arrays_only():
 # ----------------------------------------------------------------- streams
 @pytest.mark.parametrize("engine", ["wide", "kernel"])
 @pytest.mark.parametrize("mode", ["resident", "rebuild"])
-def test_stream_epochs_carry_tiling_leaf_spans(mode, engine):
+def test_stream_epochs_carry_tiling_leaf_spans(mode, engine, monkeypatch):
     inst = _trace_instance()
-    res = stream(inst, **_stream_kw(mode, engine))
+    res = stream(inst, **_stream_kw(mode, engine, monkeypatch))
     assert res.num_resolves >= 3
     leaves = EPOCH_LEAVES | ENGINE_LEAVES[engine]
     leaf_s = epoch_s = 0.0
@@ -131,9 +134,9 @@ def test_stream_epochs_carry_tiling_leaf_spans(mode, engine):
     assert "stream.finish" in res.spans
 
 
-def test_collecting_and_tracing_change_no_output(tmp_path):
+def test_collecting_and_tracing_change_no_output(tmp_path, monkeypatch):
     inst = _trace_instance(seed=5)
-    kw = _stream_kw("resident", "wide")
+    kw = _stream_kw("resident", "wide", monkeypatch)
     plain = stream(inst, **kw)
     with trace.collect() as outer, jax.profiler.trace(str(tmp_path)):
         traced = stream(inst, **kw)
@@ -172,62 +175,73 @@ def _tab(src, dst, dur):
     )
 
 
-# Hand counts (greedy, every release 0): a chain of three flows on one
-# port pair starts one flow a round (3 rounds); one flow (1 round); two
-# flows on disjoint port pairs start together (1 round).  Each table
-# comes with its hand-computed establishment times.
-CHAIN = (_tab([0, 0, 0], [1, 1, 1], [1.0, 2.0, 1.0]), [0.0, 1.0, 3.0])
-ONE = (_tab([1], [0], [3.0]), [0.0])
-DISJOINT = (_tab([0, 1], [0, 1], [1.0, 1.0]), [0.0, 0.0])
+# Hand counts (every release 0), per discipline: a chain of three flows
+# on one port pair starts one flow a round (3 rounds); one flow (1
+# round); two flows on disjoint port pairs start together (1 round) —
+# the same under both disciplines.  In CROSS, flow 0 on (0, 0) blocks
+# flow 1 on (0, 1) and flow 3 on (1, 0) until t = 2: greedy backfills
+# flow 2 on (1, 1) at t = 0 in a second round at that instant and starts
+# flows 1 and 3 together at t = 2 (3 rounds); reserving holds flow 2 for
+# flow 1, the first claimer on egress 1, and flow 3 for flow 2, the first
+# claimer on ingress 1, so one flow starts a round, at 0, 2, 3 and 4 (4
+# rounds).  Each table comes with its hand-computed establishment times
+# per discipline.
+def _both(est):
+    return {"greedy": est, "reserving": est}
 
 
-def _run_members(monkeypatch, members, engine):
+CHAIN = (_tab([0, 0, 0], [1, 1, 1], [1.0, 2.0, 1.0]), _both([0.0, 1.0, 3.0]))
+ONE = (_tab([1], [0], [3.0]), _both([0.0]))
+DISJOINT = (_tab([0, 1], [0, 1], [1.0, 1.0]), _both([0.0, 0.0]))
+CROSS = (
+    _tab([0, 0, 1, 1], [0, 1, 1, 0], [2.0, 1.0, 1.0, 1.0]),
+    {"greedy": [0.0, 2.0, 0.0, 2.0], "reserving": [0.0, 2.0, 3.0, 4.0]},
+)
+
+
+def _run_members(monkeypatch, members, engine, discipline):
     monkeypatch.setattr(bc, "_PAIR_KERNEL_INTERPRET", True)
     tabs = [t for t, _ in members]
     labels = [f"member {g}" for g in range(len(tabs))]
     with trace.collect() as tally:
-        est, comp = bc._execute_members(tabs, 2, "greedy", engine, labels)
+        est, comp = bc._execute_members(tabs, 2, discipline, engine, labels)
     for g, (tab, want) in enumerate(members):
+        want = want[discipline]
         F = len(want)
         assert np.array_equal(est[g, :F], want), g
         assert np.array_equal(comp[g, :F], np.asarray(want) + tab["dur"])
     return tally.counts
 
 
+@pytest.mark.parametrize("discipline", ["greedy", "reserving"])
 @pytest.mark.parametrize("engine", ["kernel", "wide"])
-@pytest.mark.parametrize("members,rounds,member_rounds", [
-    ([CHAIN], 3, 3),
-    ([ONE], 1, 1),
-    ([DISJOINT], 1, 1),
-    ([CHAIN, ONE, DISJOINT], 3, 5),
+@pytest.mark.parametrize("members,counts", [
+    # {discipline: (rounds, member_rounds)}
+    ([CHAIN], {"greedy": (3, 3), "reserving": (3, 3)}),
+    ([ONE], {"greedy": (1, 1), "reserving": (1, 1)}),
+    ([DISJOINT], {"greedy": (1, 1), "reserving": (1, 1)}),
+    ([CHAIN, ONE, DISJOINT], {"greedy": (3, 5), "reserving": (3, 5)}),
+    ([CROSS], {"greedy": (3, 3), "reserving": (4, 4)}),
 ])
 def test_calendar_counts_rounds_by_hand(
-    monkeypatch, engine, members, rounds, member_rounds
+    monkeypatch, engine, discipline, members, counts
 ):
-    c = _run_members(monkeypatch, members, engine)
+    rounds, member_rounds = counts[discipline]
+    c = _run_members(monkeypatch, members, engine, discipline)
     assert c["calendar.rounds"] == rounds
     assert c["calendar.member_rounds"] == member_rounds
     assert c["calendar.members"] == len(members)
     assert c["calendar.member_slots"] == len(members) * rounds
 
 
-@pytest.mark.parametrize("members", [[CHAIN], [CHAIN, ONE, DISJOINT]])
-def test_flow_space_calendar_counts_its_own_rounds(monkeypatch, members):
-    # The flow-space engine spends extra rounds where a blocked idle flow
-    # may still start at the same instant, so only the bounds hold.
-    c = _run_members(monkeypatch, members, "jax")
-    assert c["calendar.member_rounds"] <= c["calendar.member_slots"]
-    assert c["calendar.member_slots"] == len(members) * c["calendar.rounds"]
-    if len(members) == 1:
-        assert c["calendar.member_rounds"] == c["calendar.rounds"]
-
-
 # ------------------------------------------------------- profiler's clock
-def test_spans_nest_inside_a_callers_annotation_on_the_profile(tmp_path):
+def test_spans_nest_inside_a_callers_annotation_on_the_profile(
+    tmp_path, monkeypatch
+):
     from jax.profiler import ProfileData
 
     inst = _trace_instance(seed=9, M=6)
-    kw = _stream_kw("resident", "wide")
+    kw = _stream_kw("resident", "wide", monkeypatch)
     stream(inst, **kw)  # compile outside the trace
     with jax.profiler.trace(str(tmp_path)):
         with jax.profiler.TraceAnnotation("test.outer"):
@@ -277,7 +291,7 @@ def test_host_reads_sees_every_device_read(monkeypatch):
         return value.fget(self)
 
     inst = _trace_instance(seed=11)
-    kw = _stream_kw("resident", "kernel")
+    kw = _stream_kw("resident", "kernel", monkeypatch)
     stream(inst, **kw)  # compile first
     monkeypatch.setattr(ArrayImpl, "_value", property(watched))
     with jax.transfer_guard_device_to_host("disallow"):
