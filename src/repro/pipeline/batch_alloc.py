@@ -40,13 +40,21 @@ floating-point operations, in the same order, with the exact integer
 arithmetic of `repro.pipeline.exact64` (XLA:TPU only emulates f64, not
 to the last bit), so core choices, prefix port stats and prefix lower
 bounds are **bit-identical** to `allocate` on every backend — asserted
-per scheme and per flow table by `tests/test_pipeline.py`.  `allocate_batch` is the list-in/list-out
+per scheme and per flow table by `tests/test_pipeline.py`.
+
+The platform picks how the recurrence runs (`_kernel_mode`), and nothing
+overrides that choice: on a TPU as one Pallas kernel
+(`repro.kernels.alloc_scan`: the flow loop and the state stay on chip,
+the doubles as uint32 halves), elsewhere as the jnp loop (`_scan_loop`),
+which is the kernel's oracle (`tests/test_alloc_kernel.py`).  Both run
+inside the jitted `_scan_all`.  `allocate_batch` is the list-in/list-out
 wrapper (build one `EnsembleBatch`, run the array form, materialize
 `Allocation`s) kept for oracle tests and loop-path callers.
 """
 
 from __future__ import annotations
 
+import functools
 from typing import Sequence
 
 import numpy as np
@@ -112,28 +120,25 @@ def flow_sequence(
 NO_CORE = -1
 
 
-@jax.jit
-def _scan_all(
+#: Test hook: run the scan's Pallas kernel in the Pallas interpreter, so
+#: CPU tests execute the program the TPU compiles.
+_SCAN_KERNEL_INTERPRET = False
+
+
+def _kernel_mode() -> tuple[bool, bool]:
+    """``(kernel, interpret)`` of this platform's scan: the Pallas kernel
+    (`repro.kernels.alloc_scan`) on a TPU, the jnp loop elsewhere, unless
+    a test asks for the interpreter through `_SCAN_KERNEL_INTERPRET`."""
+    if _SCAN_KERNEL_INTERPRET:
+        return True, True
+    return jax.default_backend() == "tpu", False
+
+
+def _scan_loop(
     steps, pi, pj, d, valid, inv_rates, delta, lb0, core_mask, rho0, tau0
 ):
-    """Run the allocation recurrence for the whole padded ensemble.
-
-    Shapes: steps () int32, pi/pj (B, F) int32 flat-port endpoints,
-    d (B, F) sizes, valid (B, F) bool, inv_rates/lb0/core_mask (B, Kmax),
-    delta (B,), rho0/tau0 (B, Kmax, Pmax).  Every real-valued input and
-    output is the int64 bit pattern of a non-negative double
-    (`repro.pipeline.exact64`).  Returns per-step core choices and
-    real-core lb maxima plus the final (rho, tau) port stats.
-
-    ``valid`` must be a prefix of every row (`EnsembleBatch.permute_flows`
-    sorts invalid flows last), and ``steps`` at least the longest prefix:
-    the loop runs ``steps`` flow steps, not F, so the flow arena's empty
-    tail costs nothing.  ``steps`` is one traced scalar for the whole
-    ensemble, so the loop stays unbatched under the member vmap; members
-    with fewer valid flows step their invalid tail as masked no-ops.
-    Past ``steps`` the lb maxima hold each member's final one (what a
-    no-op step returns), and every invalid flow's core is `NO_CORE`.
-    """
+    """The recurrence as a jnp loop: the CPU's scan and the kernel's
+    oracle.  Returns `alloc_scan_pallas`'s outputs."""
 
     def member(rho, tau, lb, i, j, dd, v, inv_rates, delta, core_mask):
         def bump(x, k, p, by):  # x[k, p] += by, rounded as NumPy rounds
@@ -179,11 +184,83 @@ def _scan_all(
     rho, tau, lb, ks, lbs = jax.lax.fori_loop(
         jnp.uint32(0), steps.astype(jnp.uint32), step, init
     )
+    return ks.T, lbs.T, rho, tau, lb
+
+
+def _scan_kernel(*args, interpret, mesh):
+    """`alloc_scan_pallas`, per shard of ``mesh``'s ``data`` axis when
+    given: members are independent, and the compiler cannot partition a
+    Mosaic kernel, so every device steps its own members."""
+    # Imported here: the kernel imports `repro.pipeline.exact64`, whose
+    # package imports this module.
+    from repro.kernels.alloc_scan import alloc_scan_pallas
+
+    scan = functools.partial(alloc_scan_pallas, interpret=interpret)
+    if mesh is None:
+        return scan(*args)
+    data = jax.sharding.PartitionSpec("data")
+    # check_vma=False: the Pallas call's outputs declare no per-axis
+    # variance, and every value here is per-member anyway.
+    return jax.shard_map(
+        scan, mesh=mesh,
+        in_specs=(jax.sharding.PartitionSpec(),) + (data,) * (len(args) - 1),
+        out_specs=data, check_vma=False,
+    )(*args)
+
+
+def _scan_all(
+    steps, pi, pj, d, valid, inv_rates, delta, lb0, core_mask, rho0, tau0,
+    *, kernel=False, interpret=False, mesh=None,
+):
+    """Run the allocation recurrence for the whole padded ensemble.
+
+    Shapes: steps () int32, pi/pj (B, F) int32 flat-port endpoints,
+    d (B, F) sizes, valid (B, F) bool, inv_rates/lb0/core_mask (B, Kmax),
+    delta (B,), rho0/tau0 (B, Kmax, Pmax).  Every real-valued input and
+    output is the int64 bit pattern of a non-negative double
+    (`repro.pipeline.exact64`).  Returns per-step core choices and
+    real-core lb maxima plus the final (rho, tau) port stats.
+
+    ``valid`` must be a prefix of every row (`EnsembleBatch.permute_flows`
+    sorts invalid flows last), and ``steps`` at least the longest prefix:
+    the loop runs ``steps`` flow steps, not F, so the flow arena's empty
+    tail costs nothing.  ``steps`` is one traced scalar for the whole
+    ensemble, so the loop stays unbatched under the member vmap; members
+    with fewer valid flows step their invalid tail as masked no-ops.
+    Past ``steps`` the lb maxima hold each member's final one (what a
+    no-op step returns), and every invalid flow's core is `NO_CORE`.
+
+    ``kernel`` runs the recurrence as the Pallas kernel
+    (`repro.kernels.alloc_scan`, per shard of ``mesh`` when given; the
+    interpreter with ``interpret``), else as the jnp loop; `_kernel_mode`
+    picks by platform, and both give the same bits.
+    """
+    args = (steps, pi, pj, d, valid, inv_rates, delta, lb0, core_mask,
+            rho0, tau0)
+    if kernel:
+        ks, lbs, rho, tau, lb = _scan_kernel(
+            *args, interpret=interpret, mesh=mesh
+        )
+    else:
+        ks, lbs, rho, tau, lb = _scan_loop(*args)
     lb_last = jnp.max(jnp.where(core_mask, lb, NEG_INF), axis=1)
-    ran = (jnp.arange(F) < steps)[:, None]
-    lbs = jnp.where(ran, lbs, lb_last[None, :])
-    ks = jnp.where(valid.T, ks, NO_CORE)
-    return ks.T, lbs.T, rho, tau
+    ran = jnp.arange(valid.shape[1]) < steps
+    lbs = jnp.where(ran[None, :], lbs, lb_last[:, None])
+    ks = jnp.where(valid, ks, NO_CORE)
+    return ks, lbs, rho, tau
+
+
+_STATIC = ("kernel", "interpret", "mesh")
+#: The scan under the Pallas interpreter (tests): XLA:CPU's fusion would
+#: copy the kernel's long integer chains into every consumer and recompute
+#: them per element, seconds a step; unfused, the step takes microseconds.
+_scan_all_interpreted = jax.jit(
+    _scan_all, static_argnames=_STATIC,
+    compiler_options={
+        "xla_disable_hlo_passes": "fusion,cpu-instruction-fusion"
+    },
+)
+_scan_all = jax.jit(_scan_all, static_argnames=_STATIC)
 
 
 def allocate_batch_arrays(
@@ -246,8 +323,16 @@ def allocate_batch_arrays(
                     put(to_bits(lb0)), put(ensemble.core_mask),
                     put(zeros_kp), put(zeros_kp),
                 )
+                kernel, interpret = _kernel_mode()
+                sharding = ensemble.sharding
+                mesh = sharding.mesh if kernel and sharding is not None else None
+                if kernel:
+                    count("alloc.kernel_calls", 1)
             with span("alloc.wait"):
-                ks, lbs, rho, tau = to_host(*_scan_all(*args))
+                scan = _scan_all_interpreted if interpret else _scan_all
+                ks, lbs, rho, tau = to_host(*scan(
+                    *args, kernel=kernel, interpret=interpret, mesh=mesh
+                ))
                 del args  # frees the inputs' device buffers in the span
 
     with span("alloc.unpack"):

@@ -131,3 +131,178 @@ def mul(a, b):
     r = _shift_right_sticky(r, jnp.maximum(1 - e, 0))  # subnormal result
     out = _round_pack(jnp.maximum(e, 1), r)
     return jnp.where((ua == 0) | (ub == 0), jnp.int64(0), out)
+
+
+# -- the same arithmetic on uint32 halves ------------------------------------
+#
+# XLA:TPU emulates every int64 or uint64 operation as a pair of 32-bit ones,
+# and Mosaic (the Pallas TPU compiler) has no 64-bit integers at all.  The
+# allocation scan's Pallas kernel therefore carries each pattern as a
+# ``(hi, lo)`` pair of uint32 arrays and runs `add` and `mul` on the pairs:
+# the same steps, in the same order, with 64-bit shifts, carries and
+# compares written out, and the 53 x 53-bit significand product built from
+# 16-bit limbs (a 16 x 16-bit product is exact in uint32).  Every shift
+# amount stays in [0, 31], where XLA and Mosaic agree.  These need no x64.
+
+_U32 = jnp.uint32
+_HI_INF = INF >> 32  # the high word of +inf's pattern
+
+
+def split(bits):
+    """int64 patterns -> ``(hi, lo)`` uint32 halves (under x64)."""
+    u = _u64(jnp.asarray(bits, jnp.int64))
+    return (u >> 32).astype(_U32), u.astype(_U32)
+
+
+def join(x):
+    """``(hi, lo)`` uint32 halves -> int64 patterns (under x64)."""
+    hi, lo = x
+    u = (hi.astype(jnp.uint64) << 32) | lo.astype(jnp.uint64)
+    return jax.lax.bitcast_convert_type(u, jnp.int64)
+
+
+def ge_halves(a, b):
+    """``a >= b`` on pairs of non-negative patterns (unsigned compare)."""
+    return (a[0] > b[0]) | ((a[0] == b[0]) & (a[1] >= b[1]))
+
+
+def select_halves(c, a, b):
+    """Where ``c``, ``a``, else ``b``, word by word."""
+    return jnp.where(c, a[0], b[0]), jnp.where(c, a[1], b[1])
+
+
+def _add_w(a, b):
+    lo = a[1] + b[1]
+    return a[0] + b[0] + (lo < a[1]).astype(_U32), lo
+
+
+def _shr_w(x, s):
+    """``x >> s`` for uint32 ``s`` in [0, 63]."""
+    hi, lo = x
+    big = s >= 32
+    t = jnp.where(big, s - 32, s)
+    # ``(hi << 1) << (31 - t)`` is ``hi << (32 - t)`` with a shift < 32.
+    small_lo = (lo >> t) | ((hi << 1) << (31 - t))
+    return jnp.where(big, _U32(0), hi >> t), jnp.where(big, hi >> t, small_lo)
+
+
+def _shl_w(x, s):
+    """``x << s`` for uint32 ``s`` in [0, 63]."""
+    hi, lo = x
+    big = s >= 32
+    t = jnp.where(big, s - 32, s)
+    small_hi = (hi << t) | ((lo >> 1) >> (31 - t))
+    return jnp.where(big, lo << t, small_hi), jnp.where(big, _U32(0), lo << t)
+
+
+def _shr_sticky_w(x, k):
+    """`_shift_right_sticky` on pairs; ``k`` a non-negative int32."""
+    k = jnp.minimum(k, 63).astype(_U32)
+    y = _shr_w(x, k)
+    back = _shl_w(y, k)
+    lost = (back[0] != x[0]) | (back[1] != x[1])
+    return y[0], y[1] | lost.astype(_U32)
+
+
+def _round_pack_w(e, r):
+    """`_round_pack` on pairs; ``e`` an int32 >= 1."""
+    rh, rl = r
+    mh, ml = rh >> 3, (rl >> 3) | (rh << 29)
+    rem = rl & 7
+    up = (rem > 4) | ((rem == 4) & ((ml & 1) == 1))
+    lo = ml + up.astype(_U32)
+    hi = ((e - 1).astype(_U32) << 20) + mh + (lo < ml).astype(_U32)
+    over = hi >= _HI_INF  # at or past +inf: saturate
+    return jnp.where(over, _U32(_HI_INF), hi), jnp.where(over, _U32(0), lo)
+
+
+def _unpack_w(x):
+    hi, lo = x
+    e = (hi >> 20).astype(jnp.int32)
+    mh = (hi & 0xFFFFF) | jnp.where(e > 0, _U32(1 << 20), _U32(0))
+    return jnp.maximum(e, 1), (mh, lo)
+
+
+def _clz_w(x):
+    """Leading zeros of a uint32, by binary search (Mosaic has no clz)."""
+    n = jnp.zeros(x.shape, jnp.int32)
+    for s in (16, 8, 4, 2, 1):
+        small = (x >> (32 - s)) == 0
+        n = n + jnp.where(small, jnp.int32(s), jnp.int32(0))
+        x = jnp.where(small, x << s, x)
+    return n + (x == 0).astype(jnp.int32)
+
+
+def _mul_w(x, y):
+    """Full 64-bit product of two uint32 arrays, from 16-bit limbs."""
+    x1, x0 = x >> 16, x & 0xFFFF
+    y1, y0 = y >> 16, y & 0xFFFF
+    p00, p11 = x0 * y0, x1 * y1
+    p01 = x0 * y1
+    mid = p01 + x1 * y0
+    lo = p00 + (mid << 16)
+    hi = (
+        p11 + (mid >> 16) + ((mid < p01).astype(_U32) << 16)
+        + (lo < p00).astype(_U32)
+    )
+    return hi, lo
+
+
+def add_halves(a, b):
+    """`add` on ``(hi, lo)`` uint32 pairs."""
+    a_hi = ge_halves(a, b)
+    hi = select_halves(a_hi, a, b)
+    lo = select_halves(a_hi, b, a)
+    eh, mh = _unpack_w(hi)
+    el, ml = _unpack_w(lo)
+    three = _U32(3)
+    s = _add_w(_shl_w(mh, three), _shr_sticky_w(_shl_w(ml, three), eh - el))
+    carry = s[0] >> 24  # the sum reached the next binade
+    s = select_halves(carry == 1, _shr_sticky_w(s, jnp.int32(1)), s)
+    out = _round_pack_w(eh + carry.astype(jnp.int32), s)
+    return select_halves((hi[0] >> 20) == 2047, hi, out)
+
+
+def mul_halves(a, b):
+    """`mul` on ``(hi, lo)`` uint32 pairs."""
+    ea, ma = _unpack_w(a)
+    eb, mb = _unpack_w(b)
+
+    def normalize(e, m):  # subnormal: move the leading one to bit 52
+        clz = jnp.where(m[0] != 0, _clz_w(m[0]), 32 + _clz_w(m[1]))
+        sh = jnp.maximum(clz - 11, 0)
+        return e - sh, _shl_w(m, sh.astype(_U32))
+
+    ea, ma = normalize(ea, ma)
+    eb, mb = normalize(eb, mb)
+    # 106-bit product in four words w3:w2:w1:w0 from 32 x 32-bit parts.
+    ll = _mul_w(ma[1], mb[1])
+    lh = _mul_w(ma[1], mb[0])
+    hl = _mul_w(ma[0], mb[1])
+    hh = _mul_w(ma[0], mb[0])
+    t = ll[0] + lh[1]
+    w1 = t + hl[1]
+    c1 = (t < ll[0]).astype(_U32) + (w1 < t).astype(_U32)
+    u = hh[1] + lh[0]
+    u2 = u + hl[0]
+    w2 = u2 + c1
+    c2 = (
+        (u < hh[1]).astype(_U32) + (u2 < u).astype(_U32)
+        + (w2 < u2).astype(_U32)
+    )
+    w3 = hh[0] + c2
+    # Keep 56 bits (leading one at bit 55), the rest sticky: the product
+    # shifted right by 49 + top, 32 of them the dropped word w0.
+    top = w3 >> 9  # 1 when the product is >= 2**105
+    sh = 17 + top
+    sticky = (ll[1] != 0) | ((w1 & ((_U32(1) << sh) - 1)) != 0)
+    r = (
+        (w2 >> sh) | (w3 << (32 - sh)),
+        (w1 >> sh) | (w2 << (32 - sh)) | sticky.astype(_U32),
+    )
+    e = ea + eb - 1023 + top.astype(jnp.int32)
+    r = _shr_sticky_w(r, jnp.maximum(1 - e, 0))  # subnormal result
+    out = _round_pack_w(jnp.maximum(e, 1), r)
+    zero = ((a[0] == 0) & (a[1] == 0)) | ((b[0] == 0) & (b[1] == 0))
+    zero_w = _U32(0)
+    return jnp.where(zero, zero_w, out[0]), jnp.where(zero, zero_w, out[1])

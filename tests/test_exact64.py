@@ -1,5 +1,7 @@
 """Integer-only binary64 arithmetic (`repro.pipeline.exact64`) against
-NumPy's IEEE doubles, bit for bit."""
+NumPy's IEEE doubles, bit for bit: on int64 patterns (`add`, `mul`) and
+on their uint32 halves (`add_halves`, `mul_halves`, the allocation-scan
+kernel's arithmetic, run here as plain jnp)."""
 
 import numpy as np
 import pytest
@@ -36,7 +38,19 @@ def _run(fn, a, b):
         return x64.from_bits(jax.jit(fn)(x64.to_bits(a), x64.to_bits(b)))
 
 
-@pytest.mark.parametrize("op", ["add", "mul"])
+def _run_halves(fn, a, b):
+    """``fn`` on uint32 halves, split and joined outside the jitted call
+    (which runs without x64, as the kernel's arithmetic does)."""
+    with jax.enable_x64():
+        a, b = (tuple(np.asarray(w) for w in x64.split(x64.to_bits(v)))
+                for v in (a, b))
+    out = jax.jit(fn)(a, b)
+    assert all(w.dtype == np.uint32 for w in out)
+    with jax.enable_x64():
+        return x64.from_bits(x64.join(out))
+
+
+@pytest.mark.parametrize("op", ["add", "mul", "add_halves", "mul_halves"])
 @pytest.mark.parametrize("kind", ["bits", "times", "near", "special"])
 def test_matches_numpy_bit_for_bit(op, kind):
     n = 4096
@@ -46,8 +60,9 @@ def test_matches_numpy_bit_for_bit(op, kind):
         a, b = np.meshgrid(_SPECIAL, _SPECIAL)
         a, b = a.ravel(), b.ravel()
     with np.errstate(over="ignore"):
-        want = a + b if op == "add" else a * b
-    got = _run(getattr(x64, op), a, b)
+        want = a + b if op.startswith("add") else a * b
+    run = _run_halves if op.endswith("_halves") else _run
+    got = run(getattr(x64, op), a, b)
     bad = np.flatnonzero(got.view(np.int64) != want.view(np.int64))
     assert bad.size == 0, (op, kind, a[bad[:3]], b[bad[:3]], got[bad[:3]])
 

@@ -6,9 +6,11 @@ smoke's pattern).  The subprocess runs the same mixed-shape ensemble —
 with bucket sizes that do NOT divide the device count — through
 `sweep()` and `sweep(mesh=make_local_mesh())` and asserts bit-identical
 per-coflow CCTs, LP objectives, and byte-identical JSON/CSV row
-artifacts: once on the calendar the CPU picks (the host one), and once
+artifacts: once on the calendar the CPU picks (the host one), once
 with the device calendar forced, so its member-sharded program
-(`batch_circuit._run_calendar_pairs_sharded`) runs over the 8 devices.
+(`batch_circuit._run_calendar_pairs_sharded`) runs over the 8 devices,
+and once with the allocation scan's Pallas kernel forced (interpreted),
+so it runs per shard under `jax.shard_map`.
 """
 
 import os
@@ -23,6 +25,16 @@ import jax
 assert len(jax.devices()) == 8, jax.devices()
 
 device_calendar = sys.argv[1:] == ["device-calendar"]
+scan_kernel = sys.argv[1:] == ["scan-kernel"]
+if scan_kernel:
+    from repro.pipeline import batch_alloc as ba
+
+    ba._SCAN_KERNEL_INTERPRET = True
+    scan = ba._scan_kernel
+    meshes = []
+    ba._scan_kernel = (
+        lambda *a, **k: meshes.append(k["mesh"]) or scan(*a, **k)
+    )
 if device_calendar:
     from repro.pipeline import batch_circuit as bc
 
@@ -79,6 +91,8 @@ with open(c1, "rb") as f:
 assert single_csv == sharded_csv, "CSV rows diverged"
 if device_calendar:
     assert calls, "the sharded sweep ran no sharded device calendar"
+if scan_kernel:
+    assert None in meshes and mesh in meshes, meshes
 print("SHARDED-PARITY-OK")
 """
 
@@ -108,3 +122,7 @@ def test_sharded_sweep_bit_identical_subprocess(tmp_path):
 
 def test_sharded_device_calendar_bit_identical_subprocess(tmp_path):
     _run(tmp_path, "device-calendar")
+
+
+def test_sharded_scan_kernel_bit_identical_subprocess(tmp_path):
+    _run(tmp_path, "scan-kernel")

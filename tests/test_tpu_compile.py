@@ -9,7 +9,8 @@ refuses fails here, without a chip:
   * the kernel calendar with the native Pallas `pair_resolve` round under
     x64 (the calendar the platform picks on a TPU);
   * the `pair_resolve` kernel alone;
-  * the allocation scan, with its exact integer double arithmetic;
+  * the allocation scan's Pallas kernel, with its exact double arithmetic
+    on uint32 halves;
   * the batched subgradient LP;
 
 and each again at the shapes of the paper's Sec. V-A ensemble (64
@@ -147,6 +148,8 @@ def test_pair_resolve_kernel_compiles(one_chip, G, N):
     (1, _FLOWS, 8, _PP), (_VA_MEMBERS, _VA_FLOWS, _VA_CORES, 20),
 ])
 def test_allocation_scan_compiles_at_trace_width(one_chip, B, F, Kp, Pp):
+    """The scan a TPU runs: the Pallas kernel (`repro.kernels.alloc_scan`),
+    lowered natively inside `_scan_all` under x64."""
     from repro.pipeline.batch_alloc import _scan_all
 
     with jax.enable_x64():
@@ -159,8 +162,9 @@ def test_allocation_scan_compiles_at_trace_width(one_chip, B, F, Kp, Pp):
             s((B, Kp), i64), s((B,), i64),  # inv_rates, delta
             s((B, Kp), i64), s((B, Kp), jnp.bool_),  # lb0, core_mask
             s((B, Kp, Pp), i64), s((B, Kp, Pp), i64),  # rho0, tau0
+            kernel=True,
         ).compile()
-    assert compiled.as_text()
+    assert "tpu_custom_call" in compiled.as_text()
 
 
 @pytest.mark.parametrize("B,Mp,Pp,iters", [
